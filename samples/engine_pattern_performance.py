@@ -113,4 +113,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from siddhi_tpu.core.profiling import device_info
+    print(f"device: {device_info()}")   # no number without it
     main()

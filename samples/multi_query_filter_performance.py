@@ -41,4 +41,6 @@ def main(total=1_000_000, batch=10_000, n_queries=10):
 
 
 if __name__ == "__main__":
+    from siddhi_tpu.core.profiling import device_info
+    print(f"device: {device_info()}")   # no number without it
     main()

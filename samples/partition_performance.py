@@ -43,4 +43,6 @@ def main(total=200_000, batch=10_000, n_keys=1000):
 
 
 if __name__ == "__main__":
+    from siddhi_tpu.core.profiling import device_info
+    print(f"device: {device_info()}")   # no number without it
     main()

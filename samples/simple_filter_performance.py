@@ -42,4 +42,6 @@ def main(total=1_000_000, batch=10_000):
 
 
 if __name__ == "__main__":
+    from siddhi_tpu.core.profiling import device_info
+    print(f"device: {device_info()}")   # no number without it
     main(int(sys.argv[1]) if len(sys.argv) > 1 else 1_000_000)

@@ -43,4 +43,6 @@ def main(n_patterns=100, n_partitions=1000):
 
 
 if __name__ == "__main__":
+    from siddhi_tpu.core.profiling import device_info
+    print(f"device: {device_info()}")   # no number without it
     main(*(int(a) for a in sys.argv[1:3]))

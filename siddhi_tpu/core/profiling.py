@@ -10,7 +10,8 @@ filter program) in a ``ProfiledKernel`` that — when profiling is enabled
     when JAX exposes it, argument-signature tracking otherwise) — so a
     BENCH regression can be attributed to "NFA step retraced 40x"
     instead of guessed at,
-  * blocked device time (``jax.block_until_ready`` deltas) when
+  * blocked device time (``jax.block_until_ready`` deltas — a true
+    completion barrier on the attached chip, CHANGES.md PR 21) when
     ``device_timing`` is on — this serializes the pipeline, so it is a
     separate, opt-in level,
   * batch sizes (events carried per call, from a per-site hint) and
@@ -29,6 +30,17 @@ from __future__ import annotations
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+
+def device_info() -> Dict[str, Any]:
+    """The backend as JAX reports it: {"platform", "kind", "count"}.
+    Everything that prints a rate or a time prints this beside it — a
+    number from the CPU backend must never read as a device number.
+    Initialises the backend, so on a chip the caller becomes its owner."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
 
 
 class KernelStats:

@@ -179,6 +179,9 @@ class QueryRuntime:
                 # finer group-bys, running aggregates and INT/LONG values
                 from ..plan.planner import (DeviceGroupedAggRuntime,
                                             DeviceWindowedAggRuntime)
+                # only a shape the ring cannot express reroutes; a
+                # JaxRuntimeError from its warm compile is not wrapped
+                # by the planner and passes through here
                 try:
                     self.device_runtime = DeviceWindowedAggRuntime(
                         self, q.input_stream, factory,

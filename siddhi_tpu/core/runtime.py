@@ -310,11 +310,14 @@ class SiddhiAppRuntime:
             from .aggregation import AggregationRuntime
             ar = None
             if engine_mode(app) != "host":
+                from jax.errors import JaxRuntimeError
                 try:
                     from ..plan.iagg_compiler import DeviceAggregationRuntime
                     ar = DeviceAggregationRuntime(ad, self)
                 except TypeError:
                     ar = None     # unsupported shape (e.g. string lanes)
+                except JaxRuntimeError:
+                    raise         # compile failure / OOM: never a reroute
                 except Exception:
                     import logging
                     logging.getLogger(__name__).warning(

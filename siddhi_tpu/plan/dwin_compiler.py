@@ -546,10 +546,7 @@ class DeviceWindowProcessor(WindowProcessor):
                                jnp.asarray(directive), cap)
         work["buf"] = buf
         work["buf_host"] = None             # invalidate any prior read
-        try:
-            buf.copy_to_host_async()
-        except Exception:       # backends without async copy
-            pass
+        buf.copy_to_host_async()
 
     def _read_work(self, work: dict):
         """Block on a work item's egress; on ring overflow rewind to ITS

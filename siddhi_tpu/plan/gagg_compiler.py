@@ -614,10 +614,7 @@ class CompiledGroupedAgg:
         else:
             work["fuse"] = None
             for o in outs:
-                try:
-                    o.copy_to_host_async()
-                except Exception:   # backends without async copy
-                    break
+                o.copy_to_host_async()
         work["outs"] = outs
         work["post_carry"] = self.carry
 

@@ -18,16 +18,16 @@ point:
     (``nfa.step[B=1,C=1,K=8,...]``) — the generalization of xtenant's
     ``n_states/K/planes/B`` bucket key.  tests/test_shapes.py enforces
     that ``jax.jit`` appears nowhere else (short allowlist).
-  * **Persistent compile cache** — ``SIDDHI_TPU_COMPILE_CACHE=<dir>``
-    points JAX's compilation cache at a directory so a process restart
-    re-loads XLA executables instead of recompiling (proven across
-    subprocesses by tests/test_shapes.py).  ``=0`` (or unset) disables.
+  * **Persistent compile cache** — always on, at one placeable path:
+    where ``JAX_COMPILATION_CACHE_DIR`` says when it is set (JAX reads
+    it; this module sets no other), else ``<checkout>/.jax_cache``.  A
+    process restart re-loads XLA executables instead of recompiling
+    (proven across subprocesses by tests/test_shapes.py).  JAX's own
+    ``JAX_ENABLE_COMPILATION_CACHE=0`` turns it off.
   * **AOT shape-ladder prewarm** — ``SIDDHI_TPU_PREWARM=1`` precompiles
     the grow ladder (K doublings of live NFA shapes) in a background
     ``siddhi-prewarm`` thread via ``jit(...).lower(abstract).compile()``
-    so grow-and-replay pays a cache hit, not a cold compile.  Without a
-    configured cache dir the prewarm uses an ephemeral per-process dir
-    (the artifacts must land somewhere the re-jit can find them).
+    so grow-and-replay pays a cache hit, not a cold compile.
   * **Compile telemetry** — per-shape-class ledger (compile count,
     attributed XLA seconds, call-blocking wall seconds, persistent-cache
     hits/misses, trigger = build|grow|rebucket|prewarm|restart), folded
@@ -56,9 +56,13 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-#: Persistent on-disk compile cache: a directory path, or 0/off to
-#: disable (the default).  Read once at first registry use.
-COMPILE_CACHE_ENV = "SIDDHI_TPU_COMPILE_CACHE"
+#: Where the persistent compile cache lives unless JAX was told
+#: otherwise (``JAX_COMPILATION_CACHE_DIR``): one fixed path inside the
+#: checkout, so every process of a command — and the next command —
+#: finds what the last one compiled.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 #: Opt-in AOT shape-ladder prewarm (background grow-ladder compiles).
 PREWARM_ENV = "SIDDHI_TPU_PREWARM"
 #: An ingest-blocking compile (trigger grow/rebucket/restart) slower
@@ -120,14 +124,6 @@ def nfa_shape_dims(spec, n_partitions: int, batch_b: int,
 
 # ------------------------------------------------------------ env knobs
 
-def compile_cache_dir() -> Optional[str]:
-    """Configured cache directory, or None when killed/unset."""
-    raw = os.environ.get(COMPILE_CACHE_ENV, "").strip()
-    if raw.lower() in _FALSY:
-        return None
-    return raw
-
-
 def prewarm_enabled() -> bool:
     return os.environ.get(PREWARM_ENV, "").strip().lower() not in _FALSY
 
@@ -147,42 +143,34 @@ def _prewarm_grace_s() -> float:
 
 
 _CACHE_STATE: Dict[str, Any] = {"configured": False, "enabled": False,
-                                "dir": "", "ephemeral": False}
+                                "dir": ""}
 _CACHE_LOCK = threading.Lock()
 
 
 def configure_compile_cache() -> Dict[str, Any]:
-    """Point JAX's compilation cache at ``SIDDHI_TPU_COMPILE_CACHE``
-    (idempotent; called lazily before the first registry jit).  With
-    prewarm on but no cache dir configured, an ephemeral per-process
-    directory is used — the AOT-compiled ladder artifacts must land
-    somewhere the later re-jit can read them back from."""
+    """Place JAX's persistent compilation cache (idempotent; called
+    before the first registry jit — JAX latches the cache decision at a
+    process's first compile).  A directory JAX already has — from
+    ``JAX_COMPILATION_CACHE_DIR`` or the embedding program — is left
+    alone; otherwise the cache goes to :data:`DEFAULT_CACHE_DIR`."""
     with _CACHE_LOCK:
         if _CACHE_STATE["configured"]:
             return dict(_CACHE_STATE)
-        d = compile_cache_dir()
-        ephemeral = False
-        if d is None and prewarm_enabled():
-            import tempfile
-            d = tempfile.mkdtemp(prefix="siddhi_tpu_prewarm_cache_")
-            ephemeral = True
-        if d is not None:
-            import jax
-            os.makedirs(d, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", d)
-            # cache every executable: the default thresholds skip small /
-            # fast compiles, but coldstart is the SUM of many of those
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            try:
-                jax.config.update("jax_persistent_cache_enable_xla_caches",
-                                  "all")
-            except AttributeError:   # older jaxlib: knob absent
-                pass
-        _CACHE_STATE.update(configured=True, enabled=d is not None,
-                            dir=d or "", ephemeral=ephemeral)
+        import jax
+        if not jax.config.jax_compilation_cache_dir:
+            os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir",
+                              DEFAULT_CACHE_DIR)
+        # cache every executable: the default thresholds skip small /
+        # fast compiles, but coldstart is the SUM of many of those
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          0.0)
+        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
+        _CACHE_STATE.update(
+            configured=True,
+            enabled=bool(jax.config.jax_enable_compilation_cache),
+            dir=jax.config.jax_compilation_cache_dir)
         return dict(_CACHE_STATE)
 
 
@@ -437,8 +425,8 @@ class ShapeRegistry:
                     "cache": dict(_CACHE_STATE),
                     "hint": "an ingest-blocking XLA compile outran "
                             f"{COMPILE_STALL_MS_ENV}; enable "
-                            f"{COMPILE_CACHE_ENV}/{PREWARM_ENV} so grown "
-                            "shapes restart from the persistent cache"})
+                            f"{PREWARM_ENV} so grown shapes are compiled "
+                            "ahead of need"})
         except Exception:   # noqa: BLE001 — telemetry must not fail a step
             pass
 
@@ -665,9 +653,9 @@ def shape_registry() -> ShapeRegistry:
 
 
 # ------------------------------------------------------------ monitoring
-# Listener installation is one-way (jax.monitoring has no deregister);
-# the callbacks dispatch through shape_registry() so a test-reset
-# registry keeps receiving credit.
+# Listeners are installed once per process; the callbacks dispatch
+# through the module-level registry so a test-reset registry keeps
+# receiving credit.
 
 _LISTENERS = {"installed": False}
 

@@ -1,16 +1,15 @@
 """bench.py is part of the tested surface (round 6).
 
-BENCH_r05 was a raw rc=1 `RuntimeError: Unable to initialize backend`
-stack trace — the bench script itself had no tier-1 coverage, so a
-bench-only regression could sit undetected until the next device round.
-Two subprocess checks close that:
+The bench script itself once had no tier-1 coverage, so a bench-only
+regression could sit undetected until the next device round.  Subprocess
+checks close that:
 
   * `bench.py --smoke` (CPU-pinned, one tiny block per phase, seconds)
     must exit 0 and emit valid JSON with the per-phase fields, including
     the NFA B-sweep with equal match counts across B;
-  * with an unreachable backend, bench.py must emit a structured
-    `{"skipped": "backend unavailable", ...}` line and exit 0 instead of
-    crashing.
+  * a full run (`bench.py`, no `--smoke`) with an unreachable backend, or
+    with only the CPU, must exit non-zero: a measurement path that finds
+    no chip fails instead of skipping or timing the CPU.
 """
 import json
 import os
@@ -204,12 +203,21 @@ def test_fail_on_numeric_gate():
     assert ns["value"] == 0 and ns["per_file"] == {}
 
 
-def test_bench_skips_on_unreachable_backend():
-    # a platform name jax cannot initialize reproduces the BENCH_r05
-    # failure mode; bench must report a structured skip and exit 0
+def test_bench_fails_on_unreachable_backend():
+    # a platform name jax cannot initialize: the first phase child fails
+    # on it and the full run exits non-zero — a measurement path that
+    # finds no chip must not report success (inverted in PR 21; the old
+    # behaviour was a structured skip and exit 0)
     res = _run([], env_extra={"JAX_PLATFORMS": "no_such_backend"},
                timeout=300)
-    assert res.returncode == 0, res.stdout + res.stderr
-    out = json.loads(res.stdout.strip().splitlines()[-1])
-    assert out["skipped"] == "backend unavailable"
-    assert out["error"]
+    assert res.returncode != 0, res.stdout + res.stderr
+    assert "skipped" not in res.stdout
+    assert "no_such_backend" in res.stderr
+
+
+def test_bench_full_run_refuses_the_cpu():
+    # with only the CPU backend the gate child names the platform it
+    # found and the run exits non-zero before measuring anything
+    res = _run([], env_extra={"JAX_PLATFORMS": "cpu"}, timeout=300)
+    assert res.returncode != 0, res.stdout + res.stderr
+    assert "'platform': 'cpu'" in res.stderr

@@ -23,11 +23,11 @@ sys.path.insert(0, REPO)
 
 from siddhi_tpu import SiddhiManager, StreamCallback  # noqa: E402
 from siddhi_tpu.core.flight import flight  # noqa: E402
-from siddhi_tpu.plan.shapes import (COMPILE_CACHE_ENV,  # noqa: E402
-                                    LADDER_RUNGS, PREWARM_ENV, SHAPES_TYPES,
-                                    _AotHandoff, compile_cache_dir,
-                                    nfa_shape_dims, prewarm_enabled,
-                                    shape_registry, shape_signature)
+from siddhi_tpu.plan.shapes import (LADDER_RUNGS,  # noqa: E402
+                                    PREWARM_ENV, SHAPES_TYPES,
+                                    _AotHandoff, nfa_shape_dims,
+                                    prewarm_enabled, shape_registry,
+                                    shape_signature)
 
 
 @pytest.fixture(autouse=True)
@@ -73,12 +73,7 @@ def test_nfa_shape_dims_contract():
         "nfa.bank_step[B=8,C=1,K=16,P=4,R=2,S=3,donate=1,ring=3,telem=0]")
 
 
-def test_cache_env_kill_switch(monkeypatch):
-    for off in ("0", "off", "false", ""):
-        monkeypatch.setenv(COMPILE_CACHE_ENV, off)
-        assert compile_cache_dir() is None
-    monkeypatch.setenv(COMPILE_CACHE_ENV, "/tmp/ccache")
-    assert compile_cache_dir() == "/tmp/ccache"
+def test_prewarm_env_kill_switch(monkeypatch):
     monkeypatch.setenv(PREWARM_ENV, "0")
     assert not prewarm_enabled()
     assert not shape_registry().prewarm_submit("t", {"n": 1}, lambda: None)
@@ -339,12 +334,16 @@ def test_prewarm_handoff_is_owner_gated():
 
 # ------------------------------------------- cache across process restart
 
-def _run_cachestab_worker(cache_dir, extra_env=None):
+def _run_cachestab_worker(cache_dir):
+    """cache_dir: where the child's JAX is told to cache (the one way to
+    place it); None turns the cache off with JAX's own switch."""
     env = dict(os.environ)
     env.update(JAX_PLATFORMS="cpu", SIDDHI_TPU_XTENANT="0",
-               SIDDHI_TPU_PREWARM="0")
-    env[COMPILE_CACHE_ENV] = cache_dir
-    env.update(extra_env or {})
+               SIDDHI_TPU_PREWARM="0",
+               JAX_ENABLE_COMPILATION_CACHE="0" if cache_dir is None
+               else "1")
+    if cache_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"),
          "--coldstart-worker", "--cs-tiny"],
@@ -369,6 +368,8 @@ def test_compile_cache_survives_process_restart(tmp_path):
     assert cold["digest"] == warm["digest"]
     assert cold["matches"] == warm["matches"] > 0
     # parity against a cache-disabled process: same events, same matches
-    off = _run_cachestab_worker("0")
+    off = _run_cachestab_worker(None)
     assert off["digest"] == cold["digest"]
     assert off["cache"]["enabled"] is False
+    assert cold["cache"] == {"configured": True, "enabled": True,
+                             "dir": cache}
