@@ -1824,6 +1824,11 @@ def bench_smoke():
             f"smoke telemetry FAILED: no gate passes recorded: {occ}"
     finally:
         svc.stop()
+        # tracing='true' switched the process-wide Chrome exporter on;
+        # the two overhead bounds below are of the always-on pieces
+        from siddhi_tpu.core.tracing import tracer
+        tracer().disable()
+        tracer().clear()
 
     # recorder-on vs recorder-off ingest wall time: same runtime, same
     # feed, alternating phases, min over repeats (record_block re-reads

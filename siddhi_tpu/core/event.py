@@ -89,7 +89,7 @@ class EventChunk:
     StateEvent (join/pattern output rows, event/state/StateEvent.java)."""
 
     __slots__ = ("timestamps", "types", "columns", "names", "qualified",
-                 "is_batch", "ledger_ns")
+                 "is_batch", "ledger_ns", "block_seq")
 
     def __init__(self, names: Sequence[str], timestamps: np.ndarray,
                  types: np.ndarray, columns: Dict[str, np.ndarray],
@@ -109,6 +109,11 @@ class EventChunk:
         # (queue-wait and dispatch-gap attribution, core/ledger.py); NOT
         # carried by transforms — a derived chunk is a new timeline
         self.ledger_ns = None
+        # the junction's dequeue sequence number of this delivered chunk
+        # (core/stream.py _worker_loop; a synchronous send is stamped as
+        # it is delivered): the `block` of every ledger span of its
+        # delivery
+        self.block_seq = None
 
     # ------------------------------------------------------------ constructors
 

@@ -153,9 +153,12 @@ class FlightRecorder:
     def record_block(self, app: str, stream: str = "", batch: int = 0,
                      dispatches: int = 0, scan_ticks: int = 0,
                      junction=None, scheduler=None,
-                     telemetry=None, extra: Optional[dict] = None) -> None:
+                     telemetry=None, extra: Optional[dict] = None,
+                     ledger: Optional[dict] = None) -> None:
         """One ingest block's structured record.  Called by the device
-        runtimes' ingest paths; cheap enough to stay always-on."""
+        runtimes' ingest paths; cheap enough to stay always-on.
+        ``ledger``: the block's stage waterfall row (plain floats as the
+        ledger builds them, so it skips ``extra``'s JSON coercion)."""
         if not self.enabled:
             return
         rec: Dict[str, Any] = {
@@ -175,6 +178,8 @@ class FlightRecorder:
             rec["telemetry"] = _jsonable(telemetry)
         if extra:
             rec.update(_jsonable(extra))
+        if ledger:
+            rec["ledger"] = ledger
         with self._lock:
             self._seq += 1
             rec["block"] = self._seq
@@ -244,10 +249,11 @@ class FlightRecorder:
         except Exception:   # noqa: BLE001
             log.exception("flight bundle: kernel snapshot failed")
         try:
+            from .ledger import ledger
             from .tracing import tracer
             # drop an incident marker so the span timeline shows WHERE
             # the trip happened, then embed the (bounded) trace
-            tracer().instant(f"incident.{kind}", cat="incident",
+            ledger().instant(f"incident.{kind}", cat="incident",
                              id=bid, app=app)
             bundle["trace"] = tracer().to_dict(limit=20_000)
         except Exception:   # noqa: BLE001

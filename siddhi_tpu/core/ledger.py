@@ -20,7 +20,22 @@ Stages are recorded through nest-aware spans: a span's *exclusive* time
 (elapsed minus enclosed child spans) goes to its stage, so the per-stage
 sums reconcile against an independently measured end-to-end wall clock
 without double counting (``bench --phase waterfall`` asserts >= 95%
-coverage).  Per-block deltas are folded into per-app/per-stage HDR
+coverage).  The span is the program's ONE span source, on two clocks at
+once: a span with a ``name`` also credits its exclusive time to the
+sub-span accumulator ``"<stage>.<name>"`` (:data:`SPAN_NAMES`) and records
+it, one entry per execution, in a per-app histogram under that key; and
+while a ``jax.profiler`` session runs every span is a
+``TraceAnnotation("siddhi/<stage>[.<name>]", block=<seq>)``, so that it
+lies in the host plane of the same ``.xplane.pb`` as the device's ops, on
+the thread that ran it.  ``block`` is the junction's dequeue sequence
+number of the delivered chunk: it joins a block's spans across the send
+that dispatched it and the later send that retired it.  A span with a
+name and no stage credits no stage and only annotates
+(:data:`ANNOTATIONS`; ``device.issue`` alone among them is declared, and
+keeps its elapsed time under its key).  When ``tracing='true'`` the same
+spans feed the operator's Chrome-trace exporter (core/tracing.py).
+The waits of a block in flight (:data:`WAITS`) are histograms only: they
+credit no stage.  Per-block deltas are folded into per-app/per-stage HDR
 histograms (PR 1 machinery) and a ``ledger`` waterfall row on each flight
 ring record — same global-accumulator-delta convention as the ring's
 existing rim/kernel ms split.
@@ -41,15 +56,19 @@ per call so the bench overhead phase can toggle it per block.  Like
 """
 from __future__ import annotations
 
+import contextlib
 import os
+import sys
 import threading
 import time
 
 from collections import deque
+from operator import itemgetter, sub as _sub
 from typing import Any, Dict, List, Optional
 
 from .hotpath import hot_path
 from .statistics import Histogram
+from .tracing import tracer as _chrome_sink
 
 LEDGER_ENV = "SIDDHI_TPU_LEDGER"
 
@@ -58,7 +77,53 @@ LEDGER_ENV = "SIDDHI_TPU_LEDGER"
 STAGES = ("ingress", "queue", "dispatch", "device", "egress_d2h",
           "decode", "publish")
 
-_STAGE_SET = frozenset(STAGES)
+_stage_values = itemgetter(*STAGES)     # the seven accumulators at once
+
+#: the named sub-spans the program declares, ``"<stage>.<name>"``: each
+#: has an accumulator beside the seven stages in :meth:`stage_ns` and a
+#: per-app histogram beside them in ``snapshot()["apps"][app]
+#: ["stages_ms"]``.  A span under another name raises KeyError, so a
+#: renamed span fails a test (benchmark/tests/test_setup_reader.py holds
+#: every metric file to this list), not a run.  What each one wraps:
+#:
+#:   dispatch.keys    the partition-key executor over the chunk
+#:   dispatch.lanes   key -> lane map (``map_keys_to_lanes``)
+#:   dispatch.cols    kernel input columns (``_event_cols``)
+#:   dispatch.pack    the windowed-agg runtime's ``pack_blocks`` (it sits
+#:                    under ``dispatch`` there, and stage membership is
+#:                    not this list's to change)
+#:   device.encode    string dictionary encoding / derived lanes
+#:   device.pack      the NFA's dense ``[P, T]`` scatter (``pack_blocks``)
+#:   device.sync      a gang bucket's flush, up to and after the gang call
+#:   device.issue     one registry-jitted call (``RegisteredJit.__call__``,
+#:                    annotated ``siddhi/device.issue/<registry kind>``).
+#:                    Opened without a stage, wherever the call is made
+#:                    from: its time stays with the stage that called it
+#:   device.retire    a pattern retire's part under ``device``: what
+#:                    ``retire_events`` does besides the gang flush and the
+#:                    D2H read (re-pack on overflow, compact-row decode)
+#:   decode.fetch     the windowed-agg runtime's fetch of its step's
+#:                    outputs from the fused slab, under ``decode`` there
+SPAN_NAMES = ("dispatch.keys", "dispatch.lanes", "dispatch.cols",
+              "dispatch.pack", "device.encode", "device.pack", "device.sync",
+              "device.issue", "device.retire", "decode.fetch")
+
+#: spans without a stage that only annotate (no accumulator; nothing at
+#: all unless a profiler session or the operator's exporter records):
+#: ``queue.idle`` the junction worker's blocking ``q.get``, ``deliver``
+#: one dequeued block's whole delivery, ``ingest.chunk`` a send,
+#: ``egress_d2h.seal`` the fused slab's eager concatenate/bitcasts,
+#: ``match.scatter`` a retire's emit, and app creation's ``parse``,
+#: ``analyze``, ``plan``, ``plan.verify``, ``schema``, ``numeric``
+ANNOTATIONS = ("queue.idle", "deliver", "ingest.chunk", "egress_d2h.seal",
+               "match.scatter", "parse", "analyze", "plan", "plan.verify",
+               "schema", "numeric")
+
+#: waits of one block in flight, per-app histograms only (no stage):
+#: ``wait.defer`` submit -> its step actually launched (0 for a step
+#: issued at once; a gang tenant waits for its bucket's flush);
+#: ``wait.inflight`` submit -> the start of its retire
+WAITS = ("wait.defer", "wait.inflight")
 
 
 # os.environ.get pays ~0.9 us per call (key encode + value decode);
@@ -207,53 +272,175 @@ class _SloState:
 
 _pcns = time.perf_counter_ns
 
+# jax.profiler.TraceAnnotation, bound on the first span after jax was
+# imported: this module stays importable (and the analyzer's CLI stays
+# runnable) without jax, and with no jax in the process there is no
+# profiler session a span could lie in
+_TA = None
+
+
+def _ta_session() -> bool:
+    """Is a profiler session recording host events?  This body runs only
+    until jax is imported: it then rebinds its own name to TraceMe's
+    test."""
+    global _TA, _ta_session
+    if "jax" not in sys.modules:
+        return False
+    from jax.profiler import TraceAnnotation
+    _TA = TraceAnnotation
+    _ta_session = TraceAnnotation.is_enabled
+    return _ta_session()
+
+
+_SINK = _chrome_sink()
+
+
+def _names_of(stage: Optional[str], name: Optional[str]) -> tuple:
+    """-> (accumulator key or None, annotation name).  ``name`` may carry
+    a ``/<detail>`` that only the annotation shows (``device.issue/
+    <registry kind>``): the key is what stands before it."""
+    if name is None:
+        return None, f"siddhi/{stage}"
+    base = name.split("/", 1)[0]
+    if stage is not None:
+        key = f"{stage}.{base}"
+        if key not in SPAN_NAMES:
+            raise KeyError(
+                f"ledger span {key!r} is not declared in SPAN_NAMES")
+        return key, f"siddhi/{stage}.{name}"
+    # without a stage only a declared name keeps an accumulator
+    if base in SPAN_NAMES:
+        return base, f"siddhi/{name}"
+    if base not in ANNOTATIONS:
+        raise KeyError(f"ledger span {base!r} is not in ANNOTATIONS")
+    return None, f"siddhi/{name}"
+
+
+def _sink_event(name: str, cat: str, t0: int, dur: Optional[int],
+                args: Optional[Dict[str, Any]]) -> None:
+    """One Chrome trace event into the operator's exporter, stamped with
+    this module's clock (``dur`` None: an instant)."""
+    ev = {"name": name, "cat": cat, "ts": t0 / 1e3, "pid": 0,
+          "tid": threading.get_ident()}
+    if dur is None:
+        ev.update(ph="i", s="t")
+    else:
+        ev.update(ph="X", dur=dur / 1e3)
+    if args:
+        ev["args"] = args
+    _SINK.add(ev)
+
+
+# what span() hands out for an annotation nobody is recording
+_NO_SPAN = contextlib.nullcontext()
+
 
 class _Span:
-    """Nest-aware stage span.  On exit the span's EXCLUSIVE time
-    (elapsed minus enclosed child spans on this thread) is credited to
-    its stage and its full elapsed time is charged to the parent's
-    child accumulator — so ``sum(stage_ns)`` over a fully-spanned path
-    equals the wall clock once, not once per nesting level.
+    """Nest-aware span.  With a stage: on exit its EXCLUSIVE time
+    (elapsed minus enclosed stage spans on this thread) is credited to
+    its stage, and its full elapsed time is charged to the parent's
+    child accumulator, so ``sum(stage_ns)`` over the seven stages of a
+    fully-spanned path equals the wall clock once, not once per nesting
+    level.  With a name as well, that time less the stage-less named
+    spans inside goes to ``"<stage>.<name>"`` and the app's histogram of
+    that key: sub-spans are exclusive of one another too.
+
+    Without a stage it touches no stage's books: what the spans inside
+    it took it hands up to the span around it, and its own time stays
+    with the stage that called it, whichever that is.  A declared name
+    keeps its whole elapsed time under its key (``device.issue``: a leaf).
 
     The hot path runs cold-cache right next to device dispatches, where
-    every attribute chase costs real time — frames are plain two-int
-    lists ``[t0, child_ns]`` on a thread-local stack, no per-frame
-    object."""
+    every attribute chase costs real time — a span's state is one plain
+    list ``[child_ns, named_ns, block, app, rec, t0, annotation]`` on a
+    thread-local stack, so the span object itself holds nothing of one
+    execution: the ledger hands out one object per (stage, name) over
+    and over, and makes a new one only where it is given a ``block``.
+    ``block`` and ``app`` default to the enclosing frame's.  Only the
+    outermost span asks the kill switch and ``rec`` (is a profiler
+    session or the operator's exporter recording?); those inside take
+    its answer."""
 
-    __slots__ = ("ledger", "stage", "frame", "stack")
+    __slots__ = ("ledger", "stage", "key", "annot", "block", "app")
 
-    def __init__(self, ledger: "LatencyLedger", stage: str):
+    def __init__(self, ledger: "LatencyLedger", stage: Optional[str],
+                 key: Optional[str], annot: str,
+                 block: Optional[int] = None, app: Optional[str] = None):
         self.ledger = ledger
         self.stage = stage
+        self.key = key
+        self.annot = annot
+        self.block = block
+        self.app = app
 
     def __enter__(self):
-        if ledger_enabled():
-            tls = self.ledger._tls
-            st = getattr(tls, "stack", None)
+        tls = self.ledger._tls
+        st = getattr(tls, "stack", None)
+        block = self.block
+        if st:
+            top = st[-1]
+            rec = top[4]
+            frame = [0, 0, top[2], top[3], rec, 0, None] if block is None \
+                else [0, 0, block, self.app, rec, 0, None]
+        else:
+            if not ledger_enabled():
+                return self
             if st is None:
                 st = tls.stack = []
-            frame = [_pcns(), 0]
-            st.append(frame)
-            self.frame = frame
-            self.stack = st
-        else:
-            self.frame = None
+            rec = _SINK.enabled or _ta_session()
+            frame = [0, 0, block, self.app, rec, 0, None]
+        st.append(frame)
+        if rec and _ta_session():
+            block = frame[2]
+            ta = frame[6] = _TA(self.annot) if block is None else \
+                _TA(self.annot, block=block)
+            ta.__enter__()
+        frame[5] = _pcns()
         return self
 
     def __exit__(self, *exc):
-        frame = self.frame
-        if frame is None:
-            return False
-        elapsed = _pcns() - frame[0]
-        st = self.stack
-        st.pop()
-        if st:
-            st[-1][1] += elapsed
-        ns = elapsed - frame[1]
+        st = getattr(self.ledger._tls, "stack", None)
+        if not st:
+            return False            # the ledger was off at the enter
+        frame = st.pop()
+        elapsed = _pcns() - frame[5]
         led = self.ledger
-        if ns > 0:
-            led._ns[self.stage] += ns
-        led._spans[self.stage] += 1
+        stage = self.stage
+        key = self.key
+        if stage is not None:
+            if st:
+                st[-1][0] += elapsed
+            ns = elapsed - frame[0]
+            if ns > 0:
+                led._ns[stage] += ns
+            led._spans[stage] += 1
+            if key is not None:
+                ns -= frame[1]
+                if ns > 0:
+                    led._ns[key] += ns
+                app = frame[3]
+                if app is not None:
+                    named = led._named
+                    named.append((app, key, ns))
+                    if len(named) >= led._FOLD_NAMED_EVERY:
+                        led._fold_named()
+        else:
+            # what the stage spans inside it took is their own stages'
+            ns = elapsed - frame[0]
+            if st:
+                top = st[-1]
+                top[0] += frame[0]
+                top[1] += frame[1] if key is None else ns
+            if key is not None and ns > 0:
+                led._ns[key] += ns
+        if frame[4]:
+            if frame[6] is not None:
+                frame[6].__exit__(None, None, None)
+            if _SINK.enabled:
+                block = frame[2]
+                _sink_event(self.annot[7:], stage or "engine", frame[5],
+                            elapsed,
+                            None if block is None else {"block": block})
         return False
 
 
@@ -275,16 +462,29 @@ class LatencyLedger:
     #: _FOLD_EVERY blocks / lazily on any read surface
     _FOLD_EVERY = 64
 
+    #: named-span / wait entries buffered before the same lazy fold
+    #: (~0.9 us an entry: half a millisecond of the worker at a time)
+    _FOLD_NAMED_EVERY = 512
+
     def __init__(self):
-        self._ns: Dict[str, int] = {s: 0 for s in STAGES}
+        # the seven stages and, beside them, the declared sub-spans
+        self._ns: Dict[str, int] = {s: 0 for s in STAGES + SPAN_NAMES}
         self._spans: Dict[str, int] = {s: 0 for s in STAGES}
         self._lock = threading.Lock()
         self._tls = threading.local()
+        # stage or (stage, name) -> the span object of every execution of
+        # that span
+        self._span_of: Dict[Any, _Span] = {}
         # (app, stage) -> Histogram of per-block stage ns; stage "total"
         # is the per-block all-stage sum (the e2e estimator SLOs burn on)
         self._hist: Dict[tuple, Histogram] = {}
         # app -> buffered per-block delta lists awaiting the fold
         self._pending: Dict[str, list] = {}
+        # (app, sub-span or wait key, ns): one entry per execution,
+        # awaiting the same fold
+        self._named: list = []
+        # app -> [retires whose result was ready, retires that blocked]
+        self._retires: Dict[str, list] = {}
         # app -> the most recent block's stage deltas (waterfall row)
         self._last_deltas: Dict[str, list] = {}
         # (app, stream) -> lag watermark state
@@ -303,8 +503,44 @@ class LatencyLedger:
             st = self._tls.stack = []
         return st
 
-    def span(self, stage: str) -> _Span:
-        return _Span(self, stage)
+    def span(self, stage: Optional[str], name: Optional[str] = None,
+             block: Optional[int] = None, app: Optional[str] = None):
+        """``with ledger().span("device", "pack"): ...`` — see the module
+        docstring.  ``block`` and ``app`` are given together, where a
+        block changes hands (a delivery, a retire); the spans inside
+        take them from there."""
+        sp = self._span_of.get(stage if name is None else (stage, name))
+        if sp is None:
+            sp = self._span_of[stage if name is None else (stage, name)] = \
+                _Span(self, stage, *_names_of(stage, name))
+        if block is not None:
+            return _Span(self, stage, sp.key, sp.annot, block, app)
+        if stage is None and sp.key is None:
+            # only an annotation: is anybody recording it?
+            st = getattr(self._tls, "stack", None)
+            if not (st[-1][4] if st else _SINK.enabled or _ta_session()):
+                return _NO_SPAN
+        return sp
+
+    def stamp(self) -> Optional[tuple]:
+        """``(now ns, the current block)`` for a handle that goes in
+        flight; None with the ledger off."""
+        st = getattr(self._tls, "stack", None)
+        if st:
+            return _pcns(), st[-1][2]
+        return (_pcns(), None) if ledger_enabled() else None
+
+    def current_block(self) -> Optional[int]:
+        """The ``block`` of the innermost open stage span on this thread
+        (a chunk emitted inside a delivery stays with that block)."""
+        st = getattr(self._tls, "stack", None)
+        return st[-1][2] if st else None
+
+    def instant(self, name: str, cat: str = "engine", **args) -> None:
+        """A point event for the operator's Chrome exporter (nothing on
+        the profiler's clock: a TraceMe has a duration)."""
+        if _SINK.enabled:
+            _sink_event(name, cat, _pcns(), None, args)
 
     def record(self, stage: str, ns: int) -> None:
         """Credit ``ns`` of exclusive wall time to ``stage``."""
@@ -318,13 +554,30 @@ class LatencyLedger:
         """Per-chunk admit stamp: ingress stage time + the event-time lag
         watermark (max admitted event timestamp vs the wall clock — or
         the playback clock when the app replays history)."""
-        self.record("ingress", dur_ns)
+        if dur_ns > 0:
+            self._ns["ingress"] += dur_ns
+        self._spans["ingress"] += 1
         ent = self._lag.get((app, stream))
         if ent is None:
             ent = self._lag[(app, stream)] = {}
-        ent["event_ts_ms"] = float(event_ts_ms)
         ent["admit_wall_ms"] = time.time() * 1000.0
-        ent["lag_ms"] = float(now_ms) - float(event_ts_ms)
+        ent["lag_ms"] = float(now_ms - event_ts_ms)
+
+    def note_retire(self, app: str, t_submit: Optional[int],
+                    t_issue: int, t_retire: int, ready: bool) -> None:
+        """One in-flight block starts its retire: bank its waits (ns
+        stamps of this module's clock; a block with no submit stamp was
+        dispatched with the ledger off) and count whether its result was
+        already there."""
+        named = self._named
+        if t_submit is not None:
+            named.append((app, "wait.inflight", t_retire - t_submit))
+            named.append((app, "wait.defer", t_issue - t_submit))
+        row = self._retires.get(app)
+        if row is None:
+            with self._lock:
+                row = self._retires.setdefault(app, [0, 0])
+        row[0 if ready else 1] += 1
 
     # ------------------------------------------------------ block fold
 
@@ -348,13 +601,14 @@ class LatencyLedger:
         histogram fold is deferred — see ``_FOLD_EVERY``)."""
         if not ledger_enabled():
             return None
-        ns = self._ns
-        cur = [ns[s] for s in STAGES]
+        cur = _stage_values(self._ns)
         prev = getattr(owner, "_ledger_ns0", None)
         owner._ledger_ns0 = cur
         if prev is None:
             return None
-        deltas = [c - p if c > p else 0 for c, p in zip(cur, prev)]
+        deltas = tuple(map(_sub, cur, prev))
+        if min(deltas) < 0:         # the accumulators were reset() since
+            deltas = tuple(d if d > 0 else 0 for d in deltas)
         total_ns = sum(deltas)
         self._last_deltas[app] = deltas
         pend = self._pending.get(app)
@@ -375,7 +629,8 @@ class LatencyLedger:
 
     @staticmethod
     def _row_ms(deltas) -> Dict[str, float]:
-        return {s: round(d / 1e6, 4)
+        # ms to four places (d // 100 / 1e4: no call per stage)
+        return {s: d // 100 / 1e4
                 for s, d in zip(STAGES, deltas) if d > 0}
 
     def _fold_pending(self, app: Optional[str] = None) -> None:
@@ -388,14 +643,24 @@ class LatencyLedger:
                 continue
             drained = pend[:]
             del pend[:len(drained)]     # GIL-safe vs concurrent appends
-            for deltas in drained:
-                tot = 0
-                for s, d in zip(STAGES, deltas):
-                    if d > 0:
-                        tot += d
-                        self._hist_for(a, s).record(d)
-                if tot > 0:
-                    self._hist_for(a, "total").record(tot)
+            # a stage's deltas of all drained blocks at once (no delta
+            # is negative: note_block clamps them)
+            rows = list(zip(*drained)) + [[sum(d) for d in drained]]
+            for s, row in zip(STAGES + ("total",), rows):
+                values = [d for d in row if d > 0]
+                if values:
+                    self._hist_for(a, s).record_many(values)
+        self._fold_named()
+
+    def _fold_named(self) -> None:
+        named = self._named
+        drained = named[:]
+        del named[:len(drained)]        # GIL-safe vs concurrent appends
+        groups: Dict[tuple, list] = {}
+        for app, key, ns in drained:
+            groups.setdefault((app, key), []).append(ns)
+        for (app, key), values in groups.items():
+            self._hist_for(app, key).record_many(values)
 
     def _app_lag_ms(self, app: str) -> Optional[float]:
         lags = [v["lag_ms"] for (a, _s), v in list(self._lag.items())
@@ -428,9 +693,11 @@ class LatencyLedger:
     def drop_app(self, app: str) -> None:
         """Forget one app's SLO + lag + histogram state (runtime
         shutdown; process-global stage counters are left alone)."""
+        self._fold_named()          # so the app's buffered entries go too
         with self._lock:
             self._slo.pop(app, None)
             self._pending.pop(app, None)
+            self._retires.pop(app, None)
             self._last_deltas.pop(app, None)
             for key in [k for k in self._lag if k[0] == app]:
                 self._lag.pop(key, None)
@@ -446,7 +713,7 @@ class LatencyLedger:
     def _stage_summary(self, app: str) -> Dict[str, Dict[str, float]]:
         self._fold_pending(app)
         out: Dict[str, Dict[str, float]] = {}
-        for stage in STAGES + ("total",):
+        for stage in STAGES + ("total",) + SPAN_NAMES + WAITS:
             h = self._hist.get((app, stage))
             if h is not None and h.count:
                 out[stage] = h.summary(scale=1e-6)      # ns -> ms
@@ -457,6 +724,7 @@ class LatencyLedger:
         doc: Dict[str, Any] = {
             "enabled": ledger_enabled(),
             "stage_seconds": {s: self._ns[s] / 1e9 for s in STAGES},
+            "span_seconds": {s: self._ns[s] / 1e9 for s in SPAN_NAMES},
             "stage_spans": dict(self._spans),
         }
         apps = sorted({a for (a, _s) in self._hist}
@@ -476,6 +744,10 @@ class LatencyLedger:
             last = self._last_deltas.get(a)
             if last:
                 entry["last_block_ms"] = self._row_ms(last)
+            row = self._retires.get(a)
+            if row is not None:
+                entry["retire_ready_total"] = row[0]
+                entry["retire_blocked_total"] = row[1]
             per_app[a] = entry
         doc["apps"] = per_app
         return doc
@@ -490,6 +762,14 @@ class LatencyLedger:
                          f"{self._ns[stage] / 1e9:.9g}")
             lines.append(f"siddhi_ledger_stage_spans_total{lab} "
                          f"{self._spans[stage]}")
+        for key in SPAN_NAMES:
+            lab = _fmt_labels({"span": key})
+            lines.append(f"siddhi_ledger_span_seconds_total{lab} "
+                         f"{self._ns[key] / 1e9:.9g}")
+        for app, row in sorted(self._retires.items()):
+            lab = _fmt_labels({"app": app})
+            lines.append(f"siddhi_retire_ready_total{lab} {row[0]}")
+            lines.append(f"siddhi_retire_blocked_total{lab} {row[1]}")
         for (app, stage), h in sorted(self._hist.items()):
             if not h.count:
                 continue
@@ -519,11 +799,14 @@ class LatencyLedger:
     def reset(self) -> None:
         """Test/bench isolation (mirrors flight().reset())."""
         with self._lock:
-            for s in STAGES:
+            for s in self._ns:
                 self._ns[s] = 0
+            for s in STAGES:
                 self._spans[s] = 0
             self._hist.clear()
             self._pending.clear()
+            del self._named[:]
+            self._retires.clear()
             self._last_deltas.clear()
             self._lag.clear()
             self._slo.clear()

@@ -195,12 +195,11 @@ class ProfiledKernel:
                 except Exception:   # noqa: BLE001 — hint only
                     pass
             st.h2d_bytes += _host_bytes(args)
-        from .tracing import tracer
-        tr = tracer()
-        if tr.enabled:
-            if compiled:
-                tr.instant(f"jit-compile:{st.name}", cat="jit")
-            tr.complete(f"kernel.{st.name}", t0, t1, cat="kernel")
+        if compiled:
+            # the call itself is a span already, `device.issue/<kind>`
+            # (plan/shapes.py RegisteredJit)
+            from .ledger import ledger
+            ledger().instant(f"jit-compile:{st.name}", cat="jit")
         if prof.device_timing:
             import jax
             t2 = time.perf_counter_ns()

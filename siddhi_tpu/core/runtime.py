@@ -706,8 +706,9 @@ class SiddhiAppRuntime:
 
     def dump_trace(self, path: str) -> str:
         """Export collected spans as Chrome trace-event JSON
-        (Perfetto-loadable).  Spans cover parse → plan → jit-compile →
-        ingest chunk → kernel step → match scatter → callback."""
+        (Perfetto-loadable): the ledger's spans (core/ledger.py), from
+        parse and plan over each delivered block's stages and named
+        sub-spans to the callback's publish."""
         from .tracing import tracer
         return tracer().export(path)
 
@@ -768,15 +769,16 @@ class SiddhiManager:
         the manager's persistence store before returning (crash
         recovery); the revision restored is reported on
         ``rt.recovered_revision`` (None when the store holds none)."""
-        from .tracing import trace_span
+        from .ledger import ledger
+        span = ledger().span        # without a stage: these only annotate
         app_string = app if isinstance(app, str) else None
         if isinstance(app, str):
-            with trace_span("parse", cat="compile", chars=len(app)):
+            with span(None, "parse"):
                 app = SiddhiCompiler.parse(app)
         analysis = None
         try:
             from ..analysis import analyze
-            with trace_span("analyze", cat="compile"):
+            with span(None, "analyze"):
                 analysis = analyze(app)
         except Exception:   # noqa: BLE001 — advisory pass must never
             # take down app creation (strict mode excepted below)
@@ -784,7 +786,7 @@ class SiddhiManager:
                 raise
         if strict and analysis is not None:
             analysis.raise_if(strict=True)
-        with trace_span("plan", cat="compile", app=app.name or "?"):
+        with span(None, "plan"):
             rt = SiddhiAppRuntime(app, self.siddhi_context, app_string)
         rt.analysis = analysis
         # plan-level verifier (analysis/plan_verify.py): automaton
@@ -795,7 +797,7 @@ class SiddhiManager:
         # would tax app creation.
         try:
             from ..analysis.plan_verify import attach_plan_analysis
-            with trace_span("plan.verify", cat="compile"):
+            with span(None, "plan.verify"):
                 attach_plan_analysis(rt)
         except Exception:   # noqa: BLE001 — advisory pass must never
             # take down app creation (strict mode excepted below)
@@ -808,7 +810,7 @@ class SiddhiManager:
         # and is the artifact t1_report digests for drift tracking
         try:
             from ..analysis.state_schema import attach_schema_analysis
-            with trace_span("schema", cat="compile"):
+            with span(None, "schema"):
                 attach_schema_analysis(rt, strict=strict)
         except Exception:   # noqa: BLE001 — advisory pass must never
             # take down app creation (strict mode excepted below)
@@ -822,7 +824,7 @@ class SiddhiManager:
         # sentinels (core/numguard.py)
         try:
             from ..analysis.ranges import attach_numeric_analysis
-            with trace_span("numeric", cat="compile"):
+            with span(None, "numeric"):
                 attach_numeric_analysis(rt)
         except Exception:   # noqa: BLE001 — advisory pass must never
             # take down app creation (strict mode excepted below)

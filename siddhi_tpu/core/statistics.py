@@ -85,20 +85,27 @@ class Histogram:
         self._lock = threading.Lock()
 
     def record(self, v: int) -> None:
-        v = int(v)
-        if v < 0:
-            v = 0
-        idx = _bucket_index(v)
+        self.record_many((int(v),))
+
+    def record_many(self, values) -> None:
+        """Record each of ``values`` (ints; a negative one counts as 0)
+        under one lock: a deferred fold pays less than half per entry
+        of what a ``record`` each would."""
         with self._lock:
-            if idx >= len(self.counts):
-                self.counts.extend([0] * (idx + 1 - len(self.counts)))
-            self.counts[idx] += 1
-            self.count += 1
-            self.total += v
-            if self.min is None or v < self.min:
-                self.min = v
-            if v > self.max:
-                self.max = v
+            counts = self.counts
+            for v in values:
+                if v < 0:
+                    v = 0
+                idx = _bucket_index(v)
+                if idx >= len(counts):
+                    counts.extend([0] * (idx + 1 - len(counts)))
+                counts[idx] += 1
+                self.total += v
+                if self.min is None or v < self.min:
+                    self.min = v
+                if v > self.max:
+                    self.max = v
+            self.count += len(values)
 
     def percentile(self, q: float) -> float:
         """q in [0, 100] → bucket-midpoint estimate (≤ ~6% rel error)."""
@@ -531,8 +538,16 @@ LEDGER_TYPES = [
      "counter", "Exclusive wall time attributed to a pipeline stage"),
     ("siddhi_ledger_stage_spans_total",
      "counter", "Ledger span exits per pipeline stage"),
+    ("siddhi_ledger_span_seconds_total",
+     "counter", "Exclusive wall time of a named sub-span of a stage "
+     "(core/ledger.py SPAN_NAMES)"),
+    ("siddhi_retire_ready_total",
+     "counter", "In-flight blocks whose result was ready at their retire"),
+    ("siddhi_retire_blocked_total",
+     "counter", "In-flight blocks whose retire had to wait for the device"),
     ("siddhi_ledger_stage_latency_ms",
-     "gauge", "Per-app per-block stage latency quantiles (ms)"),
+     "gauge", "Per-app latency quantiles (ms): a stage per block, a named "
+     "sub-span per execution, a wait per block in flight"),
     ("siddhi_event_time_lag_ms",
      "gauge", "Max admitted event timestamp vs wall/playback clock"),
     ("siddhi_processing_lag_ms",

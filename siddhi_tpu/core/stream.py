@@ -12,6 +12,7 @@ bounded queue + worker thread that re-batches pending events into larger chunks
 """
 from __future__ import annotations
 
+import itertools
 import logging
 import queue
 import threading
@@ -31,7 +32,6 @@ from .hotpath import hot_path
 from .lockwitness import maybe_wrap
 from .profiling import rim_stats
 from .threads import engine_thread_name
-from .tracing import tracer as _tracer
 
 log = logging.getLogger(__name__)
 
@@ -39,6 +39,10 @@ FAULT_PREFIX = "!"
 
 _RIM = rim_stats()
 _LED = _ledger()
+
+# dequeue sequence numbers of delivered chunks, one series per process so
+# that a block's spans can be told apart across junctions in one trace
+_BLOCK_SEQ = itertools.count(1)
 
 
 class StreamCallback:
@@ -295,7 +299,10 @@ class StreamJunction:
         delivered = False   # forced drain-timeout stop while we may still
         while not self._stop.is_set():  # be wedged inside a receiver
             try:
-                item = q.get(timeout=0.1)
+                # an idle device under an idle worker reads "waiting
+                # for a send" in a trace, not untraced Python
+                with _LED.span(None, "queue.idle"):
+                    item = q.get(timeout=0.1)
             except queue.Empty:
                 if delivered:
                     self._flush_receivers()
@@ -324,6 +331,7 @@ class StreamJunction:
                 batch.append(nxt)
                 n += len(nxt)
             merged = EventChunk.concat(batch) if len(batch) > 1 else batch[0]
+            merged.block_seq = next(_BLOCK_SEQ)
             if ledger_enabled():
                 # queue stage: enqueue stamp -> this dequeue, per popped
                 # chunk; the merged chunk restarts its timeline here so
@@ -334,7 +342,11 @@ class StreamJunction:
                         _LED.record("queue", now_ns - c.ledger_ns)
                 merged.ledger_ns = now_ns
             try:
-                self._deliver(merged)
+                # one block's whole delivery: what the worker does when
+                # no finer span says
+                with _LED.span(None, "deliver", merged.block_seq,
+                               self.app_ctx.name):
+                    self._deliver(merged)
                 delivered = True
                 if barrier is not None:
                     delivered = False
@@ -519,8 +531,12 @@ class StreamJunction:
 
     @hot_path("per-block fan-out to every subscriber")
     def _deliver(self, chunk: EventChunk):
-        tr = _tracer()
         led = _LED if ledger_enabled() else None
+        if led is not None and chunk.block_seq is None and \
+                led.current_block() is None:
+            # a synchronous send is a block of its own; a chunk a query
+            # emits inside a delivery stays with that delivery's block
+            chunk.block_seq = next(_BLOCK_SEQ)
         if led is not None and chunk.ledger_ns is not None:
             # dispatch gap: boundary stamp (dequeue / junction entry) ->
             # delivery start; consumed so a re-routed chunk (fault
@@ -529,27 +545,19 @@ class StreamJunction:
             chunk.ledger_ns = None
         for r in list(self.receivers):
             try:
-                if tr.enabled:
-                    with tr.span("callback" if isinstance(
-                            r, (StreamCallback, QueryCallback))
-                            else "deliver",
-                            stream=self.definition.id, n=len(chunk),
-                            receiver=type(r).__name__):
-                        self._recv_one(r, chunk, led)
-                else:
-                    self._recv_one(r, chunk, led)
+                self._recv_one(r, chunk, led)
             except Exception as e:  # noqa: BLE001 — @OnError boundary
                 self._handle_error(chunk, e, receiver=r)
 
-    @staticmethod
-    def _recv_one(r, chunk: EventChunk, led):
+    def _recv_one(self, r, chunk: EventChunk, led):
         if led is None:
             r.receive_chunk(chunk)
             return
         # dispatch stage (exclusive): junction fan-out + host-side query
         # processing; the device/decode/publish work nested inside the
-        # receiver carries its own spans and is subtracted automatically
-        with led.span("dispatch"):
+        # receiver carries its own spans and is subtracted automatically.
+        # The spans nested inside take the app and the block from here
+        with led.span("dispatch", None, chunk.block_seq, self.app_ctx.name):
             r.receive_chunk(chunk)
 
     def _handle_error(self, chunk: EventChunk, e: Exception, receiver=None):
@@ -796,7 +804,7 @@ class InputHandler:
             _LED.note_ingress(self.app_ctx.name, self.definition.id,
                               mx, clock_ms, now - t0)
             chunk.ledger_ns = now
-        with _tracer().span("ingest.chunk", stream=self.definition.id, n=n):
+        with _LED.span(None, "ingest.chunk"):
             self.junction.send(chunk)
         if self.app_ctx.timestamp_generator.in_playback:
             self.app_ctx.scheduler.advance_to(mx)

@@ -43,6 +43,7 @@ from ..query_api.definition import AttrType
 from ..query_api.expression import (And, Compare, CompareOp, Constant, IsNull,
                                     Not, Or, TimeConstant, Variable,
                                     variables_of)
+from ..core.ledger import ledger as _ledger
 from ..core.stateschema import (Carry, ListOf, Scalar, Struct,
                                 persistent_schema)
 from ..utils.errors import SiddhiAppCreationError, SiddhiAppRuntimeException
@@ -2159,21 +2160,25 @@ class CompiledPatternNFA:
         else:
             codes = np.asarray([self.stream_codes[s] for s in stream_names],
                                np.int32)
+        led = _ledger()
         cols = {}
-        for a in self.attr_names:
-            if a in self.derived and a not in columns:
-                c = self.derived_lane(a, columns[self.derived[a][0]])
-            elif a in self.int_exact_src and a not in columns:
-                c = self.int_exact_lane(a, columns[self.int_exact_src[a]])
-            else:
-                c = columns[a]
-                if a in self.encoded_attrs:
-                    c = self.encode_column(c)
-            cols[a] = np.asarray(c)
-        block = pack_blocks(np.asarray(partition_ids), cols,
-                            np.asarray(timestamps), codes,
-                            self.n_partitions, base_ts=self.base_ts,
-                            pad_t_pow2=pad_t_pow2)
+        with led.span("device", "encode"):
+            for a in self.attr_names:
+                if a in self.derived and a not in columns:
+                    c = self.derived_lane(a, columns[self.derived[a][0]])
+                elif a in self.int_exact_src and a not in columns:
+                    c = self.int_exact_lane(
+                        a, columns[self.int_exact_src[a]])
+                else:
+                    c = columns[a]
+                    if a in self.encoded_attrs:
+                        c = self.encode_column(c)
+                cols[a] = np.asarray(c)
+        with led.span("device", "pack"):
+            block = pack_blocks(np.asarray(partition_ids), cols,
+                                np.asarray(timestamps), codes,
+                                self.n_partitions, base_ts=self.base_ts,
+                                pad_t_pow2=pad_t_pow2)
         if bucket is not None:
             # cross-tenant super-dispatch (plan/xtenant.py): defer the
             # block into the tenant's bucket — the gang step runs it

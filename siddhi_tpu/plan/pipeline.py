@@ -10,7 +10,8 @@ ingest/compute overlap of the reference's @Async disruptor junction
 (stream/StreamJunction.java:280-316).
 
 Contract for subclasses:
-  - call ``_init_pipeline(app, stream_ids)`` after ``self.qr`` is set;
+  - call ``_init_pipeline(app, stream_ids)`` after ``self.qr`` is set,
+    and set ``self.app_name`` (the ledger's per-app histograms);
   - dispatch device work in ``ingest`` and hand the un-read handles to
     ``_submit(work)``;
   - implement ``_retire(work)`` — block on the handles, decode, emit
@@ -28,11 +29,14 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from collections import deque
 from typing import Any, Dict, Iterable, List, Optional
 
 from ..core.ledger import ledger as _ledger
 from ..query_api.annotation import find_annotation
+
+_LED = _ledger()
 
 DEFAULT_DEPTH = 4
 
@@ -61,6 +65,39 @@ def resolve_depth(app, junctions: Iterable[Any]) -> int:
     return 0
 
 
+# ---------------------------------------------- the waits of a block in flight
+# Every in-flight handle/work (the pattern runtime's and the ones below)
+# carries ``t_submit`` (ns, when it was appended), ``seq`` (the junction's
+# dequeue sequence number of its block) and, where its step is not issued
+# at once, ``t_issue`` (a gang tenant: stamped at its bucket's flush).
+
+def stamp_submit(work: Dict[str, Any]) -> None:
+    stamp = _LED.stamp()
+    if stamp is not None:
+        work["t_submit"], work["seq"] = stamp
+
+
+def note_retire(app: str, work: Dict[str, Any], buf: Any) -> None:
+    """The start of ``work``'s retire: bank ``wait.defer`` and
+    ``wait.inflight`` and count whether the result the retire is about to
+    read was already there (``jax.Array.is_ready()``, before anything
+    blocks on it).  ``buf``: a device buffer of that result (the outputs
+    of one step are there together), or None where nothing is on the
+    device (a dead automaton's handle), which is ready."""
+    t_submit = work.get("t_submit")
+    if t_submit is None:        # dispatched with the ledger off
+        return
+    t_retire = time.perf_counter_ns()
+    if "xpend" in work:
+        # a gang tenant's block that no flush has launched yet (depth 0,
+        # or the last block before a flush): the retire launches it
+        _LED.note_retire(app, t_submit, t_retire, t_retire, False)
+    else:
+        is_ready = getattr(buf, "is_ready", None)
+        _LED.note_retire(app, t_submit, work.get("t_issue", t_submit),
+                         t_retire, is_ready is None or is_ready())
+
+
 class PipelinedDeviceIngest:
     """In-flight chunk queue: dispatch now, read/decode ``depth`` chunks
     later (FIFO, so emission order is preserved)."""
@@ -77,10 +114,17 @@ class PipelinedDeviceIngest:
     def _submit(self, work: Dict[str, Any]) -> None:
         if self._watchdog is not None:
             self._watchdog.note_progress()
+        stamp_submit(work)
         self._inflight.append(work)
         while len(self._inflight) > self.pipeline_depth:
-            with _ledger().span("decode"):
-                self._retire(self._inflight.popleft())
+            self._retire_oldest()
+
+    def _retire_oldest(self) -> None:
+        work = self._inflight.popleft()
+        with _LED.span("decode", None, work.get("seq"), self.app_name):
+            outs = work.get("outs")
+            note_retire(self.app_name, work, outs[0] if outs else None)
+            self._retire(work)
 
     def flush(self) -> None:
         """Retire every in-flight chunk: called on idle/drain by the
@@ -88,8 +132,7 @@ class PipelinedDeviceIngest:
         (re-entrant) — state reads can race the junction worker."""
         with self.qr.lock:
             while self._inflight:
-                with _ledger().span("decode"):
-                    self._retire(self._inflight.popleft())
+                self._retire_oldest()
 
     def _retire(self, work: Dict[str, Any]) -> None:
         raise NotImplementedError
@@ -129,6 +172,10 @@ class _FuseGroup:
         if self.sealed:
             return
         self.sealed = True
+        with _LED.span(None, "egress_d2h.seal"):
+            self._seal()
+
+    def _seal(self) -> None:
         import jax
         import jax.numpy as jnp
         pieces = []
@@ -163,7 +210,7 @@ class _FuseGroup:
                 self.fuser._rotate()
             self.seal()
             if self._host is None and self._slab is not None:
-                with _ledger().span("egress_d2h"):
+                with _LED.span("egress_d2h"):
                     self._host = np.asarray(self._slab)   # the ONE D2H
                 self.fuser.d2h_count += 1
                 self.fuser.last_slab_bytes = self._host.nbytes

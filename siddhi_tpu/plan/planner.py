@@ -38,7 +38,7 @@ from ..core.ledger import ledger as _ledger
 from ..core.stateschema import Keyed, persistent_schema
 from ..parallel.shards import build_shards, resolve_shards, split_rows
 from .nfa_compiler import CompiledPatternNFA
-from .pipeline import PipelinedDeviceIngest
+from .pipeline import PipelinedDeviceIngest, note_retire, stamp_submit
 
 ENGINE_ENV = "SIDDHI_TPU_ENGINE"
 DEFAULT_SLOTS = 8
@@ -88,8 +88,7 @@ def _record_block(rt_obj, prof, disp0: int, ticks0: int, stream: str,
     # flight ring will actually store it)
     led = ledger()
     ledger_row = led.note_block(rt_obj.app_name, rt_obj, runtime=app,
-                                want_row=fl.enabled) \
-        if led.enabled else None
+                                want_row=fl.enabled)
     if not fl.enabled:
         return
     sched = getattr(app.app_ctx, "scheduler", None) if app is not None \
@@ -106,8 +105,6 @@ def _record_block(rt_obj, prof, disp0: int, ticks0: int, stream: str,
         # the gang launch (flight rows already carry the app label)
         extra = dict(extra or {}, xtenant={"bucket": bucket.label,
                                            "tenants": len(bucket.tenants)})
-    if ledger_row:
-        extra = dict(extra or {}, ledger=ledger_row)
     # rim-vs-kernel ms split: delta of the always-on host-rim clock (and,
     # when profiling is on, the kernel dispatch clock) since this
     # runtime's previous block — per-block attribution for the ring
@@ -122,7 +119,8 @@ def _record_block(rt_obj, prof, disp0: int, ticks0: int, stream: str,
     rt_obj._flight_kern_ns0 = kern_now
     fl.record_block(rt_obj.app_name, stream=stream, batch=batch,
                     dispatches=d, scan_ticks=t, junction=junction,
-                    scheduler=sched, telemetry=telemetry, extra=extra)
+                    scheduler=sched, telemetry=telemetry, extra=extra,
+                    ledger=ledger_row)
 
 
 class KeyLanes(dict):
@@ -555,6 +553,7 @@ class DevicePatternRuntime:
         if data.is_empty:
             return
         prof = profiler()
+        led = _ledger()
         disp0 = prof.total_dispatches() if prof.enabled else 0
         ticks0 = prof.total_scan_ticks() if prof.enabled else 0
         n = len(data)
@@ -564,20 +563,22 @@ class DevicePatternRuntime:
                 raise SiddhiAppCreationError(
                     f"device pattern path: stream '{stream_id}' has no "
                     f"partition key executor")
-            keys = ex.keys(data)
-            keep = np.asarray([k is not None for k in keys], bool)
-            if not keep.all():
-                data = data.mask(keep)
-                keys = [k for k in keys if k is not None]
-                n = len(data)
-                if n == 0:
-                    return
+            with led.span("dispatch", "keys"):
+                keys = ex.keys(data)
+                keep = np.asarray([k is not None for k in keys], bool)
+                if not keep.all():
+                    data = data.mask(keep)
+                    keys = [k for k in keys if k is not None]
+                    n = len(data)
+            if n == 0:
+                return
             if self.shards is not None:
                 self._ingest_sharded(stream_code, data, keys, n)
                 _record_block(self, prof, disp0, ticks0, stream_id, n,
                               junction=self._junctions.get(stream_id))
                 return
-            pids = self._lanes_for_keys(keys)
+            with led.span("dispatch", "lanes"):
+                pids = self._lanes_for_keys(keys)
         else:
             pids = np.zeros(n, np.int64)
         if self.nfa.mesh is not None:
@@ -590,13 +591,15 @@ class DevicePatternRuntime:
                 self._ub_active = actual
             self._ub_active = min(self._ub_active + t_max,
                                   self.nfa.spec.n_slots)
-        cols = self._event_cols(data, n)
-        ts_arr = np.asarray(data.timestamps, np.int64)
-        codes = np.full(n, stream_code, np.int32)
-        with _ledger().span("device"):
+        with led.span("dispatch", "cols"):
+            cols = self._event_cols(data, n)
+            ts_arr = np.asarray(data.timestamps, np.int64)
+            codes = np.full(n, stream_code, np.int32)
+        with led.span("device"):
             h = self.nfa.dispatch_events(pids, cols, ts_arr,
                                          stream_codes=codes,
                                          pad_t_pow2=True)
+        stamp_submit(h)
         self._inflight.append(h)
         # retire down to the pipeline depth: with depth 0 this is the old
         # synchronous behavior (matches delivered before ingest returns);
@@ -618,7 +621,9 @@ class DevicePatternRuntime:
         replay it and every later in-flight chunk), decode columnar,
         emit."""
         h = self._inflight.popleft()
-        with _ledger().span("device"):
+        note_retire(self.app_name, h, h.get("buf"))
+        with _ledger().span("device", "retire", block=h.get("seq"),
+                            app=self.app_name):
             pids, ts, cols = self.nfa.retire_events(h)
         if self._telemetry_sink is not None and \
                 self.nfa.last_telemetry is not None:
@@ -660,7 +665,7 @@ class DevicePatternRuntime:
                 self._schedule_absent(self.nfa.last_min_deadline)
             return
         self._dropped_seen = max(dropped, self._dropped_seen)
-        self._emit_columns(pids, ts, cols)
+        self._emit_columns(pids, ts, cols, h.get("seq"))
         if self.nfa.has_absent:
             # schedule off the retired chunk's carry — the deadline rode
             # the egress tail, no extra device read (see egress_dispatch)
@@ -678,16 +683,16 @@ class DevicePatternRuntime:
             while self._inflight:
                 self._retire_one()
 
-    def _emit_columns(self, pids, ts, cols) -> None:
+    def _emit_columns(self, pids, ts, cols, block=None) -> None:
         from ..core.event import EventChunk
-        from ..core.tracing import trace_span
         if not len(ts):
             return
         names = [o[0] for o in self.nfa.select_outputs]
-        # no ledger span here: every call site sits under the pipeline's
-        # "decode" span already (pipeline.py _submit/flush), and the
-        # downstream head.process work carries its own nested spans
-        with trace_span("match.scatter", n=int(len(ts))):
+        # no stage span here (the downstream head.process work carries
+        # its own nested spans): an annotation that hands the retired
+        # block's id on to them
+        with _ledger().span(None, "match.scatter", block=block,
+                            app=self.app_name):
             self.head.process(EventChunk.from_columns(names, ts, cols))
 
     def _emit(self, matches) -> None:
@@ -954,42 +959,50 @@ class DeviceWindowedAggRuntime(PipelinedDeviceIngest):
         if data.is_empty:
             return
         prof = profiler()
+        led = _ledger()
         disp0 = prof.total_dispatches() if prof.enabled else 0
         ticks0 = prof.total_scan_ticks() if prof.enabled else 0
-        keys = self.key_executor.keys(data)
-        keep = np.asarray([k is not None for k in keys], bool)
-        if not keep.all():
-            data = data.mask(keep)
-            keys = [k for k in keys if k is not None]
-            if data.is_empty:
-                return
+        with led.span("dispatch", "keys"):
+            keys = self.key_executor.keys(data)
+            keep = np.asarray([k is not None for k in keys], bool)
+            if not keep.all():
+                data = data.mask(keep)
+                keys = [k for k in keys if k is not None]
+        if data.is_empty:
+            return
         n = len(data)
         if self.shards is not None:
             self._ingest_sharded(data, keys)
             _record_block(self, prof, disp0, ticks0, stream_id, n)
             return
-        lanes = map_keys_to_lanes(self.key_lanes, keys,
-                                  self.cwa.n_partitions, self._grow)
+        with led.span("dispatch", "lanes"):
+            lanes = map_keys_to_lanes(self.key_lanes, keys,
+                                      self.cwa.n_partitions, self._grow)
         P = self.cwa.n_partitions
-        cols = {a.name: np.asarray(data.columns[a.name])
-                for a in self.cwa.input_definition.attributes
-                if a.name in data.columns and
-                data.columns[a.name].dtype != object}
-        ts_arr = np.asarray(data.timestamps, np.int64)
-        block, rows = pack_blocks(lanes, cols, ts_arr,
-                                  np.zeros(n, np.int32), P,
-                                  base_ts=int(ts_arr[0]), pad_t_pow2=True,
-                                  return_rows=True)
-        if self.cwa.window_kind == "time":
-            # absolute i64 ts lanes: the time kernel's expiry must be
-            # comparable ACROSS blocks (packed __ts is per-block offsets);
-            # externalTime reads the event's ts attribute instead
-            src = (np.asarray(data.columns[self.cwa.ts_attr], np.int64)
-                   if self.cwa.ts_attr else ts_arr)
-            ts64 = np.zeros(block["__ts"].shape, np.int64)
-            ts64[lanes, rows] = src
-            block["__ts64"] = ts64
-        with _ledger().span("device"):
+        with led.span("dispatch", "cols"):
+            cols = {a.name: np.asarray(data.columns[a.name])
+                    for a in self.cwa.input_definition.attributes
+                    if a.name in data.columns and
+                    data.columns[a.name].dtype != object}
+            ts_arr = np.asarray(data.timestamps, np.int64)
+        # this runtime packs under `dispatch`, the pattern runtime under
+        # `device` (inside dispatch_events): the sub-span is named for
+        # the stage it is in
+        with led.span("dispatch", "pack"):
+            block, rows = pack_blocks(lanes, cols, ts_arr,
+                                      np.zeros(n, np.int32), P,
+                                      base_ts=int(ts_arr[0]),
+                                      pad_t_pow2=True, return_rows=True)
+            if self.cwa.window_kind == "time":
+                # absolute i64 ts lanes: the time kernel's expiry must
+                # be comparable ACROSS blocks (packed __ts is per-block
+                # offsets); externalTime reads the event's ts attribute
+                src = (np.asarray(data.columns[self.cwa.ts_attr], np.int64)
+                       if self.cwa.ts_attr else ts_arr)
+                ts64 = np.zeros(block["__ts"].shape, np.int64)
+                ts64[lanes, rows] = src
+                block["__ts64"] = ts64
+        with led.span("device"):
             outs = self.cwa.process_block(block)
         token = None
         if self._fuser is not None:
@@ -1062,11 +1075,15 @@ class DeviceWindowedAggRuntime(PipelinedDeviceIngest):
         outs, data = work["outs"], work["data"]
         lanes, rows = work["lanes"], work["rows"]
         n = len(data)
-        if work.get("fuse") is not None:
-            outs = work["fuse"].fetch()
-        else:
-            with _ledger().span("egress_d2h"):
-                outs = [np.asarray(o) for o in outs]
+        # fetching the step's outputs: this runtime's counterpart of the
+        # pattern runtime's `device.retire`, named for the stage it is in
+        # (the D2H read inside it keeps its own)
+        with _ledger().span("decode", "fetch"):
+            if work.get("fuse") is not None:
+                outs = work["fuse"].fetch()
+            else:
+                with _ledger().span("egress_d2h"):
+                    outs = [np.asarray(o) for o in outs]
         sums = outs[0]
         counts = outs[1]
         mins = outs[2] if len(outs) > 2 else None
@@ -1729,8 +1746,7 @@ class DeviceFilterRuntime(PipelinedDeviceIngest):
             [o[0] for o in self.outputs],
             np.asarray(chunk.timestamps)[ok], out_cols,
             types=chunk.types[ok])
-        from ..core.tracing import trace_span
-        with trace_span("match.scatter", n=len(out)):
+        with _ledger().span(None, "match.scatter"):
             self.head.process(out)
 
     # ------------------------------------------------------------ lifecycle

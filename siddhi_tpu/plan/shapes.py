@@ -56,6 +56,10 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from ..core.ledger import ledger as _ledger
+
+_span = _ledger().span
+
 #: Where the persistent compile cache lives unless JAX was told
 #: otherwise (``JAX_COMPILATION_CACHE_DIR``): one fixed path inside the
 #: checkout, so every process of a command — and the next command —
@@ -174,6 +178,16 @@ def configure_compile_cache() -> Dict[str, Any]:
         return dict(_CACHE_STATE)
 
 
+#: jax.monitoring duration event -> the phase whose ShapeEntry field
+#: ``<phase>_seconds`` it is credited to beside ``compile_seconds``
+#: (jax/_src/dispatch.py names the three)
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+
+
 # ------------------------------------------------------------ entries
 
 class ShapeEntry:
@@ -182,6 +196,7 @@ class ShapeEntry:
     is all the exposition needs."""
 
     __slots__ = ("signature", "kind", "dims", "compiles", "compile_seconds",
+                 "trace_seconds", "lower_seconds", "backend_seconds",
                  "blocked_seconds", "cache_hits", "cache_misses", "calls",
                  "triggers", "last_trigger", "last_compile_unix", "prewarmed")
 
@@ -190,7 +205,11 @@ class ShapeEntry:
         self.kind = kind
         self.dims = dict(dims)
         self.compiles = 0              # XLA compiles (incl. retraces)
-        self.compile_seconds = 0.0     # attributed trace+compile seconds
+        self.compile_seconds = 0.0     # attributed trace+compile seconds,
+        # the sum of its three phases (jax.monitoring, _COMPILE_PHASES):
+        self.trace_seconds = 0.0       # Python -> jaxpr; paid on a
+        self.lower_seconds = 0.0       # cache hit too, as is jaxpr -> MLIR
+        self.backend_seconds = 0.0     # XLA compile, or the cache's load
         self.blocked_seconds = 0.0     # caller wall blocked on a compile
         self.cache_hits = 0            # persistent-cache hits
         self.cache_misses = 0
@@ -206,6 +225,9 @@ class ShapeEntry:
         return {"signature": self.signature, "kind": self.kind,
                 "dims": dict(self.dims), "compiles": self.compiles,
                 "compile_seconds": round(self.compile_seconds, 6),
+                "trace_seconds": round(self.trace_seconds, 6),
+                "lower_seconds": round(self.lower_seconds, 6),
+                "backend_seconds": round(self.backend_seconds, 6),
                 "blocked_seconds": round(self.blocked_seconds, 6),
                 "cache_hits": self.cache_hits,
                 "cache_misses": self.cache_misses,
@@ -254,12 +276,15 @@ class RegisteredJit:
     detects compiles via the jit's in-memory cache-size delta."""
 
     __slots__ = ("_jitted", "entry", "registry", "trigger",
-                 "_first_call_hook", "_last_cs")
+                 "_first_call_hook", "_last_cs", "_span")
 
     def __init__(self, jitted, entry: ShapeEntry, registry: "ShapeRegistry",
                  trigger: str, first_call_hook: Optional[Callable] = None):
         self._jitted = jitted
         self.entry = entry
+        # XLA modules keep their jit_<function> names; the host line of
+        # a trace names each launch by its registry kind
+        self._span = f"device.issue/{entry.kind}"
         self.registry = registry
         self.trigger = trigger
         self._first_call_hook = first_call_hook
@@ -285,7 +310,8 @@ class RegisteredJit:
         stack.append(self.entry)
         t0 = time.perf_counter_ns()
         try:
-            out = self._jitted(*args, **kwargs)
+            with _span(None, self._span):
+                out = self._jitted(*args, **kwargs)
         finally:
             t1 = time.perf_counter_ns()
             stack.pop()
@@ -439,7 +465,12 @@ class ShapeRegistry:
 
     def _credit_duration(self, event: str, secs: float) -> None:
         if event.startswith("/jax/core/compile/"):
-            self._frame_entry().compile_seconds += float(secs)
+            e = self._frame_entry()
+            e.compile_seconds += float(secs)
+            phase = _COMPILE_PHASES.get(event)
+            if phase is not None:
+                field = f"{phase}_seconds"
+                setattr(e, field, getattr(e, field) + float(secs))
 
     # ------------------------------------------------------------ prewarm
 
@@ -549,6 +580,9 @@ class ShapeRegistry:
         return {"shape_classes": len(es),
                 "compiles": sum(e.compiles for e in es),
                 "compile_seconds": sum(e.compile_seconds for e in es),
+                "trace_seconds": sum(e.trace_seconds for e in es),
+                "lower_seconds": sum(e.lower_seconds for e in es),
+                "backend_seconds": sum(e.backend_seconds for e in es),
                 "blocked_seconds": sum(e.blocked_seconds for e in es),
                 "cache_hits": sum(e.cache_hits for e in es),
                 "cache_misses": sum(e.cache_misses for e in es)}
@@ -580,6 +614,12 @@ class ShapeRegistry:
             lines.append(
                 f"siddhi_compile_seconds_total{lb} "
                 f"{e.compile_seconds:.9g}")
+            for phase in _COMPILE_PHASES.values():
+                lines.append(
+                    f"siddhi_compile_phase_seconds_total"
+                    f'{{kind="{e.kind}",signature="{e.signature}",'
+                    f'phase="{phase}"}} '
+                    f'{getattr(e, f"{phase}_seconds"):.9g}')
             lines.append("siddhi_compile_blocked_seconds_total"
                          f"{lb} {e.blocked_seconds:.9g}")
             lines.append(f"siddhi_compile_total{lb} {e.compiles}")
@@ -620,6 +660,9 @@ class ShapeRegistry:
 SHAPES_TYPES = [
     ("siddhi_compile_seconds_total", "counter",
      "Attributed XLA trace+compile seconds per shape class"),
+    ("siddhi_compile_phase_seconds_total", "counter",
+     "The same seconds by phase: trace (Python to jaxpr), lower (jaxpr "
+     "to MLIR), backend (XLA compile, or the persistent cache's load)"),
     ("siddhi_compile_blocked_seconds_total", "counter",
      "Caller wall seconds blocked on a compile per shape class"),
     ("siddhi_compile_total", "counter",

@@ -46,8 +46,10 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from ..core.ledger import ledger as _ledger
 from ..core.lockwitness import maybe_wrap
 
 XTENANT_ENV = "SIDDHI_TPU_XTENANT"
@@ -208,6 +210,12 @@ class TenantBucket:
         entries = self.pending
         if not entries:
             return
+        # device.sync: everything of a flush but the gang call itself,
+        # which is a device.issue span of its own inside this one
+        with _ledger().span("device", "sync"):
+            self._gang_step(entries)
+
+    def _gang_step(self, entries: List[Tuple[Any, Dict, Dict]]) -> None:
         self.pending = []
         self._pending_ids = set()
         nfas = [e[0] for e in entries]
@@ -223,6 +231,7 @@ class TenantBucket:
         # planner's grow-and-replay can rewind ONE tenant without
         # re-stepping (or corrupting) its co-tenants
         pres = [(n.carry, n.base_ts) for n in nfas]
+        t_issue = time.perf_counter_ns()
         out = gang([n.carry for n in nfas], [e[1] for e in entries])
         self.flush_total += 1
         for (nfa, block, h), (nc, buf, outs, tele), (pc, pb), cap in \
@@ -242,7 +251,7 @@ class TenantBucket:
                      dl_st=nc["slot_state"] if nfa.has_absent else None,
                      dl=nc.get("deadline") if nfa.has_absent else None,
                      dl_base=h["base_ts"], tk=(int(T), int(K)), telem=tele,
-                     pre_carry=pc, pre_base=pb)
+                     pre_carry=pc, pre_base=pb, t_issue=t_issue)
             h.pop("xpend", None)
         if self.fuser is not None:
             # all co-scheduled tenants registered: one slab, one D2H

@@ -147,6 +147,35 @@ def test_registry_jit_attributes_compile_and_calls():
                and 'signature="test.kernel[n=7]"' in l for l in lines)
 
 
+def test_compile_seconds_split_by_phase_sum_to_the_whole():
+    """trace (Python to jaxpr), lower (jaxpr to MLIR) and backend (XLA's
+    compile, or the persistent cache's load) are what compile_seconds is
+    made of: per entry, in the totals and on /metrics."""
+    import jax.numpy as jnp
+    reg = shape_registry()
+    rj = reg.jit("test.phases", {"n": 3}, lambda x: jnp.tanh(x) @ x.T)
+    rj(jnp.ones((8, 8)))
+    rj(jnp.ones((16, 16)))                # a second shape: a re-trace
+    e = rj.entry
+    for f in ("trace_seconds", "lower_seconds", "backend_seconds"):
+        assert getattr(e, f) > 0, f
+    assert e.trace_seconds + e.lower_seconds + e.backend_seconds == \
+        pytest.approx(e.compile_seconds)
+    d = e.as_dict()
+    assert d["trace_seconds"] + d["lower_seconds"] + d["backend_seconds"] \
+        == pytest.approx(d["compile_seconds"], abs=2e-6)
+    tot = reg.totals()
+    assert tot["trace_seconds"] + tot["lower_seconds"] + \
+        tot["backend_seconds"] == pytest.approx(tot["compile_seconds"])
+    lines = [ln for ln in reg.prometheus_lines()
+             if ln.startswith("siddhi_compile_phase_seconds_total")
+             and 'kind="test.phases"' in ln]
+    assert sorted(ln.split('phase="')[1].split('"')[0] for ln in lines) \
+        == ["backend", "lower", "trace"]
+    assert sum(float(ln.rsplit(" ", 1)[1]) for ln in lines) == \
+        pytest.approx(e.compile_seconds, rel=1e-6)
+
+
 def test_adopt_tallies_triggers_per_rebuild():
     import jax
     reg = shape_registry()
