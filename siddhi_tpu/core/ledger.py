@@ -125,6 +125,18 @@ ANNOTATIONS = ("queue.idle", "deliver", "ingest.chunk", "egress_d2h.seal",
 #: ``wait.inflight`` submit -> the start of its retire
 WAITS = ("wait.defer", "wait.inflight")
 
+#: the per-app retire counters, in the order of a ``_retires`` row.  The
+#: first two: was the result there when the retire began.  The last
+#: three: what made the block leave its queue (plan/pipeline.py) — a
+#: check that does not wait found its result ready (a submit's, or the
+#: junction worker's idle hook); a submit found the queue over its cap
+#: and blocked on the oldest; a blocking ``flush()``.  A cause is its
+#: counter's place in the row.
+RETIRE_COUNTERS = ("retire_ready_total", "retire_blocked_total",
+                   "retire_on_ready_total", "retire_on_depth_total",
+                   "retire_on_flush_total")
+ON_READY, ON_DEPTH, ON_FLUSH = 2, 3, 4
+
 
 # os.environ.get pays ~0.9 us per call (key encode + value decode);
 # the ledger asks "am I on?" ~10x per ingest block, so that alone would
@@ -564,11 +576,13 @@ class LatencyLedger:
         ent["lag_ms"] = float(now_ms - event_ts_ms)
 
     def note_retire(self, app: str, t_submit: Optional[int],
-                    t_issue: int, t_retire: int, ready: bool) -> None:
+                    t_issue: int, t_retire: int, ready: bool,
+                    cause: int) -> None:
         """One in-flight block starts its retire: bank its waits (ns
         stamps of this module's clock; a block with no submit stamp was
-        dispatched with the ledger off) and count whether its result was
-        already there."""
+        dispatched with the ledger off), count whether its result was
+        already there, and what caused the retire (``ON_READY``,
+        ``ON_DEPTH`` or ``ON_FLUSH``)."""
         named = self._named
         if t_submit is not None:
             named.append((app, "wait.inflight", t_retire - t_submit))
@@ -576,8 +590,10 @@ class LatencyLedger:
         row = self._retires.get(app)
         if row is None:
             with self._lock:
-                row = self._retires.setdefault(app, [0, 0])
+                row = self._retires.setdefault(
+                    app, [0] * len(RETIRE_COUNTERS))
         row[0 if ready else 1] += 1
+        row[cause] += 1
 
     # ------------------------------------------------------ block fold
 
@@ -746,8 +762,7 @@ class LatencyLedger:
                 entry["last_block_ms"] = self._row_ms(last)
             row = self._retires.get(a)
             if row is not None:
-                entry["retire_ready_total"] = row[0]
-                entry["retire_blocked_total"] = row[1]
+                entry.update(zip(RETIRE_COUNTERS, row))
             per_app[a] = entry
         doc["apps"] = per_app
         return doc
@@ -768,8 +783,8 @@ class LatencyLedger:
                          f"{self._ns[key] / 1e9:.9g}")
         for app, row in sorted(self._retires.items()):
             lab = _fmt_labels({"app": app})
-            lines.append(f"siddhi_retire_ready_total{lab} {row[0]}")
-            lines.append(f"siddhi_retire_blocked_total{lab} {row[1]}")
+            for name, n in zip(RETIRE_COUNTERS, row):
+                lines.append(f"siddhi_{name}{lab} {n}")
         for (app, stage), h in sorted(self._hist.items()):
             if not h.count:
                 continue

@@ -70,17 +70,30 @@ class ProcessStreamReceiver:
         self.query_name = query_name
         self.app_ctx = app_ctx
 
-    def flush(self):
-        """Retire pipelined device work held anywhere in the processor
-        chain (device ingress heads, mid-chain device windows) under the
-        query lock — junction idle/drain hook."""
+    def _chain_call(self, method: str) -> bool:
+        """Call ``method`` under the query lock on every processor of the
+        chain that has it (device ingress heads, mid-chain device
+        windows); -> did any return true"""
+        busy = False
         p = self.first
         while p is not None:
-            f = getattr(p, "flush", None)
+            f = getattr(p, method, None)
             if f is not None:
                 with self.lock:
-                    f()
+                    busy = bool(f()) or busy
             p = getattr(p, "next", None)
+        return busy
+
+    def flush(self):
+        """Retire pipelined device work held anywhere in the processor
+        chain, blocking on it — junction barrier/drain hook."""
+        self._chain_call("flush")
+
+    def settle(self) -> bool:
+        """The junction worker's idle hook: launch what the delivery left
+        pending and retire what is ready, without waiting for the device.
+        -> is work still in flight"""
+        return self._chain_call("settle")
 
     def receive_chunk(self, chunk: EventChunk):
         dbg = getattr(self.app_ctx, "debugger", None) if self.app_ctx else None

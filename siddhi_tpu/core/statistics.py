@@ -545,6 +545,15 @@ LEDGER_TYPES = [
      "counter", "In-flight blocks whose result was ready at their retire"),
     ("siddhi_retire_blocked_total",
      "counter", "In-flight blocks whose retire had to wait for the device"),
+    ("siddhi_retire_on_ready_total",
+     "counter", "In-flight blocks retired because a check that does not "
+     "wait (a submit's, the junction's idle hook) found the result ready"),
+    ("siddhi_retire_on_depth_total",
+     "counter", "In-flight blocks retired because a submit found the "
+     "queue over its cap (@app:pipeline) and blocked on the oldest"),
+    ("siddhi_retire_on_flush_total",
+     "counter", "In-flight blocks retired by a blocking flush (barrier, "
+     "drain, state read)"),
     ("siddhi_ledger_stage_latency_ms",
      "gauge", "Per-app latency quantiles (ms): a stage per block, a named "
      "sub-span per execution, a wait per block in flight"),

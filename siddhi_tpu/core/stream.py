@@ -40,6 +40,13 @@ FAULT_PREFIX = "!"
 _RIM = rim_stats()
 _LED = _ledger()
 
+# the junction worker's waits on its queue: with nothing in flight, how
+# often it looks at the stop and drain flags; with device work in flight
+# after a delivery, how soon it asks again whether a result is ready (a
+# timed wait on the queue's condition, not a spin: a send wakes it)
+_IDLE_WAIT_S = 0.1
+_SETTLE_POLL_S = 0.001
+
 # dequeue sequence numbers of delivered chunks, one series per process so
 # that a block's spans can be told apart across junctions in one trace
 _BLOCK_SEQ = itertools.count(1)
@@ -292,26 +299,35 @@ class StreamJunction:
     def _worker_loop(self):
         """Re-batches queued chunks up to batch_size_max before delivery
         (reference util/event/handler/StreamHandler.java re-batching).
-        When the queue goes idle (or on drain), flushes receivers that
-        pipeline device work (plan/planner.py DevicePatternRuntime) so
-        deferred matches never hang waiting for the next event."""
+        Receivers that pipeline device work (plan/pipeline.py) deliver a
+        block's rows when its result is ready: the moment the queue is
+        found empty after a delivery the worker settles them (launch
+        what is pending, retire what is ready — never waiting for the
+        device) and, while any still has work in flight, settles again
+        every ``_SETTLE_POLL_S`` between looks at the queue, so a send
+        that arrives is taken at once and deferred matches never hang
+        waiting for the next event.  A barrier, and the drain, flush
+        (blocking)."""
         q = self._queue     # local ref: stop() clears the attribute on a
-        delivered = False   # forced drain-timeout stop while we may still
+        inflight = False    # forced drain-timeout stop while we may still
         while not self._stop.is_set():  # be wedged inside a receiver
             try:
-                # an idle device under an idle worker reads "waiting
-                # for a send" in a trace, not untraced Python
-                with _LED.span(None, "queue.idle"):
-                    item = q.get(timeout=0.1)
+                item = q.get_nowait()
             except queue.Empty:
-                if delivered:
-                    self._flush_receivers()
-                    delivered = False
-                if self._drain.is_set():
-                    break       # drained: queue empty after drain request
-                continue
+                if inflight:
+                    inflight = self._settle_receivers()
+                try:
+                    # an idle device under an idle worker reads "waiting
+                    # for a send" in a trace, not untraced Python
+                    with _LED.span(None, "queue.idle"):
+                        item = q.get(timeout=_SETTLE_POLL_S if inflight
+                                     else _IDLE_WAIT_S)
+                except queue.Empty:
+                    if self._drain.is_set():
+                        break   # drained: queue empty after drain request
+                    continue
             if isinstance(item, _FlushBarrier):
-                delivered = False
+                inflight = False
                 try:
                     item.arrive(self._flush_receivers)
                 finally:
@@ -347,9 +363,9 @@ class StreamJunction:
                 with _LED.span(None, "deliver", merged.block_seq,
                                self.app_ctx.name):
                     self._deliver(merged)
-                delivered = True
+                inflight = True
                 if barrier is not None:
-                    delivered = False
+                    inflight = False
                     barrier.arrive(self._flush_receivers)
             finally:
                 # one task_done per popped item: the batch's extra pops
@@ -357,18 +373,28 @@ class StreamJunction:
                 for _ in range(len(batch) + (1 if barrier is not None
                                              else 0)):
                     q.task_done()
-        if delivered:
+        if inflight:
             self._flush_receivers()
 
-    def _flush_receivers(self):
+    def _each_receiver(self, method: str) -> bool:
+        """Call ``method`` on every receiver that has it, errors to the
+        @OnError boundary; -> did any return true"""
+        busy = False
         for r in list(self.receivers):
-            f = getattr(r, "flush", None)
+            f = getattr(r, method, None)
             if f is not None:
                 try:
-                    f()
+                    busy = bool(f()) or busy
                 except Exception as e:  # noqa: BLE001 — @OnError boundary
                     self._handle_error(
                         EventChunk.empty(self.definition.attribute_names), e)
+        return busy
+
+    def _flush_receivers(self):
+        self._each_receiver("flush")
+
+    def _settle_receivers(self) -> bool:
+        return self._each_receiver("settle")
 
     def flush(self):
         """Synchronous flush: when this returns, every chunk already sent

@@ -78,9 +78,8 @@ class WindowProcessor(Processor):
     def _locked(self, fn, *args):
         if self.lock is not None:
             with self.lock:
-                fn(*args)
-        else:
-            fn(*args)
+                return fn(*args)
+        return fn(*args)
 
     # -------------------------------------------------------------- find (joins)
 

@@ -38,6 +38,7 @@ from ..query_api.definition import AttrType
 from ..query_api.expression import Constant, TimeConstant, Variable
 from ..utils.errors import (SiddhiAppCreationError,
                             SiddhiAppRuntimeException)
+from .pipeline import retire_after_submit, settle_inflight
 
 DEVICE_KINDS = ("length", "lengthBatch", "time", "timeBatch",
                 "externalTime", "externalTimeBatch", "timeLength",
@@ -643,19 +644,33 @@ class DeviceWindowProcessor(WindowProcessor):
 
     def _submit(self, work: dict) -> None:
         self._inflight.append(work)
-        while len(self._inflight) > self.pipeline_depth:
-            self._retire_work(self._inflight.popleft())
+        retire_after_submit(self._inflight, self.pipeline_depth,
+                            self._retire_head)
+
+    def _retire_head(self, cause: Optional[int] = None) -> None:
+        # (a window's works carry no submit stamp: their retires are not
+        # counted, whatever the cause)
+        self._retire_work(self._inflight.popleft())
+
+    def settle(self) -> bool:
+        """The junction worker's idle hook (plan/pipeline.py): retire the
+        works whose result is ready, without waiting for the device.
+        -> is work still in flight"""
+        if not self._inflight:
+            return False
+        return self._locked(settle_inflight, self._inflight,
+                            self._retire_head)
 
     def flush(self):
-        """Retire every in-flight chunk — called on junction idle/drain,
-        before timer steps, and before any state read.  Takes the OWNING
-        query's lock (RLock, re-entrant for the junction worker): cross-
-        query callers — a named-window join's find_chunk, store queries,
-        snapshots — run on other queries' threads and would otherwise
-        race the worker's _submit (review r5)."""
+        """Retire every in-flight chunk, blocking on each — a junction's
+        barrier and drain, before timer steps, and before any state read.
+        Takes the OWNING query's lock (RLock, re-entrant for the junction
+        worker): cross-query callers — a named-window join's find_chunk,
+        store queries, snapshots — run on other queries' threads and
+        would otherwise race the worker's _submit (review r5)."""
         def run():
             while self._inflight:
-                self._retire_work(self._inflight.popleft())
+                self._retire_head()
         self._locked(run)
 
     def _host_buf(self, work: dict) -> np.ndarray:

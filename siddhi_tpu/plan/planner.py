@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import os
 from collections import deque
+from functools import partial
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -34,11 +35,12 @@ from ..query_api.expression import Variable
 from ..query_api.query import OutputEventsFor
 from ..utils.errors import (SiddhiAppCreationError,
                             SiddhiAppRuntimeException)
-from ..core.ledger import ledger as _ledger
+from ..core.ledger import ON_FLUSH, ledger as _ledger
 from ..core.stateschema import Keyed, persistent_schema
 from ..parallel.shards import build_shards, resolve_shards, split_rows
 from .nfa_compiler import CompiledPatternNFA
-from .pipeline import PipelinedDeviceIngest, note_retire, stamp_submit
+from .pipeline import (PipelinedDeviceIngest, note_retire,
+                       retire_after_submit, settle_inflight, stamp_submit)
 
 ENGINE_ENV = "SIDDHI_TPU_ENGINE"
 DEFAULT_SLOTS = 8
@@ -279,6 +281,11 @@ class _DeviceIngress:
         if f is not None:
             f()
 
+    def settle(self) -> bool:
+        # the junction worker's idle hook: flush's counterpart that
+        # never waits for the device; -> is work still in flight
+        return self.runtime.settle()
+
 
 @persistent_schema(
     "keyed-pattern", version=1, schema=Keyed("nfa"),
@@ -376,15 +383,15 @@ class DevicePatternRuntime:
             app.junction_of(stream_id).subscribe(recv)
             qr.receivers[stream_id] = recv
 
-        # ingest pipelining: keep up to `depth` chunks in flight so the
-        # egress read round-trip overlaps later dispatches
-        # (plan/pipeline.py shares the depth contract).  Absent patterns
-        # pipeline too (round 5): the earliest pending deadline rides the
-        # egress tail, so the host TIMER is scheduled off the retired
-        # (chunk-delayed) carry with no extra device read — in-kernel
-        # deadline passes keep deadline-vs-event ordering exact for
-        # deadlines that expire during later chunks, and idle/drain
-        # flushes bound the wall-clock tail
+        # ingest pipelining: a chunk's handle stays in flight until its
+        # result is ready (at most `depth` of them), so the step and its
+        # egress read overlap later dispatches (plan/pipeline.py holds
+        # the rule).  Absent patterns pipeline too (round 5): the
+        # earliest pending deadline rides the egress tail, so the host
+        # TIMER is scheduled off the retired carry with no extra device
+        # read — in-kernel deadline passes keep deadline-vs-event
+        # ordering exact for deadlines that expire during later chunks,
+        # and the junction's idle settle bounds the wall-clock tail
         from .pipeline import resolve_depth, egress_fuser_for
         self._inflight: "deque" = deque()
         self.pipeline_depth = resolve_depth(
@@ -501,13 +508,14 @@ class DevicePatternRuntime:
             sh.inflight.append(h)
             sh.events += len(rows)
             sh.dispatches += 1
-            while len(sh.inflight) > self.pipeline_depth:
-                self._retire_shard(sh)
+            retire_after_submit(sh.inflight, self.pipeline_depth,
+                                partial(self._retire_shard, sh))
 
-    def _retire_shard(self, sh) -> None:
-        """Per-shard twin of _retire_one: block on the shard's oldest
+    def _retire_shard(self, sh, cause: Optional[int] = None) -> None:
+        """Per-shard twin of _retire_one: read the shard's oldest
         in-flight chunk; on slot-ring overflow rewind/grow/replay THIS
-        shard only."""
+        shard only.  (A shard's handles carry no submit stamp: their
+        retires are not counted, whatever the cause.)"""
         h = sh.inflight.popleft()
         eng = sh.engine
         with _ledger().span("device"):
@@ -601,27 +609,27 @@ class DevicePatternRuntime:
                                          pad_t_pow2=True)
         stamp_submit(h)
         self._inflight.append(h)
-        # retire down to the pipeline depth: with depth 0 this is the old
-        # synchronous behavior (matches delivered before ingest returns);
-        # with depth D the egress read of chunk N
-        # overlaps chunks N+1..N+D's dispatch (≙ the ingest/compute
+        # with depth 0 every chunk retires here (synchronous: matches
+        # delivered before ingest returns); with depth D the chunks whose
+        # result is ready retire here, in order, and D only caps how many
+        # may stay in flight while the device works (≙ the ingest/compute
         # overlap of the reference's @Async disruptor junction,
         # stream/StreamJunction.java:280-316)
-        while len(self._inflight) > self.pipeline_depth:
-            self._retire_one()
+        retire_after_submit(self._inflight, self.pipeline_depth,
+                            self._retire_one)
         tel = self.nfa.last_telemetry
         _record_block(self, prof, disp0, ticks0, stream_id, n,
                       junction=self._junctions.get(stream_id),
                       telemetry=(tel.sum(axis=0) if tel is not None
                                  else None))
 
-    def _retire_one(self) -> None:
-        """Block on the oldest in-flight chunk, handle slot-ring overflow
-        (grow-and-replay: restore that chunk's pre-carry, double the ring,
-        replay it and every later in-flight chunk), decode columnar,
-        emit."""
+    def _retire_one(self, cause: int) -> None:
+        """Read the oldest in-flight chunk (blocking if its result is not
+        there yet), handle slot-ring overflow (grow-and-replay: restore
+        that chunk's pre-carry, double the ring, replay it and every
+        later in-flight chunk), decode columnar, emit."""
         h = self._inflight.popleft()
-        note_retire(self.app_name, h, h.get("buf"))
+        note_retire(self.app_name, h, cause)
         with _ledger().span("device", "retire", block=h.get("seq"),
                             app=self.app_name):
             pids, ts, cols = self.nfa.retire_events(h)
@@ -671,17 +679,33 @@ class DevicePatternRuntime:
             # the egress tail, no extra device read (see egress_dispatch)
             self._schedule_absent(self.nfa.last_min_deadline)
 
+    def settle(self) -> bool:
+        """The junction worker's idle hook (plan/pipeline.py
+        settle_inflight): launch this tenant's pending gang, then retire
+        what is ready, without waiting for the device.
+        -> is work still in flight"""
+        if not self._inflight and not any(
+                sh.inflight for sh in self.shards or ()):
+            return False
+        with self.qr.lock:
+            busy = settle_inflight(self._inflight, self._retire_one)
+            for sh in self.shards or ():
+                if sh.inflight:
+                    busy = settle_inflight(
+                        sh.inflight, partial(self._retire_shard, sh)) or busy
+            return busy
+
     def flush(self) -> None:
-        """Retire every in-flight chunk (pipelined mode): called on idle/
-        drain by the async junction, and before any state read.  Takes the
-        query lock (re-entrant) — state reads can race the junction
-        worker's ingest."""
+        """Retire every in-flight chunk, blocking on each: a junction's
+        barrier and drain, and before any state read.  Takes the query
+        lock (re-entrant) — state reads can race the junction worker's
+        ingest."""
         with self.qr.lock:
             if self.shards is not None:
                 for sh in self.shards:
                     self._flush_shard(sh)
             while self._inflight:
-                self._retire_one()
+                self._retire_one(ON_FLUSH)
 
     def _emit_columns(self, pids, ts, cols, block=None) -> None:
         from ..core.event import EventChunk
@@ -1168,7 +1192,8 @@ class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
     Keyed mode maps partition keys to lanes (like DevicePatternRuntime);
     unkeyed mode runs one lane.  Ingest is pipelined (round 5): each
     chunk's kernel step dispatches immediately, the egress read + decode
-    retires up to `pipeline_depth` chunks later (plan/pipeline.py)."""
+    retires when the result is ready, at most `pipeline_depth` chunks
+    later (plan/pipeline.py)."""
 
     backend = "device"
 

@@ -604,13 +604,18 @@ def test_launches_outside_a_device_span_stay_with_their_stage(
 
 
 def test_note_retire_banks_waits_and_counts(monkeypatch):
+    from siddhi_tpu.core.ledger import ON_DEPTH, ON_FLUSH, ON_READY
     led = LatencyLedger()
-    led.note_retire("a", 1_000_000, 3_000_000, 9_000_000, True)
-    led.note_retire("a", 2_000_000, 2_000_000, 4_000_000, False)
-    led.note_retire("a", None, None, 5_000_000, True)   # dispatched ledger-off
+    led.note_retire("a", 1_000_000, 3_000_000, 9_000_000, True, ON_READY)
+    led.note_retire("a", 2_000_000, 2_000_000, 4_000_000, False, ON_DEPTH)
+    # dispatched ledger-off
+    led.note_retire("a", None, None, 5_000_000, True, ON_FLUSH)
     app = led.snapshot("a")["apps"]["a"]
     assert app["retire_ready_total"] == 2
     assert app["retire_blocked_total"] == 1
+    # what caused each retire, beside whether its result was there
+    assert (app["retire_on_ready_total"], app["retire_on_depth_total"],
+            app["retire_on_flush_total"]) == (1, 1, 1)
     assert app["stages_ms"]["wait.inflight"]["count"] == 2
     assert app["stages_ms"]["wait.defer"]["count"] == 2
     assert app["stages_ms"]["wait.defer"]["min"] == 0.0
@@ -618,6 +623,7 @@ def test_note_retire_banks_waits_and_counts(monkeypatch):
     text = "\n".join(led.prometheus_lines())
     assert 'siddhi_retire_ready_total{app="a"} 2' in text
     assert 'siddhi_retire_blocked_total{app="a"} 1' in text
+    assert 'siddhi_retire_on_depth_total{app="a"} 1' in text
     assert 'siddhi_ledger_span_seconds_total{span="device.pack"}' in text
     led.drop_app("a")
     assert "a" not in led.snapshot()["apps"]
@@ -728,10 +734,19 @@ def test_waits_of_a_block_in_flight(single_device, head, depth):
             st["wait.inflight"]["mean"])
         assert st["device.retire"]["count"] == 2 * blocks
     else:
-        # a block is launched by the next block's sync and retired four
-        # blocks later: deferred for less than it is in flight
+        # a block is launched by the next block's sync (these sends are
+        # synchronous: no junction worker settles them) and retired by
+        # the first later submit that finds its result ready, at the
+        # latest by the fifth, which blocks on it (the cap): deferred
+        # for less than it is in flight
         assert st["wait.defer"]["mean"] < st["wait.inflight"]["mean"]
         assert entry["retire_ready_total"] > 0
+        # the last block of either query is still pending at the closing
+        # flush: the first query's launches the gang and waits for it
+        assert entry["retire_on_flush_total"] >= 2
+        assert entry["retire_blocked_total"] >= 1
+    assert entry["retire_on_ready_total"] + entry["retire_on_depth_total"] \
+        + entry["retire_on_flush_total"] == 2 * blocks
 
 
 def test_naming_sub_spans_moves_no_stage_total(single_device, monkeypatch):

@@ -46,6 +46,15 @@ def _single_device(monkeypatch):
     # never pack) — pin the operator escape hatch so the runtimes this
     # module builds come up mesh-free on the 8-device conftest CPU mesh
     monkeypatch.setenv("SIDDHI_TPU_MESH", "off")
+    # this module counts the process-wide gang's buckets, and another
+    # file of the same xdist worker may have left tenants in it (a
+    # partition's device queries are not shut down with their app):
+    # start every test from an empty gang, whatever ran before
+    packer = tenant_packer()
+    for row in list(packer.buckets.values()):
+        for bucket in list(row):
+            for nfa in list(bucket.tenants):
+                packer.evict(nfa)
 
 
 def _pattern_app(i, thr, e2="v > e1.v"):
