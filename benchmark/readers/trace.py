@@ -172,27 +172,45 @@ def reduce_xplane(path, step_regex, device_plane=DEVICE_PLANE):
     }
 
 
+LOOKBACK_NS = 2_000_000_000
+UNNAMED = "no host span (untraced Python)"
+
+
 def _idle_gaps(busy, host, top=10, longest=4000):
     """The device's idle gaps by what the host was doing: each of the
     `longest` gaps goes to the shortest host span that covers at least
-    half of it (so an enclosing span does not hide what ran inside it);
-    a gap that no span covers so far is the host running untraced Python.
+    half of it (so an enclosing span does not hide what ran inside it),
+    among the spans that start in the LOOKBACK_NS before the gap's end:
+    by time and not by a count of events, since the runtime writes tens
+    of thousands of short events for one upload.  A gap that no span
+    covers by half (a wait for the next send, then its packing) is cut in
+    two and each half named alone, down to an eighth of the gap; what no
+    span covers then is the host running untraced Python.
     -> the `top` activities by gap seconds."""
-    import bisect
+    import numpy as np
     gaps = sorted(((b[0] - a[1], a[1], b[0]) for a, b in zip(busy, busy[1:])),
                   reverse=True)[:longest]
     host = sorted(host)
-    starts = [h[0] for h in host]
+    start = np.asarray([h[0] for h in host], np.int64)
+    end = np.asarray([h[1] for h in host], np.int64)
     by = {}
-    for length, g0, g1 in gaps:
-        best = None
-        hi = bisect.bisect_right(starts, g1)
-        for s, e, name in host[max(0, hi - 4000):hi]:
-            if 2 * (min(e, g1) - max(s, g0)) >= length and \
-                    (best is None or e - s < best[0]):
-                best = (e - s, name)
-        name = best[1] if best else "no host span (untraced Python)"
-        by[name] = by.get(name, 0.0) + length / 1e9
+
+    def credit(g0, g1, halvings):
+        lo, hi = np.searchsorted(start, [g1 - LOOKBACK_NS, g1 + 1])
+        s, e = start[lo:hi], end[lo:hi]
+        covers = np.flatnonzero(
+            2 * (np.minimum(e, g1) - np.maximum(s, g0)) >= g1 - g0)
+        if not len(covers) and halvings:
+            mid = (g0 + g1) // 2
+            credit(g0, mid, halvings - 1)
+            credit(mid, g1, halvings - 1)
+            return
+        who = host[lo + covers[np.argmin((e - s)[covers])]][2] \
+            if len(covers) else UNNAMED
+        by[who] = by.get(who, 0.0) + (g1 - g0) / 1e9
+
+    for _length, g0, g1 in gaps:
+        credit(g0, g1, 3)
     return [[n, s] for n, s in sorted(by.items(), key=lambda kv: -kv[1])[:top]]
 
 

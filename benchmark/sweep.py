@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""The one-time sweep that finds a paced cell's knee: the same app, for
+"""The one-time sweep that finds a paced cell's rate: the same app, for
 each offered rate (lowest first) `--repeats` windows.  A window sustains
 its rate when
 
@@ -12,8 +12,13 @@ its rate when
 and a rate is sustained when every one of its windows is.  The knee is the
 highest sustained rate: a system that sustains a rate has the capacity for
 every lower one, so a window that fails below the knee is a transient and
-is reported as such.  Its output goes into PERF.md by hand, and 0.8 of the
-knee into the workload file; no run reads it.
+is reported as such.  Each window also gives its latency p95 as a share of
+the send period (`p95_periods`: under 0.9, a block's rows have left before
+the next send is due, so no send waits behind another) and the 5th
+percentile beside the 50th and 95th (rows that leave in two groups show
+as a wide p05..p95).  Its output goes into PERF.md by hand and the rate
+chosen into the workload file, with `rate_from` saying by which rule; no
+run reads it.
 
     python3 benchmark/sweep.py --workload <cell> --rates 50000,100000,... \
         [--seed 1] [--seconds 6] [--repeats 3]
@@ -44,9 +49,10 @@ def one_window(served, traffic, seconds):
     half = send < gen["n_sends"] // 2
     late = (gen["starts"] - gen["due"]) * 1e3
     period_ms = traffic.send_events / traffic.rate * 1e3
+    p05, p50, p95 = (float(v) for v in np.percentile(lat, [5, 50, 95]))
     row = {"sends": gen["n_sends"], "rows": len(lat),
-           "p50_ms": float(np.percentile(lat, 50)),
-           "p95_ms": float(np.percentile(lat, 95)),
+           "p05_ms": p05, "p50_ms": p50, "p95_ms": p95,
+           "p95_periods": p95 / period_ms,
            "growth": float(np.percentile(lat[~half], 95)
                            / np.percentile(lat[half], 95)),
            "late_p95_ms": float(np.percentile(late, 95)),
@@ -89,15 +95,16 @@ def main(argv=None, require_tpu=True):
         table.append(row)
         print("[sweep] " + json.dumps(row), flush=True)
     served.shutdown()
-    print("[sweep] rate | period_ms | p50_ms | p95_ms | growth | "
-          "late_p95_ms | drain_s | sustained   (median, worst of "
-          f"{opts.repeats} windows)")
+    print("[sweep] rate | period_ms | p05_ms | p50_ms | p95_ms | p95_periods "
+          "| growth | late_p95_ms | drain_s | sustained   (median, worst "
+          f"of {opts.repeats} windows)")
     for r in table:
         def two(k):
             v = [w[k] for w in r["windows"]]
             return f"{np.median(v):.2f}, {max(v):.2f}"
         print(f"[sweep] {r['rate']:.0f} | {r['period_ms']:.2f} | "
-              f"{two('p50_ms')} | {two('p95_ms')} | {two('growth')} | "
+              f"{two('p05_ms')} | {two('p50_ms')} | {two('p95_ms')} | "
+              f"{two('p95_periods')} | {two('growth')} | "
               f"{two('late_p95_ms')} | {two('drain_s')} | {r['sustained']}")
     good = [r["rate"] for r in table if r["sustained"]]
     knee = max(good) if good else None

@@ -44,7 +44,41 @@ def test_idle_gaps_go_to_the_shortest_covering_host_span():
     assert gaps["main:outer"] == pytest.approx(10e-9)
     assert "main:blip" not in gaps
     assert trace._idle_gaps(busy, []) == \
-        [["no host span (untraced Python)", pytest.approx(110e-9)]]
+        [[trace.UNNAMED, pytest.approx(110e-9)]]
+
+
+def test_idle_gap_lookback_is_by_time_not_by_event_count():
+    """One gang call writes ~27,000 runtime events for its upload: a span
+    of the program that covered the gap before them still names it."""
+    ms = 1_000_000
+    busy = [[0, ms], [121 * ms, 122 * ms]]
+    host = [(2 * ms, 118 * ms, "python3:siddhi/dispatch")]
+    host += [(119 * ms + 50 * k, 119 * ms + 50 * k + 40,
+              "pjrt-tpu-tasks:Transpose") for k in range(30_000)]
+    assert trace._idle_gaps(busy, host) == \
+        [["python3:siddhi/dispatch", pytest.approx(0.120)]]
+    # a span that started more than LOOKBACK_NS before the gap's end is
+    # not searched
+    late = trace.LOOKBACK_NS + 200 * ms
+    far = [[late, late + ms], [late + 101 * ms, late + 102 * ms]]
+    assert trace._idle_gaps(far, [(0, late + 101 * ms, "main:stale")]) == \
+        [[trace.UNNAMED, pytest.approx(0.100)]]
+
+
+def test_idle_gap_that_no_span_covers_by_half_is_named_by_its_halves():
+    """A paced cell's gap: the worker waits for the next send, then packs
+    it; neither covers half, each covers most of one half."""
+    busy = [[0, 10], [110, 120]]
+    host = [(10, 52, "python3:siddhi/queue.idle"),
+            (56, 104, "python3:siddhi/deliver")]
+    gaps = dict(trace._idle_gaps(busy, host))
+    assert gaps == {"python3:siddhi/queue.idle": pytest.approx(50e-9),
+                    "python3:siddhi/deliver": pytest.approx(50e-9)}
+    # cut down to an eighth of the gap and no further
+    thin = dict(trace._idle_gaps([[0, 10], [810, 820]],
+                                 [(10, 70, "main:a")]))
+    assert thin == {trace.UNNAMED: pytest.approx(700e-9),
+                    "main:a": pytest.approx(100e-9)}
 
 
 def test_recorded_trace_reduces():

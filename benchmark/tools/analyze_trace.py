@@ -3,13 +3,16 @@ benchmark/run.py and, before the trace is discarded, look into the
 .xplane.pb for what `breakdown.idle_gaps` cannot say (PERF.md 5 and 6
 quote its numbers):
 
-  longest_gaps   the 12 longest device idle gaps: how far back the trace
-                 reader's 4,000-event lookback reaches from each, and which
-                 program spans (`siddhi/...`, `bench....`) overlap it;
-  gaps_by_program_span_lookback_by_time
-                 the reader's rule (the shortest span that covers at least
-                 half of the gap names it) over the program's spans alone,
-                 looking back by time (2 s) and not by event count;
+  longest_gaps   the 12 longest device idle gaps: how many host events
+                 start inside each, and which program spans (`siddhi/...`,
+                 `bench....`) overlap it;
+  gaps_by_program_span
+                 the reader's rule (`readers/trace.py` `_idle_gaps`: the
+                 shortest span that covers at least half of the gap names
+                 it) over the program's spans alone, the runtime's own
+                 events left out;
+  step_ms        the step modules' runs by device duration, rounded to
+                 0.1 ms: one depth of block shows as one duration;
   block_join     per block and query, by the spans' `block` stat: send ->
                  dequeue -> submit -> retire -> callback, the table that
                  reconciles a paced cell's `match_latency_p50_ms`.
@@ -22,6 +25,7 @@ import bisect
 import collections
 import json
 import os
+import re
 import statistics
 import sys
 
@@ -30,18 +34,17 @@ sys.path[:0] = [os.path.dirname(BENCH), BENCH]
 import run as bench_run                      # noqa: E402  benchmark/run.py
 from readers import trace as trace_reader    # noqa: E402
 
-LOOKBACK_EVENTS = 4000          # benchmark/readers/trace.py's own
-LOOKBACK_NS = 2_000_000_000     # this tool's, by time
-
 
 def _program_span(name):
     return name.startswith("siddhi/") or name.startswith("bench.")
 
 
-def _host_and_busy(path):
-    """-> (host events sorted by start, the device's busy intervals)."""
+def _host_busy_steps(path, step_regex):
+    """-> (host events sorted by start, the device's busy intervals, the
+    step modules' runs by duration in ms)."""
     from jax.profiler import ProfileData
-    host, busy = [], []
+    step = re.compile(step_regex)
+    host, busy, step_ms = [], [], collections.Counter()
     for plane in ProfileData.from_file(path).planes:
         if trace_reader.DEVICE_PLANE.match(plane.name):
             for line in plane.lines:
@@ -49,6 +52,10 @@ def _host_and_busy(path):
                     busy = trace_reader._union(
                         [(e.start_ns, e.start_ns + e.duration_ns)
                          for e in line.events])
+                elif line.name == trace_reader.MODULES_LINE:
+                    step_ms.update(
+                        round(e.duration_ns / 1e6, 1) for e in line.events
+                        if step.search(trace_reader._base(e.name)))
         elif plane.name.startswith("/host:CPU"):
             for line in plane.lines:
                 thread = line.name.split("/")[0] or "main"
@@ -63,7 +70,7 @@ def _host_and_busy(path):
                     host.append((ev.start_ns, ev.start_ns + ev.duration_ns,
                                  ev.name, thread, block))
     host.sort()
-    return host, busy
+    return host, busy, step_ms
 
 
 def _longest_gaps(host, gaps):
@@ -71,7 +78,6 @@ def _longest_gaps(host, gaps):
     rows = []
     for length, g0, g1 in gaps[:12]:
         hi = bisect.bisect_right(starts, g1)
-        lo = max(0, hi - LOOKBACK_EVENTS)
         over = collections.Counter()
         for s, e, name, *_ in host[bisect.bisect_left(
                 starts, g0 - 400_000_000):hi]:
@@ -81,29 +87,11 @@ def _longest_gaps(host, gaps):
                     over[name] += o / 1e6
         rows.append({
             "gap_ms": length / 1e6,
-            "lookback_reach_ms": (g1 - host[lo][0]) / 1e6 if hi else 0,
             "host_events_starting_in_gap":
                 hi - bisect.bisect_left(starts, g0),
-            "lookback_top": collections.Counter(
-                h[2][:40] for h in host[lo:hi]).most_common(3),
             "program_spans_overlap_ms":
                 [[n, round(v, 2)] for n, v in over.most_common(8)]})
     return rows
-
-
-def _gaps_by_program_span(host, gaps):
-    prog = [h for h in host if _program_span(h[2])]
-    starts = [h[0] for h in prog]
-    by = collections.Counter()
-    for length, g0, g1 in gaps[:4000]:
-        best = None
-        for s, e, name, *_ in prog[bisect.bisect_left(
-                starts, g0 - LOOKBACK_NS):bisect.bisect_right(starts, g1)]:
-            if 2 * (min(e, g1) - max(s, g0)) >= length and \
-                    (best is None or e - s < best[0]):
-                best = (e - s, name)
-        by[best[1] if best else "no program span"] += length / 1e9
-    return [[n, round(s, 4)] for n, s in by.most_common(12)]
 
 
 def _block_join(host):
@@ -147,19 +135,21 @@ def _block_join(host):
             comp["D_retire_to_callback"].append((cbs[j][1] - rs) / 1e6)
             comp["total"].append((cbs[j][1] - t_send) / 1e6)
     return {"rows": len(comp["total"]), "blocks": len(blocks),
-            "median_ms": {k: statistics.median(v) for k, v in comp.items()},
-            "mean_ms": {k: statistics.fmean(v) for k, v in comp.items()}}
+            "median_ms": {k: statistics.median(v)
+                          for k, v in comp.items() if v},
+            "mean_ms": {k: statistics.fmean(v) for k, v in comp.items() if v}}
 
 
-def analyze(path, out):
-    host, busy = _host_and_busy(path)
+def analyze(path, out, step_regex):
+    host, busy, step_ms = _host_busy_steps(path, step_regex)
     gaps = sorted(((b[0] - a[1], a[1], b[0])
                    for a, b in zip(busy, busy[1:])), reverse=True)
     res = {"host_events": len(host),
            "idle_s": sum(g[0] for g in gaps) / 1e9,
            "longest_gaps": _longest_gaps(host, gaps),
-           "gaps_by_program_span_lookback_by_time":
-               _gaps_by_program_span(host, gaps),
+           "gaps_by_program_span": trace_reader._idle_gaps(
+               busy, [h[:3] for h in host if _program_span(h[2])], top=12),
+           "step_ms": sorted(step_ms.items()),
            "block_join": _block_join(host)}
     with open(out, "w") as f:
         json.dump(res, f, indent=1)
@@ -175,10 +165,12 @@ def main():
     opts = ap.parse_args()
     opts.trace = 1
     discard = trace_reader.Tracer.discard
+    step_regex = bench_run.Cell(opts.workload).config["kernel"][
+        "step_modules"]
 
     def analyze_then_discard(self):
         try:
-            analyze(self.path(), opts.out)
+            analyze(self.path(), opts.out, step_regex)
         finally:
             discard(self)
 
