@@ -75,6 +75,9 @@ def nfa_state_bytes(a: AutomatonIR,
         b["lmask"] = P * K * I32
     if "absent" in kinds:
         b["deadline"] = P * K * I32
+        # armed / fired / fired in-block / killed, per lane
+        # (ops/nfa.ABSENT_CTR)
+        b["absent_ctr"] = P * 4 * I32
     arm_once = (not a.is_every) or \
         (not a.is_sequence and a.states and a.states[0].kind == "count")
     if arm_once:
@@ -87,10 +90,12 @@ def nfa_state_bytes(a: AutomatonIR,
 
 
 def nfa_egress_bytes(a: AutomatonIR) -> int:
-    """Per-chunk compacted-egress buffer: (cap+1) x (4 + R*C) int32."""
+    """Per-chunk compacted-egress buffer: (cap+1) x (4 + R*C) int32, and
+    one row more (the absent counters) with a `not … for t` unit."""
     R = max(a.n_rows, 1)
     C = max(a.n_caps, 1)
-    return (a.egress_cap + 1) * (4 + R * C) * I32
+    rows = a.egress_cap + 1 + any(s.kind == "absent" for s in a.states)
+    return rows * (4 + R * C) * I32
 
 
 def nfa_flops_per_event(a: AutomatonIR) -> int:

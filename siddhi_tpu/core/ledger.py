@@ -104,9 +104,16 @@ _stage_values = itemgetter(*STAGES)     # the seven accumulators at once
 #:                    D2H read (re-pack on overflow, compact-row decode)
 #:   decode.fetch     the windowed-agg runtime's fetch of its step's
 #:                    outputs from the fused slab, under ``decode`` there
+#:   device.timer     a host TIMER of the device pattern runtime: the
+#:                    flush of its in-flight queue and the TIMER block's
+#:                    step, for time that the runtime's own events did
+#:                    not bring (the wall clock, playback's idle
+#:                    heartbeat, a send on a stream the pattern does not
+#:                    read; never a send on one it reads)
 SPAN_NAMES = ("dispatch.keys", "dispatch.lanes", "dispatch.cols",
               "dispatch.pack", "device.encode", "device.pack", "device.sync",
-              "device.issue", "device.retire", "decode.fetch")
+              "device.issue", "device.retire", "decode.fetch",
+              "device.timer")
 
 #: spans without a stage that only annotate (no accumulator; nothing at
 #: all unless a profiler session or the operator's exporter records):
@@ -136,6 +143,16 @@ RETIRE_COUNTERS = ("retire_ready_total", "retire_blocked_total",
                    "retire_on_ready_total", "retire_on_depth_total",
                    "retire_on_flush_total")
 ON_READY, ON_DEPTH, ON_FLUSH = 2, 3, 4
+
+#: the per-app counters of `not … for t` deadlines on the device path, in
+#: the order of an ``_absent`` row: slots that entered an absent unit;
+#: deadlines that fired; of those, the ones fired by an event block's own
+#: clock; slots killed by an arrival on the `not` stream (the first four
+#: are ops/nfa.ABSENT_CTR, counted on the device and read off the
+#: egress tail); TIMER rows stepped by host TIMERs
+ABSENT_COUNTERS = ("absent_armed_total", "absent_fired_total",
+                   "absent_fired_inblock_total", "absent_killed_total",
+                   "absent_timer_rows_total")
 
 
 # os.environ.get pays ~0.9 us per call (key encode + value decode);
@@ -497,6 +514,9 @@ class LatencyLedger:
         self._named: list = []
         # app -> [retires whose result was ready, retires that blocked]
         self._retires: Dict[str, list] = {}
+        # app -> ABSENT_COUNTERS row.  Kept past drop_app, as the stage
+        # accumulators are: a run reads it after its app shut down
+        self._absent: Dict[str, list] = {}
         # app -> the most recent block's stage deltas (waterfall row)
         self._last_deltas: Dict[str, list] = {}
         # (app, stream) -> lag watermark state
@@ -594,6 +614,17 @@ class LatencyLedger:
                     app, [0] * len(RETIRE_COUNTERS))
         row[0 if ready else 1] += 1
         row[cause] += 1
+
+    def note_absent(self, app: str, deltas) -> None:
+        """Add to an app's ABSENT_COUNTERS (a device pattern runtime, as
+        it retires a block or steps a TIMER)."""
+        row = self._absent.get(app)
+        if row is None:
+            with self._lock:
+                row = self._absent.setdefault(
+                    app, [0] * len(ABSENT_COUNTERS))
+        for i, d in enumerate(deltas):
+            row[i] += int(d)
 
     # ------------------------------------------------------ block fold
 
@@ -743,7 +774,7 @@ class LatencyLedger:
             "span_seconds": {s: self._ns[s] / 1e9 for s in SPAN_NAMES},
             "stage_spans": dict(self._spans),
         }
-        apps = sorted({a for (a, _s) in self._hist}
+        apps = sorted({a for (a, _s) in self._hist} | set(self._absent)
                       ) if app is None else [app]
         per_app = {}
         for a in apps:
@@ -763,6 +794,9 @@ class LatencyLedger:
             row = self._retires.get(a)
             if row is not None:
                 entry.update(zip(RETIRE_COUNTERS, row))
+            row = self._absent.get(a)
+            if row is not None:
+                entry.update(zip(ABSENT_COUNTERS, row))
             per_app[a] = entry
         doc["apps"] = per_app
         return doc
@@ -784,6 +818,10 @@ class LatencyLedger:
         for app, row in sorted(self._retires.items()):
             lab = _fmt_labels({"app": app})
             for name, n in zip(RETIRE_COUNTERS, row):
+                lines.append(f"siddhi_{name}{lab} {n}")
+        for app, row in sorted(self._absent.items()):
+            lab = _fmt_labels({"app": app})
+            for name, n in zip(ABSENT_COUNTERS, row):
                 lines.append(f"siddhi_{name}{lab} {n}")
         for (app, stage), h in sorted(self._hist.items()):
             if not h.count:
@@ -822,6 +860,7 @@ class LatencyLedger:
             self._pending.clear()
             del self._named[:]
             self._retires.clear()
+            self._absent.clear()
             self._last_deltas.clear()
             self._lag.clear()
             self._slo.clear()

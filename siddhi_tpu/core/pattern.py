@@ -22,6 +22,8 @@ and util/parser/StateInputStreamParser.java:76-404 (state graph wiring:
 """
 from __future__ import annotations
 
+import heapq
+
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -595,6 +597,9 @@ class StateStreamRuntime:
         self.units: List[StateUnit] = []
         self.tables = {tid: t for tid, t in self.app.tables.items()}
         self._matches: List[StateEvent] = []
+        # absent wakeups not yet run: (due ms, serial, unit)
+        self._due: List[tuple] = []
+        self._due_seq = 0
         self._stream_units: Dict[str, List[StateUnit]] = {}
         self._refs_by_unit: Dict[int, str] = {}
 
@@ -843,17 +848,44 @@ class StateStreamRuntime:
         return self.app.app_ctx.timestamp_generator.current_time()
 
     def schedule(self, ts: int, unit: StateUnit):
-        def fire(now, _u=unit):
+        """An absent unit's wakeup.  Kept here as well as with the app's
+        scheduler, so that an event can work off what is due before it is
+        routed (``_fire_due``); each wakeup runs once, whoever comes
+        first."""
+        heapq.heappush(self._due, (ts, self._due_seq, unit))
+        self._due_seq += 1
+
+        def fire(now):
             with self.lock:
-                _u.absent_tick(now)
-                self.flush_matches()
+                self._fire_due(now, by_event=False)
         self.app.app_ctx.scheduler.notify_at(ts, fire)
+
+    def _fire_due(self, now: int, by_event: bool):
+        """Run every wakeup due at or before `now`, in due order.  The
+        clock reaches an event's timestamp, and the timers due by then
+        fire, BEFORE the event is routed (upstream's playback order:
+        InputHandler.send sets the clock first), whatever the cut of the
+        stream into sends and whichever key's event moved the clock: so
+        an event meets a deadline at or before its own timestamp as
+        already fired, and the wakeup lands at the time it was asked for
+        (as the playback scheduler's do)."""
+        land = by_event or \
+            self.app.app_ctx.timestamp_generator.in_playback
+        while self._due and self._due[0][0] <= now:
+            ts, _, unit = heapq.heappop(self._due)
+            unit.absent_tick(ts if land else now)
+            self.flush_matches()
 
     def start(self):
         for u in self.units:
             u.start()
 
     def process_event(self, receiver: PatternReceiver, row: Row):
+        if self._due and self.state_type != StateType.SEQUENCE:
+            # (a SEQUENCE keeps the order it had: its partials at an
+            # absent unit live and die by the stabilize barrier below,
+            # and the kernel's sequence passes mirror that order)
+            self._fire_due(row[0], by_event=True)
         # stabilize (reference stabilizeStates)
         if self.state_type == StateType.SEQUENCE:
             for u in reversed(self.units):

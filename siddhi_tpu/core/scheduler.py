@@ -37,8 +37,13 @@ class Scheduler:
         self._stopped = False
         #: cumulative fired-target count (flight-recorder block records)
         self.fires = 0
-        if ts_gen.in_playback:
-            ts_gen.add_time_change_listener(self._on_virtual_time)
+        #: playback: the latest time `advance_to` was called with.  A
+        #: sender advances AFTER it handed its chunk to the junction, so
+        #: whatever brought the clock here is already in a queue
+        self.advanced_to = -1
+        # only playback's idle heartbeat calls the listeners; playback is
+        # enabled from the app's annotations, after this is built
+        ts_gen.add_time_change_listener(self._on_virtual_time)
 
     def notify_at(self, ts: int, target: Callable[[int], None]):
         wd = self.watchdog
@@ -93,6 +98,7 @@ class Scheduler:
         while True:
             due = []
             with self._lock:
+                self.advanced_to = max(self.advanced_to, now)
                 while self._heap and self._heap[0][0] <= now:
                     due.append(heapq.heappop(self._heap))
             if not due:

@@ -554,6 +554,21 @@ LEDGER_TYPES = [
     ("siddhi_retire_on_flush_total",
      "counter", "In-flight blocks retired by a blocking flush (barrier, "
      "drain, state read)"),
+    ("siddhi_absent_armed_total",
+     "counter", "Device pattern slots that entered a `not ... for t` unit "
+     "with a deadline"),
+    ("siddhi_absent_fired_total",
+     "counter", "Device `not ... for t` deadlines that fired (the slot "
+     "left the unit by time)"),
+    ("siddhi_absent_fired_inblock_total",
+     "counter", "Of those, deadlines fired by an event block's own clock "
+     "rather than by a host TIMER row"),
+    ("siddhi_absent_killed_total",
+     "counter", "Device pattern slots killed by an arrival on the `not` "
+     "stream before their deadline"),
+    ("siddhi_absent_timer_rows_total",
+     "counter", "TIMER rows stepped on the device by host TIMERs of "
+     "absent patterns"),
     ("siddhi_ledger_stage_latency_ms",
      "gauge", "Per-app latency quantiles (ms): a stage per block, a named "
      "sub-span per execution, a wait per block in flight"),

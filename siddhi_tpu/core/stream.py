@@ -152,6 +152,20 @@ class _FlushBarrier:
                 self.done.set()
 
 
+class _InOrder:
+    """Queue item of StreamJunction.call_in_order: a call that takes its
+    turn behind the chunks already sent."""
+
+    def __init__(self, fn):
+        self.fn = fn
+
+    def __len__(self):          # rides the chunk queue
+        return 0
+
+    def arrive(self, _flush_fn):
+        self.fn()
+
+
 class StreamJunction:
     """Pub/sub hub for one stream."""
 
@@ -326,8 +340,8 @@ class StreamJunction:
                     if self._drain.is_set():
                         break   # drained: queue empty after drain request
                     continue
-            if isinstance(item, _FlushBarrier):
-                inflight = False
+            if isinstance(item, (_FlushBarrier, _InOrder)):
+                inflight = isinstance(item, _InOrder)
                 try:
                     item.arrive(self._flush_receivers)
                 finally:
@@ -335,13 +349,13 @@ class StreamJunction:
                 continue
             batch = [item]
             n = len(item)
-            barrier = None
+            barrier = None      # or a call that waits its turn
             while n < self.batch_size_max:
                 try:
                     nxt = q.get_nowait()
                 except queue.Empty:
                     break
-                if isinstance(nxt, _FlushBarrier):
+                if isinstance(nxt, (_FlushBarrier, _InOrder)):
                     barrier = nxt
                     break
                 batch.append(nxt)
@@ -365,7 +379,7 @@ class StreamJunction:
                     self._deliver(merged)
                 inflight = True
                 if barrier is not None:
-                    inflight = False
+                    inflight = isinstance(barrier, _InOrder)
                     barrier.arrive(self._flush_receivers)
             finally:
                 # one task_done per popped item: the batch's extra pops
@@ -428,6 +442,28 @@ class StreamJunction:
                         return
         else:
             self._flush_receivers()
+
+    def call_in_order(self, fn: Callable[[], None]) -> None:
+        """Run ``fn()`` behind everything already sent here: under @Async
+        on the worker, when the chunks queued so far have been delivered;
+        at once on a synchronous junction (or once the drain has begun).
+        Errors go to the @OnError boundary."""
+        def call():
+            try:
+                fn()
+            except Exception as e:  # noqa: BLE001 — @OnError boundary
+                self._handle_error(
+                    EventChunk.empty(self.definition.attribute_names), e)
+        q = self._queue
+        if not self.is_async or q is None or self._drain.is_set():
+            call()
+            return
+        # `put` without the wait for room: the caller may be the worker,
+        # or hold a lock the worker needs to make room
+        with q.mutex:
+            q.queue.append(_InOrder(call))
+            q.unfinished_tasks += 1
+            q.not_empty.notify()
 
     # ------------------------------------------------------------ sending
 
@@ -538,7 +574,7 @@ class StreamJunction:
                 item = q.get_nowait()
             except queue.Empty:
                 break
-            if isinstance(item, _FlushBarrier):
+            if isinstance(item, (_FlushBarrier, _InOrder)):
                 # guaranteed room: we just popped an entry and only
                 # producers racing us could have refilled it — the put
                 # below can block at most momentarily

@@ -52,6 +52,19 @@ import numpy as np
 NO_SLOT = jnp.int32(-1)
 COUNT_INF = 0x7FFFFFFF
 
+#: block leaf ``[P, 1]`` int32: the block's clock, its latest event time
+#: (offset from the engine's base like ``__ts``).  Present only in blocks
+#: of an automaton with a `not … for t` unit (build_block_step).
+CLOCK_KEY = "__clock"
+
+#: what the ``absent_ctr`` carry leaf counts, per lane and cumulatively:
+#: slots that entered a `not … for t` unit with a deadline; deadlines that
+#: fired (the slot left the unit by time, row or not); of those, the ones
+#: fired by an event block's own clock (a lane's event at or past the
+#: deadline, or the block's closing pass) rather than by a host TIMER row;
+#: slots killed by an arrival on the `not` stream
+ABSENT_CTR = ("armed", "fired", "fired_inblock", "killed")
+
 #: B-event micro-batching of the scan chain (round 6).  The env value is
 #: B itself: unset/empty → DEFAULT_BATCH_B; ``=1`` is the kill switch
 #: (legacy one-event ticks, no hoisting — mirrors SIDDHI_TPU_NFA_PRUNE).
@@ -237,6 +250,10 @@ def make_carry(spec: NfaSpec, n_partitions: int) -> Dict[str, jnp.ndarray]:
         carry["lmask"] = jnp.zeros((P, K), jnp.int32)
     if _has(spec, "absent"):
         carry["deadline"] = jnp.zeros((P, K), jnp.int32)
+        # cumulative per lane, in ABSENT_CTR order; read off the
+        # egress tail, never by a device read of its own
+        carry["absent_ctr"] = jnp.zeros((P, len(ABSENT_CTR)),
+                                        jnp.int32)
     if spec.arm_once:
         carry["armed_total"] = jnp.zeros((P,), jnp.int32)
     if spec.telemetry:
@@ -358,6 +375,10 @@ class _StepState:
         self.seq_froze = carry.get("seq_froze")
         self.lmask = carry.get("lmask")
         self.deadline = carry.get("deadline")
+        # this step's additions to the lane's ABSENT_CTR (plain
+        # zeros until an absent unit's code adds a traced count)
+        self.actr = carry.get("absent_ctr")
+        self.n_armed = self.n_fired = self.n_inblock = self.n_killed = 0
         self.armed_total = carry.get("armed_total")
         self.m_mask = jnp.zeros((K,), bool)
         self.m_ts = jnp.zeros((K,), jnp.int32)
@@ -474,6 +495,57 @@ class _StepState:
         if spec.units[t].kind == "absent":
             self.deadline = jnp.where(
                 pred, base_ts + spec.units[t].waiting_ms, self.deadline)
+            self.count_armed(pred)
+
+    def count_armed(self, pred):
+        self.n_armed = self.n_armed + jnp.sum(pred.astype(jnp.int32))
+
+    def fire_deadlines(self, now, gate, by_timer=None):
+        """Every `not … for t` deadline due at or before `now` fires, for
+        the slots `gate` admits: the slot lands AT ITS DEADLINE (match
+        timestamp, next unit's entry time, a cascaded deadline's base),
+        and `within` is judged at the deadline, not at `now` — a slot
+        whose deadline lies past `within` of its start dies instead.
+        Ascending unit order cascades an absence chain in one pass.
+        by_timer: the pass is a host TIMER row's (else a block's own
+        clock: counted as fired in-block)."""
+        spec = self.spec
+        for j, u in enumerate(spec.units):
+            if u.kind != "absent":
+                continue
+            due = gate & (self.st == j) & (self.deadline <= now)
+            if spec.within_ms is not None and j >= 1:
+                late = due & (self.deadline - self.start > spec.within_ms)
+                self.st = jnp.where(late, -1, self.st)
+                due = due & ~late
+            self.land(due, j, self.deadline)
+            n = jnp.sum(due.astype(jnp.int32))
+            self.n_fired = self.n_fired + n
+            self.n_inblock = self.n_inblock + (
+                n if by_timer is None else jnp.where(by_timer, 0, n))
+
+    def to_carry(self) -> Dict[str, jnp.ndarray]:
+        out = {"slot_state": self.st, "slot_start": self.start,
+               "slot_enter": self.enter, "slot_seq": self.seq,
+               "arm_seq": self.arm_seq, "captures": self.caps,
+               "dropped": self.dropped}
+        if self.cnt_cur is not None:
+            out["cnt_cur"] = self.cnt_cur
+            out["cnt_prev"] = self.cnt_prev
+        if self.seq_froze is not None:
+            out["seq_froze"] = self.seq_froze
+        if self.lmask is not None:
+            out["lmask"] = self.lmask
+        if self.deadline is not None:
+            out["deadline"] = self.deadline
+        if self.actr is not None:
+            out["absent_ctr"] = self.actr + jnp.stack(
+                [jnp.asarray(n, jnp.int32) for n in
+                 (self.n_armed, self.n_fired, self.n_inblock,
+                  self.n_killed)])
+        if self.armed_total is not None:
+            out["armed_total"] = self.armed_total
+        return out
 
     def write_all(self, pred, row: int, ev_rows):
         """Write every lane of `row` for `pred` slots."""
@@ -597,6 +669,24 @@ def _one_partition_step(spec: NfaSpec, carry: Dict, event):
     tel = carry.get("telem") if spec.telemetry else None
     tel_exp = jnp.int32(0)
 
+    # ---- absent deadline pass: the clock reaches an event's timestamp,
+    # and every timer due by then fires, BEFORE the event is routed
+    # (upstream's playback order: InputHandler.send sets the clock, the
+    # Scheduler sends its TIMER events, then the event goes in).  So an
+    # event meets a deadline at or before its own timestamp as already
+    # fired, by event time alone, whatever other lanes saw: an arrival on
+    # the `not` stream kills only with ts < deadline, and a slot the
+    # deadline moved on may consume THIS event at its new unit.  Lanes
+    # with no event at or past a deadline are served by the block's
+    # closing pass (build_block_step).  Before the within expiry below,
+    # which is judged at the event's time.
+    # (A SEQUENCE keeps the order it had, below: its partials at an
+    # absent unit live and die by the per-event stabilize barrier.)
+    have0 = jnp.any(s.st == 0) if spec.lead_absent else None
+    by_timer = stream == -2 if s.deadline is not None else None
+    if s.deadline is not None and not spec.is_sequence:
+        s.fire_deadlines(ts, valid, by_timer)
+
     # ---- within expiry (reference isExpired :104-113 — start-state
     # partials are exempt: a half-filled leading pair or accumulating
     # kleene start never expires, only later units enforce `within`)
@@ -618,8 +708,9 @@ def _one_partition_step(spec: NfaSpec, carry: Dict, event):
         # REAL events only: the oracle's ticks stop after a successful
         # confirmation until an arrival (or fresh scheduling) restarts
         # them — re-arming on an injected TIMER row would chain
-        # confirmations the reference never produces
-        have0 = jnp.any(s.st == 0)
+        # confirmations the reference never produces.  `have0` is the
+        # lane BEFORE this event's deadline pass: a start partial that
+        # just confirmed is replaced at the next real event, not this one
         want0 = valid & (stream != -2) & ~have0
         free0 = (s.st < 0) & ~s.m_mask
         armed0 = (want0 & jnp.any(free0)) & \
@@ -628,6 +719,7 @@ def _one_partition_step(spec: NfaSpec, carry: Dict, event):
         s.st = jnp.where(armed0, 0, s.st)
         s.deadline = jnp.where(armed0, ts + spec.units[0].waiting_ms,
                                s.deadline)
+        s.count_armed(armed0)
         s.start = jnp.where(armed0, ts, s.start)
         s.enter = jnp.where(armed0, ts, s.enter)
         s.seq = jnp.where(armed0, s.arm_seq, s.seq)
@@ -639,25 +731,22 @@ def _one_partition_step(spec: NfaSpec, carry: Dict, event):
             s.cnt_prev = jnp.where(armed0, -1, s.cnt_prev)
         s.dropped = s.dropped + jnp.where(want0 & ~jnp.any(free0), 1, 0)
 
-    # ---- SEQUENCE early deadline pass: the playback scheduler fires a
-    # deadline that coincides with (or precedes) an event's timestamp
-    # BEFORE that event stabilizes the sequence — a due `not … for t`
-    # confirms the absence even though the arriving event would clear the
-    # pending list (see the stabilize barrier below); fired slots advance
-    # and may consume THIS event at their new unit
-    if spec.is_sequence and _has(spec, "absent"):
-        for j, u in enumerate(spec.units):
-            if u.kind != "absent":
-                continue
-            fire = valid & (s.st == j) & (s.deadline <= ts)
-            s.land(fire, j, s.deadline)
+    # ---- SEQUENCE early deadline pass: a deadline that coincides with
+    # (or precedes) an event's timestamp fires BEFORE that event
+    # stabilizes the sequence — a due `not … for t` confirms the absence
+    # even though the arriving event would clear the pending list (see
+    # the stabilize barrier below); fired slots advance and may consume
+    # THIS event at their new unit
+    if spec.is_sequence and s.deadline is not None:
+        s.fire_deadlines(ts, valid, by_timer)
 
     # ---- SEQUENCE stabilize barrier for absent units: the oracle clears
     # every unit's pending list BEFORE each real event (stabilizeStates →
     # resetState), so a partial waiting at a `not … for t` unit survives
     # only an event-free gap — any arriving event (even a non-matching
-    # one) breaks the sequence before the deadline could fire.  Timer
-    # rows (stream -2) do not stabilize.
+    # one) breaks the sequence before the deadline could fire; a deadline
+    # at or before the event's timestamp has fired above.  Timer rows
+    # (stream -2) do not stabilize.
     if spec.is_sequence and _has(spec, "absent"):
         absent_u = np.asarray([u.kind == "absent" for u in spec.units] +
                               [False], bool)
@@ -793,12 +882,14 @@ def _one_partition_step(spec: NfaSpec, carry: Dict, event):
             # an actual arrival on the `not` stream kills the partial
             # (AbsentStreamPostStateProcessor: never advances)
             kill = at & (stream == u.stream_a) & conds[u.cond_a]
+            s.n_killed = s.n_killed + jnp.sum(kill.astype(jnp.int32))
             if j == 0 and spec.lead_absent:
                 # leading absent: the kill re-arms in place with a fresh
                 # deadline (oracle add_every_state on arrival — the wait
                 # restarts from the arrival)
                 s.deadline = jnp.where(kill, ts + u.waiting_ms,
                                        s.deadline)
+                s.count_armed(kill)
                 s.start = jnp.where(kill, ts, s.start)
                 s.enter = jnp.where(kill, ts, s.enter)
             else:
@@ -1009,6 +1100,7 @@ def _one_partition_step(spec: NfaSpec, carry: Dict, event):
         if t0 < S and units[t0].kind == "absent":
             s.deadline = jnp.where(live_arm & (s.st == t0),
                                    ts + units[t0].waiting_ms, s.deadline)
+            s.count_armed(live_arm & (s.st == t0))
 
     # ---- every-min-0 SEQUENCE seed: the virgin closed this event while
     # the event also passes the kleene condition — the oracle's re-init
@@ -1049,34 +1141,14 @@ def _one_partition_step(spec: NfaSpec, carry: Dict, event):
         spm, rk = s.spawn[g0]
         s.alloc_clones(g0, spm, rk, ts)
 
-    # ---- absent deadline pass: virtual time has reached ts, so every due
-    # `not … for t` deadline fires now — AFTER the event was processed (the
-    # playback scheduler advances to an event's time after routing it);
-    # ascending unit order cascades an absence chain in one pass.  Slots
-    # that advance here capture the NEXT event onward.
-    if s.deadline is not None:
-        for j, u in enumerate(units):
-            if u.kind != "absent":
-                continue
-            fire = valid & (s.st == j) & (s.deadline <= ts)
-            s.land(fire, j, s.deadline)
+    # ---- SEQUENCE: a slot that reached an absent unit during this event
+    # with a deadline already due
+    if spec.is_sequence and s.deadline is not None:
+        s.fire_deadlines(ts, valid, by_timer)
 
     match_caps = s.m_caps
 
-    out = {"slot_state": s.st, "slot_start": s.start,
-           "slot_enter": s.enter, "slot_seq": s.seq, "arm_seq": s.arm_seq,
-           "captures": s.caps, "dropped": s.dropped}
-    if s.cnt_cur is not None:
-        out["cnt_cur"] = s.cnt_cur
-        out["cnt_prev"] = s.cnt_prev
-    if s.seq_froze is not None:
-        out["seq_froze"] = s.seq_froze
-    if s.lmask is not None:
-        out["lmask"] = s.lmask
-    if s.deadline is not None:
-        out["deadline"] = s.deadline
-    if s.armed_total is not None:
-        out["armed_total"] = s.armed_total
+    out = s.to_carry()
     if tel is not None:
         # gate pass/fail per unit: reuse the conds/st_pre/stream values
         # the transitions consumed — an "eligible" slot sat at unit j on
@@ -1138,7 +1210,7 @@ def build_block_step(spec: NfaSpec, batch_b: Optional[int] = None,
     B = resolve_batch_b(spec.batch_b or None) if batch_b is None \
         else resolve_batch_b(batch_b)
 
-    def per_partition(carry_p, events_p):
+    def scan_events(carry_p, events_p):
         def step(c, ev):
             return _one_partition_step(spec, c, ev)
         if B == 1:
@@ -1157,6 +1229,36 @@ def build_block_step(spec: NfaSpec, batch_b: Optional[int] = None,
         carry2, ys = jax.lax.scan(tick, carry_p, chunks, unroll=unroll)
         ys = tuple(y.reshape((ticks * B,) + y.shape[2:])[:T] for y in ys)
         return carry2, ys
+
+    def per_partition(carry_p, events_p):
+        if CLOCK_KEY not in events_p:
+            return scan_events(carry_p, events_p)
+        # the block carries its own clock (its latest event time, over all
+        # lanes): after the lane's last event every deadline the clock has
+        # reached fires, in this same step and for lanes with no event
+        # too, so no host TIMER row has to be stepped for it.  The rows
+        # ride the lane's last time row (a slot cannot complete twice at
+        # one row: one that matched there stays for the next block), so
+        # the block is no deeper than its events made it.
+        events_p = dict(events_p)
+        clock = events_p.pop(CLOCK_KEY)[0]
+        carry2, (mask, caps, ts, enter, seq) = scan_events(carry_p,
+                                                           events_p)
+        s = _StepState(spec, carry2, spec.n_slots)
+        s.fire_deadlines(clock, ~mask[-1])
+        for g0 in sorted(s.spawn):
+            s.alloc_clones(g0, *s.spawn[g0], clock)
+        m = s.m_mask
+        ys = (mask.at[-1].set(mask[-1] | m),
+              caps.at[-1].set(jnp.where(m[:, None, None], s.m_caps,
+                                        caps[-1])),
+              ts.at[-1].set(jnp.where(m, s.m_ts, ts[-1])),
+              enter.at[-1].set(jnp.where(m, s.m_enter, enter[-1])),
+              seq.at[-1].set(jnp.where(m, s.m_seq, seq[-1])))
+        out = s.to_carry()
+        if "telem" in carry2:
+            out["telem"] = carry2["telem"]
+        return out, ys
 
     def block_step(carry, block):
         return jax.vmap(per_partition)(carry, block)

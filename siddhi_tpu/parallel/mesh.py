@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.nfa import NfaSpec, build_block_step, make_carry
+from ..ops.nfa import CLOCK_KEY, NfaSpec, build_block_step, make_carry
 
 
 def partition_mesh(devices: Optional[Sequence] = None,
@@ -89,7 +89,8 @@ def jit_engine_step(spec: NfaSpec, mesh: Mesh, axis: str = "p",
         lambda v: lead_axis_sharding(mesh, v, axis), proto_carry)
     block_sh = {name: NamedSharding(mesh, P(axis, None))
                 for name in list(spec.attr_names) +
-                ["__ts", "__stream", "__valid"]}
+                ["__ts", "__stream", "__valid"] +
+                ([CLOCK_KEY] if "deadline" in proto_carry else [])}
 
     def lead(nd):
         return NamedSharding(mesh, P(axis, *([None] * (nd - 1))))

@@ -32,7 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..compiler import SiddhiCompiler
-from ..ops.nfa import (COUNT_INF, NfaSpec, UnitSpec, build_block_step,
+from ..ops.nfa import (ABSENT_CTR, CLOCK_KEY, COUNT_INF, NfaSpec,
+                       UnitSpec, build_block_step,
                        make_carry, make_timer_block, pack_blocks,
                        resolve_batch_b)
 from ..query_api import (AbsentStreamStateElement, CountStateElement,
@@ -1031,6 +1032,13 @@ class CompiledPatternNFA:
             telemetry=bool(telemetry))
         self.has_absent = any(u.kind == "absent" for u in self.units)
         self.last_min_deadline: Optional[int] = None
+        # the playback clock as this engine's blocks have carried it: the
+        # largest event (or TIMER) time stepped so far, absolute ms
+        self.clock: Optional[int] = None
+        # ABSENT_CTR summed over the lanes, as the last retired
+        # block's egress tail gave them, and TIMER rows stepped
+        self.absent_counts = np.zeros(len(ABSENT_CTR), np.int64)
+        self.timer_rows_total = 0
         self.last_telemetry = None   # [P, 3S+1] host int32 after retire
         from ..parallel.mesh import auto_mesh, round_up_partitions
         self.mesh = auto_mesh() if isinstance(mesh, str) and mesh == "auto" \
@@ -1792,8 +1800,14 @@ class CompiledPatternNFA:
                 pad)
             carry = {k: np.concatenate([carry[k], np.asarray(fresh[k])],
                                        axis=0) for k in carry}
+        # a leaf the snapshot's engine did not have yet starts empty
+        for k, v in make_carry(self.spec, 1).items():
+            if k not in carry:
+                carry[k] = np.zeros((self.n_partitions,) + v.shape[1:],
+                                    v.dtype)
         self.carry = self._place_carry(carry)
         self.base_ts = state["base_ts"]
+        self.clock = None
         dec = state.get("str_decoder")
         if dec is not None and self.encoded_attrs:
             # the carry is replaced wholesale by the snapshot's, so its
@@ -1835,7 +1849,8 @@ class CompiledPatternNFA:
         R = max(self.spec.n_rows, 1)
         C = max(self.spec.n_caps, 1)
 
-        def pack(mask, caps, ts, enter, seq, dropped, dl_st, dl, cap):
+        def pack(mask, caps, ts, enter, seq, dropped, dl_st, dl, cap,
+                 ctr=None):
             flat = mask.reshape(-1)
             (idx,) = jnp.nonzero(flat, size=cap, fill_value=-1)
             safe = jnp.maximum(idx, 0)
@@ -1860,6 +1875,12 @@ class CompiledPatternNFA:
                 dmin = jnp.min(jnp.where(waiting, dl,
                                          jnp.int32(2 ** 31 - 1)))
                 tail = tail.at[0, 2].set(dmin)
+            if ctr is not None:
+                # ABSENT_CTR, summed over the lanes, in a row of
+                # their own before the tail: the same transfer
+                crow = jnp.zeros((1, 4 + R * C), jnp.int32)
+                crow = crow.at[0, :ctr.shape[1]].set(jnp.sum(ctr, axis=0))
+                return jnp.concatenate([rows, crow, tail], axis=0)
             return jnp.concatenate([rows, tail], axis=0)
 
         return pack
@@ -1895,8 +1916,9 @@ class CompiledPatternNFA:
         dropped = self.carry["dropped"]
         dl_st = self.carry["slot_state"] if self.has_absent else None
         dl = self.carry.get("deadline") if self.has_absent else None
+        ctr = self.carry.get("absent_ctr") if self.has_absent else None
         buf = self._egress_jit(mask, caps, ts, enter, seq, dropped,
-                               dl_st, dl, self._egress_cap)
+                               dl_st, dl, self._egress_cap, ctr)
         # on-device telemetry rides the SAME slab/transfer as the match
         # buffer — readout costs no extra D2H dispatch
         telem = self.carry.get("telem") if self.spec.telemetry else None
@@ -1914,6 +1936,7 @@ class CompiledPatternNFA:
                 telem.copy_to_host_async()
         return {"buf": buf, "fuse": token, "cap": self._egress_cap,
                 "outs": outs, "dropped": dropped, "dl_st": dl_st, "dl": dl,
+                "ctr": ctr,
                 "dl_base": self.base_ts, "tk": (T, K), "telem": telem}
 
     def egress_retire(self, handle):
@@ -1946,7 +1969,7 @@ class CompiledPatternNFA:
             mask, caps, ts, enter, seq = handle["outs"]
             buf = np.asarray(self._ensure_egress_jit()(
                 mask, caps, ts, enter, seq, handle["dropped"],
-                handle["dl_st"], handle["dl"], cap))
+                handle["dl_st"], handle["dl"], cap, handle.get("ctr")))
             count = int(buf[-1, 0])
             self.last_dropped_total = int(buf[-1, 1])
         if self.has_absent:
@@ -1954,6 +1977,9 @@ class CompiledPatternNFA:
             self.last_min_deadline = (
                 None if dmin == 2 ** 31 - 1
                 else dmin + (handle["dl_base"] or 0))
+            if handle.get("ctr") is not None:
+                self.absent_counts = buf[-2, :len(ABSENT_CTR)] \
+                    .astype(np.uint32).astype(np.int64)
         return buf[:count], handle["tk"]
 
     def _compact_egress(self, mask, caps, ts, enter, seq):
@@ -2102,6 +2128,15 @@ class CompiledPatternNFA:
         c["arm_seq"] = c["arm_seq"] + empty.astype(np.int32)
         self.carry = self._place_carry(c)
 
+    def _stamp_clock(self, block: Dict[str, np.ndarray], ts_max: int):
+        """The block's clock goes in with the block (an automaton with a
+        `not … for t` unit only): its closing pass fires every deadline
+        due by then, on lanes with no event too."""
+        if self.has_absent:
+            self.clock = max(self.clock or ts_max, ts_max)
+            block[CLOCK_KEY] = np.full(
+                (self.n_partitions, 1), self.clock - self.base_ts, np.int32)
+
     def process_timer(self, now_ms: int):
         """Inject one virtual TIMER row at absolute time now_ms (absent
         deadlines + within expiry between real events)."""
@@ -2115,6 +2150,8 @@ class CompiledPatternNFA:
         self._maybe_rebase(now_ms, now_ms)
         block = make_timer_block(self.n_partitions, now_ms - self.base_ts,
                                  self.attr_names)
+        self._stamp_clock(block, now_ms)
+        self.timer_rows_total += self.n_partitions
         # numpy leaves: jit places them per its in_shardings (sharded under
         # a mesh) — pre-committing to one device would conflict
         outs = self.process_block(block)
@@ -2179,6 +2216,8 @@ class CompiledPatternNFA:
                                 np.asarray(timestamps), codes,
                                 self.n_partitions, base_ts=self.base_ts,
                                 pad_t_pow2=pad_t_pow2)
+        if ts_range is not None:
+            self._stamp_clock(block, ts_range[1])
         if bucket is not None:
             # cross-tenant super-dispatch (plan/xtenant.py): defer the
             # block into the tenant's bucket — the gang step runs it

@@ -35,7 +35,7 @@ from ..query_api.expression import Variable
 from ..query_api.query import OutputEventsFor
 from ..utils.errors import (SiddhiAppCreationError,
                             SiddhiAppRuntimeException)
-from ..core.ledger import ON_FLUSH, ledger as _ledger
+from ..core.ledger import ABSENT_COUNTERS, ON_FLUSH, ledger as _ledger
 from ..core.stateschema import Keyed, persistent_schema
 from ..parallel.shards import build_shards, resolve_shards, split_rows
 from .nfa_compiler import CompiledPatternNFA
@@ -373,7 +373,10 @@ class DevicePatternRuntime:
                               in self.nfa.select_outputs
                               if row in self.nfa.nullable_rows}
         self._scheduled_deadline = -1
+        self._in_timer = False
         self._shutdown = False
+        # the engine's absent counters as last handed to the ledger
+        self._absent_seen = np.zeros(len(ABSENT_COUNTERS), np.int64)
 
         # one receiver per distinct input stream, on the global junctions
         for stream_id, code in self.nfa.stream_codes.items():
@@ -670,13 +673,16 @@ class DevicePatternRuntime:
                     self.nfa.grow_slots(self.nfa.spec.n_slots * 2)
                 self._emit_columns(pids, ts, cols)
             if self.nfa.has_absent:
+                self._note_absent()
                 self._schedule_absent(self.nfa.last_min_deadline)
             return
         self._dropped_seen = max(dropped, self._dropped_seen)
         self._emit_columns(pids, ts, cols, h.get("seq"))
         if self.nfa.has_absent:
-            # schedule off the retired chunk's carry — the deadline rode
-            # the egress tail, no extra device read (see egress_dispatch)
+            # schedule off the retired chunk's carry — the deadline and
+            # the counters rode the egress tail, no extra device read
+            # (see egress_dispatch)
+            self._note_absent()
             self._schedule_absent(self.nfa.last_min_deadline)
 
     def settle(self) -> bool:
@@ -739,28 +745,80 @@ class DevicePatternRuntime:
 
     # -------------------------------------------------- absent-state timers
 
+    def _note_absent(self) -> None:
+        """Hand the ledger what the engine's absent counters grew by."""
+        cur = np.append(self.nfa.absent_counts, self.nfa.timer_rows_total)
+        if (cur != self._absent_seen).any():
+            _ledger().note_absent(self.app_name, cur - self._absent_seen)
+            self._absent_seen = cur
+
     def _schedule_absent(self, dl: Optional[int] = "read") -> None:
         """Arm a host TIMER at the earliest pending `not … for t` deadline
         (≙ AbsentStreamPreStateProcessor scheduling wakeups via
         util/Scheduler.java).  Retirement passes the egress-borne value;
-        start/restore/timer paths read the live carry."""
+        start/restore/timer paths read the live carry.
+
+        Every block carries its clock and fires, inside its own step, the
+        deadlines its events have reached (ops/nfa.build_block_step).  The
+        TIMER is for time this runtime's own events do not bring: a send
+        on a stream the pattern does not consume, playback's idle
+        heartbeat, an explicit advance, the wall clock.  Whoever moves the
+        app's clock, the TIMER takes its turn behind the chunks already
+        sent to this runtime (`_on_clock`), and there it finds out
+        whether they brought the time themselves."""
         if dl == "read":
             dl = self.nfa.min_pending_deadline()
-        if dl is None or dl == self._scheduled_deadline or self._shutdown:
+        if dl == self._scheduled_deadline or self._shutdown or \
+                self._in_timer:
+            return
+        if dl is None or self._brought(dl):
+            self._scheduled_deadline = -1   # a TIMER still out is stale
             return
         self._scheduled_deadline = dl
-        app_ctx = self.qr.app_runtime.app_ctx
+        sched = self.qr.app_runtime.app_ctx.scheduler
 
         def fire(now, _dl=dl):
-            if self._shutdown:
+            # (the time a send advanced to, not the app's clock: that one
+            # moves before the send's chunk is in the queue)
+            now = max(now, _dl, sched.advanced_to)
+            for j in self._junctions.values():
+                j.call_in_order(partial(self._on_clock, _dl, now))
+        if dl <= sched.advanced_to:
+            # playback stood there before this deadline was known
+            # (another stream's send, or this one's still queued): no
+            # advance would come for it
+            fire(dl)
+        else:
+            sched.notify_at(dl, fire)
+
+    def _brought(self, dl: int) -> bool:
+        """Do this runtime's own events bring the time `dl`?  A block in
+        flight carries its clock there: it fires `dl` in its own step,
+        and its retire arms what is pending then."""
+        return bool(self._inflight) and (self.nfa.clock or 0) >= dl
+
+    def _on_clock(self, dl: int, now: int) -> None:
+        """The app's clock passed the TIMER armed at `dl` and stood at
+        `now`; called in junction order.  If this runtime's own blocks
+        brought the time meanwhile: no flush, no TIMER row, no launch.
+        Else step one TIMER row at `now`: it lands every due slot at its
+        own deadline."""
+        with self.qr.lock:
+            if self._shutdown or dl != self._scheduled_deadline:
+                return          # a retire has armed a later one since
+            self._scheduled_deadline = -1
+            if self._brought(dl):
                 return
-            with self.qr.lock:
-                self.flush()
-                matches = self.nfa.process_timer(max(now, _dl))
+            with _ledger().span("device", "timer"):
+                self._in_timer = True   # (the flush's retires arm nothing)
+                try:
+                    self.flush()
+                    matches = self.nfa.process_timer(now)
+                finally:
+                    self._in_timer = False
+                self._note_absent()
                 self._emit(matches)
-                self._scheduled_deadline = -1
-                self._schedule_absent()
-        app_ctx.scheduler.notify_at(dl, fire)
+            self._schedule_absent()
 
     # ------------------------------------------------------------ lifecycle
 

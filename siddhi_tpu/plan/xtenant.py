@@ -116,8 +116,9 @@ def _build_gang(nfas: List[Any], trigger: str = "build"):
             nc, (mask, cp, ts, enter, seq) = steps[i](carries[i], blocks[i])
             dl_st = nc["slot_state"] if absent[i] else None
             dl = nc.get("deadline") if absent[i] else None
+            ctr = nc.get("absent_ctr") if absent[i] else None
             buf = packs[i](mask, cp, ts, enter, seq, nc["dropped"],
-                           dl_st, dl, caps[i])
+                           dl_st, dl, caps[i], ctr)
             out.append((nc, buf, (mask, cp, ts, enter, seq),
                         nc.get("telem") if telem[i] else None))
         return out
@@ -250,6 +251,7 @@ class TenantBucket:
                      dropped=nc["dropped"],
                      dl_st=nc["slot_state"] if nfa.has_absent else None,
                      dl=nc.get("deadline") if nfa.has_absent else None,
+                     ctr=nc.get("absent_ctr") if nfa.has_absent else None,
                      dl_base=h["base_ts"], tk=(int(T), int(K)), telem=tele,
                      pre_carry=pc, pre_base=pb, t_issue=t_issue)
             h.pop("xpend", None)

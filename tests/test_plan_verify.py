@@ -237,7 +237,7 @@ def test_pv010_host_callback():
 def test_pv011_float64_upcast():
     import jax
     import jax.numpy as jnp
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         diags = sanitize_step(
             "k", lambda x: x * 2.0, jnp.zeros((4,), jnp.float64))
     assert "PV011" in _codes(diags)
