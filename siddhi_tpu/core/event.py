@@ -89,7 +89,7 @@ class EventChunk:
     StateEvent (join/pattern output rows, event/state/StateEvent.java)."""
 
     __slots__ = ("timestamps", "types", "columns", "names", "qualified",
-                 "is_batch", "ledger_ns", "block_seq")
+                 "is_batch", "ledger_ns", "block_seq", "factors")
 
     def __init__(self, names: Sequence[str], timestamps: np.ndarray,
                  types: np.ndarray, columns: Dict[str, np.ndarray],
@@ -114,6 +114,12 @@ class EventChunk:
         # it is delivered): the `block` of every ledger span of its
         # delivery
         self.block_seq = None
+        # what the receivers of this chunk have factored of it, for the
+        # receivers after them (core/keyfactor.py): a partition
+        # executor's keys under the executor, a string column's values
+        # under ("col", name).  Made on first use; NOT carried by
+        # transforms — a derived chunk is other events
+        self.factors = None
 
     # ------------------------------------------------------------ constructors
 

@@ -86,13 +86,17 @@ _stage_values = itemgetter(*STAGES)     # the seven accumulators at once
 #: renamed span fails a test (benchmark/tests/test_setup_reader.py holds
 #: every metric file to this list), not a run.  What each one wraps:
 #:
-#:   dispatch.keys    the partition-key executor over the chunk
-#:   dispatch.lanes   key -> lane map (``map_keys_to_lanes``)
+#:   dispatch.keys    the partition-key executor's factor of the chunk:
+#:                    made by the first query of a partition that meets
+#:                    the chunk, found on it by the others
+#:   dispatch.lanes   key -> lane map (``map_keys_to_lanes``) over the
+#:                    distinct keys, and the gather to events
 #:   dispatch.cols    kernel input columns (``_event_cols``)
 #:   dispatch.pack    the windowed-agg runtime's ``pack_blocks`` (it sits
 #:                    under ``dispatch`` there, and stage membership is
 #:                    not this list's to change)
-#:   device.encode    string dictionary encoding / derived lanes
+#:   device.encode    string dictionary encoding (per distinct value,
+#:                    then a gather) / derived lanes
 #:   device.pack      the NFA's dense ``[P, T]`` scatter (``pack_blocks``)
 #:   device.sync      a gang bucket's flush, up to and after the gang call
 #:   device.issue     one registry-jitted call (``RegisteredJit.__call__``,
@@ -153,6 +157,12 @@ ON_READY, ON_DEPTH, ON_FLUSH = 2, 3, 4
 ABSENT_COUNTERS = ("absent_armed_total", "absent_fired_total",
                    "absent_fired_inblock_total", "absent_killed_total",
                    "absent_timer_rows_total")
+
+#: the per-app counters of the keyed device runtimes' ingests, in the
+#: order of a ``_keyfac`` row: ingests that asked their partition
+#: executor for a block's factored keys (core/keyfactor.py); of those,
+#: the ones that found it made by an earlier query of the partition
+KEY_FACTOR_COUNTERS = ("key_factor_total", "key_factor_reused_total")
 
 
 # os.environ.get pays ~0.9 us per call (key encode + value decode);
@@ -517,6 +527,8 @@ class LatencyLedger:
         # app -> ABSENT_COUNTERS row.  Kept past drop_app, as the stage
         # accumulators are: a run reads it after its app shut down
         self._absent: Dict[str, list] = {}
+        # app -> KEY_FACTOR_COUNTERS row, kept as ``_absent`` is
+        self._keyfac: Dict[str, list] = {}
         # app -> the most recent block's stage deltas (waterfall row)
         self._last_deltas: Dict[str, list] = {}
         # (app, stream) -> lag watermark state
@@ -625,6 +637,22 @@ class LatencyLedger:
                     app, [0] * len(ABSENT_COUNTERS))
         for i, d in enumerate(deltas):
             row[i] += int(d)
+
+    def note_key_factor(self, app: str, reused: bool) -> None:
+        """One keyed device ingest asked for its block's factored keys."""
+        row = self._keyfac.get(app)
+        if row is None:
+            with self._lock:
+                row = self._keyfac.setdefault(
+                    app, [0] * len(KEY_FACTOR_COUNTERS))
+        row[0] += 1
+        row[1] += reused
+
+    def _counter_rows(self):
+        """(counter names, app -> row) of every per-app counter family."""
+        return ((RETIRE_COUNTERS, self._retires),
+                (ABSENT_COUNTERS, self._absent),
+                (KEY_FACTOR_COUNTERS, self._keyfac))
 
     # ------------------------------------------------------ block fold
 
@@ -775,7 +803,7 @@ class LatencyLedger:
             "stage_spans": dict(self._spans),
         }
         apps = sorted({a for (a, _s) in self._hist} | set(self._absent)
-                      ) if app is None else [app]
+                      | set(self._keyfac)) if app is None else [app]
         per_app = {}
         for a in apps:
             entry: Dict[str, Any] = {"stages_ms": self._stage_summary(a)}
@@ -791,12 +819,10 @@ class LatencyLedger:
             last = self._last_deltas.get(a)
             if last:
                 entry["last_block_ms"] = self._row_ms(last)
-            row = self._retires.get(a)
-            if row is not None:
-                entry.update(zip(RETIRE_COUNTERS, row))
-            row = self._absent.get(a)
-            if row is not None:
-                entry.update(zip(ABSENT_COUNTERS, row))
+            for names, rows in self._counter_rows():
+                row = rows.get(a)
+                if row is not None:
+                    entry.update(zip(names, row))
             per_app[a] = entry
         doc["apps"] = per_app
         return doc
@@ -815,14 +841,11 @@ class LatencyLedger:
             lab = _fmt_labels({"span": key})
             lines.append(f"siddhi_ledger_span_seconds_total{lab} "
                          f"{self._ns[key] / 1e9:.9g}")
-        for app, row in sorted(self._retires.items()):
-            lab = _fmt_labels({"app": app})
-            for name, n in zip(RETIRE_COUNTERS, row):
-                lines.append(f"siddhi_{name}{lab} {n}")
-        for app, row in sorted(self._absent.items()):
-            lab = _fmt_labels({"app": app})
-            for name, n in zip(ABSENT_COUNTERS, row):
-                lines.append(f"siddhi_{name}{lab} {n}")
+        for names, rows in self._counter_rows():
+            for app, row in sorted(rows.items()):
+                lab = _fmt_labels({"app": app})
+                for name, n in zip(names, row):
+                    lines.append(f"siddhi_{name}{lab} {n}")
         for (app, stage), h in sorted(self._hist.items()):
             if not h.count:
                 continue
@@ -861,6 +884,7 @@ class LatencyLedger:
             del self._named[:]
             self._retires.clear()
             self._absent.clear()
+            self._keyfac.clear()
             self._last_deltas.clear()
             self._lag.clear()
             self._slo.clear()

@@ -11,7 +11,8 @@ for it.
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Optional
+from functools import partial
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -21,6 +22,7 @@ from ..query_api import (Partition, Query, RangePartitionType,
 from ..query_api.definition import StreamDefinition
 from ..utils.errors import DefinitionNotExistError, SiddhiAppCreationError
 from .event import EventChunk
+from .keyfactor import Factor, factor_keys, factor_values, memoized
 from .query_runtime import QueryRuntime
 from .stateschema import PartitionState, persistent_schema
 from .stream import StreamJunction
@@ -153,17 +155,21 @@ class _PartitionExecutor:
         else:
             raise SiddhiAppCreationError(f"Unknown partition type {pt!r}")
 
+    def _values(self, chunk: EventChunk) -> np.ndarray:
+        n = len(chunk)
+        v = self.value_expr.fn(EvalCtx(chunk.columns, chunk.timestamps, n))
+        arr = np.asarray(v)
+        if arr.ndim == 0:
+            arr = np.broadcast_to(arr, (n,))
+        return arr
+
     def keys(self, chunk: EventChunk) -> List[Optional[str]]:
         n = len(chunk)
-        ctx = EvalCtx(chunk.columns, chunk.timestamps, n)
         if self.value_expr is not None:
-            v = self.value_expr.fn(ctx)
-            arr = np.asarray(v)
-            if arr.ndim == 0:
-                arr = np.broadcast_to(arr, (n,))
             return [None if x is None else str(x) for x in
                     (x.item() if isinstance(x, np.generic) else x
-                     for x in arr)]
+                     for x in self._values(chunk))]
+        ctx = EvalCtx(chunk.columns, chunk.timestamps, n)
         out: List[Optional[str]] = [None] * n
         for key, cond in self.ranges:
             m = np.asarray(cond.fn(ctx), bool)
@@ -173,6 +179,28 @@ class _PartitionExecutor:
                 if out[i] is None and m[i]:
                     out[i] = key
         return out
+
+    def factor(self, chunk: EventChunk) -> Tuple[Factor, bool]:
+        """``keys(chunk)`` as its distinct keys and each event's place
+        among them (core/keyfactor.py), the events without a key masked
+        out by ``keep``; and whether it was already there.  The first
+        query of a partition that meets a chunk makes it, per distinct
+        value where the values allow and from the per-event list where
+        they do not, and leaves it on the chunk for the partition's other
+        queries: they get the one executor and the one chunk object."""
+        return memoized(chunk, self, partial(self._factor, chunk))
+
+    def _factor(self, chunk: EventChunk) -> Factor:
+        f = None
+        if self.value_expr is not None:
+            arr = self._values(chunk)
+            f = factor_values(arr)
+            if f is not None:
+                f.source = next((name for name, col in chunk.columns.items()
+                                 if col is arr), None)
+        if f is None:
+            f = factor_keys(self.keys(chunk))
+        return f.compressed()
 
 
 class _PartitionStreamReceiver:

@@ -569,6 +569,12 @@ LEDGER_TYPES = [
     ("siddhi_absent_timer_rows_total",
      "counter", "TIMER rows stepped on the device by host TIMERs of "
      "absent patterns"),
+    ("siddhi_key_factor_total",
+     "counter", "Keyed device ingests that asked their partition executor "
+     "for a block's factored keys"),
+    ("siddhi_key_factor_reused_total",
+     "counter", "Of those, ingests that found the factor already made by "
+     "an earlier query of the partition"),
     ("siddhi_ledger_stage_latency_ms",
      "gauge", "Per-app latency quantiles (ms): a stage per block, a named "
      "sub-span per execution, a wait per block in flight"),

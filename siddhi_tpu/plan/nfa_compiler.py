@@ -44,6 +44,7 @@ from ..query_api.definition import AttrType
 from ..query_api.expression import (And, Compare, CompareOp, Constant, IsNull,
                                     Not, Or, TimeConstant, Variable,
                                     variables_of)
+from ..core.keyfactor import factor_values
 from ..core.ledger import ledger as _ledger
 from ..core.stateschema import (Carry, ListOf, Scalar, Struct,
                                 persistent_schema)
@@ -1224,11 +1225,23 @@ class CompiledPatternNFA:
         res = res & ~none
         return res.astype(np.float32)
 
-    def encode_column(self, col) -> np.ndarray:
+    def encode_column(self, col, factor) -> np.ndarray:
         """String column → float32 code lane (dictionary grows on first
         sight of a value; ingest-side, host).  Nulls map to the reserved
         code 0, which every rewritten compare guards against — host
-        parity: null operands compare false."""
+        parity: null operands compare false.  ``factor`` is the column
+        factored (core/keyfactor.py): one dictionary probe per distinct
+        value, new values taking their codes in the order of the distinct
+        values, then a gather.  A column that is not one of strings (None
+        for its factor) is encoded event by event."""
+        if factor is not None:
+            vals = factor.uniq.tolist()
+            got = list(map(self.str_encoder.get, vals))
+            if None in got:
+                got = [self._encode_str(v) for v in vals]
+            codes = np.zeros(len(vals) + 1, np.float32)     # [-1]: null
+            codes[:-1] = got
+            return codes[factor.inv]
         out = np.empty(len(col), np.float32)
         for i, v in enumerate(col):
             v = v.item() if hasattr(v, "item") else v
@@ -2162,9 +2175,13 @@ class CompiledPatternNFA:
                         timestamps: np.ndarray,
                         stream_names: Optional[np.ndarray] = None,
                         stream_codes: Optional[np.ndarray] = None,
-                        pad_t_pow2: bool = False) -> dict:
+                        pad_t_pow2: bool = False,
+                        factor_of=None) -> dict:
         """Pack + dispatch one flat event batch and start its egress D2H
         transfer without blocking; returns a handle for retire_events.
+        ``factor_of(name)`` gives an encoded string column's factor
+        (core/keyfactor.py) where the caller holds the chunk it may
+        already be on; None for a column that has to go event by event.
         The pipelined engine path (plan/planner.py) keeps a few handles in
         flight so the egress read of chunk N overlaps chunk
         N+1's dispatch; the handle carries everything needed to replay the
@@ -2209,7 +2226,9 @@ class CompiledPatternNFA:
                 else:
                     c = columns[a]
                     if a in self.encoded_attrs:
-                        c = self.encode_column(c)
+                        c = self.encode_column(
+                            c, factor_of(a) if factor_of is not None else
+                            factor_values(np.asarray(c), strings_only=True))
                 cols[a] = np.asarray(c)
         with led.span("device", "pack"):
             block = pack_blocks(np.asarray(partition_ids), cols,

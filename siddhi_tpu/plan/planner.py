@@ -35,6 +35,7 @@ from ..query_api.expression import Variable
 from ..query_api.query import OutputEventsFor
 from ..utils.errors import (SiddhiAppCreationError,
                             SiddhiAppRuntimeException)
+from ..core.keyfactor import Factor, column_factor
 from ..core.ledger import ABSENT_COUNTERS, ON_FLUSH, ledger as _ledger
 from ..core.stateschema import Keyed, persistent_schema
 from ..parallel.shards import build_shards, resolve_shards, split_rows
@@ -169,17 +170,31 @@ class KeyLanes(dict):
         return self._vlanes[pos]
 
 
-def map_keys_to_lanes(key_lanes: Dict[Any, int], keys: List[Any],
+def map_keys_to_lanes(key_lanes: Dict[Any, int], keys,
                       capacity: int, grow_fn) -> np.ndarray:
     """Assign each key a stable lane index, growing the device slab (via
     grow_fn(new_capacity)) when the key population exceeds capacity.
-    String AND integer keys take a vectorized path: one dict probe per
-    DISTINCT key in the batch (np.unique in C) instead of one per event —
-    and zero probes in steady state when key_lanes is a KeyLanes with a
-    warm cache (one searchsorted over the distinct keys)."""
-    arr = np.asarray(keys)
-    if arr.dtype.kind in "USiu" and len(keys) > 64:
-        uniq, inv = np.unique(arr, return_inverse=True)
+    ``keys`` is a block's keys as its partition executor factored them
+    (core/keyfactor.py ``Factor``: nothing per event is left to do but a
+    gather), or a plain sequence, which string AND integer keys let
+    factor here.  Over 64 events either way: one dict probe per DISTINCT
+    key in the batch, lanes handed out in the order of the sorted
+    distinct keys — and zero probes in steady state when key_lanes is a
+    KeyLanes with a warm cache (one searchsorted over the distinct
+    keys).  Up to 64 events, and keys with no vector order: one probe
+    per event, lanes in the order of first sight."""
+    uniq = None
+    if isinstance(keys, Factor):
+        if len(keys.inv) > 64:
+            uniq, inv = keys.uniq, keys.inv
+        else:
+            keys = keys.keys().tolist()
+    else:
+        arr = np.asarray(keys)
+        if arr.dtype.kind in "USiu" and len(keys) > 64:
+            uniq, inv = np.unique(arr, return_inverse=True)
+            inv = inv.reshape(-1)
+    if uniq is not None:
         lane_of = None
         if isinstance(key_lanes, KeyLanes):
             lane_of = key_lanes.lookup(uniq)
@@ -191,7 +206,7 @@ def map_keys_to_lanes(key_lanes: Dict[Any, int], keys: List[Any],
                     lane = len(key_lanes)
                     key_lanes[k] = lane
                 lane_of[i] = lane
-        lanes = lane_of[inv.reshape(-1)]
+        lanes = lane_of[inv]
     else:
         lanes = np.empty(len(keys), np.int64)
         for i, k in enumerate(keys):
@@ -206,6 +221,18 @@ def map_keys_to_lanes(key_lanes: Dict[Any, int], keys: List[Any],
             cap *= 2
         grow_fn(cap)
     return lanes
+
+
+def _factored_keys(executor, data, app_name: str):
+    """A keyed device ingest's first step: the chunk's keys as the
+    partition executor factors them (once per chunk, whichever of the
+    partition's queries comes first), counted per app, and the chunk
+    without its null-key events.  -> (data, factor)"""
+    kf, reused = executor.factor(data)
+    _ledger().note_key_factor(app_name, reused)
+    if kf.keep is not None:
+        data = data.mask(kf.keep)
+    return data, kf
 
 
 def _check_shard_count(shards, snap_shards) -> None:
@@ -432,7 +459,17 @@ class DevicePatternRuntime:
 
     # ------------------------------------------------------------ ingest
 
-    def _lanes_for_keys(self, keys: List[Any]) -> np.ndarray:
+    @staticmethod
+    def _column_factor(data, keys: Optional[Factor], name: str
+                       ) -> Optional[Factor]:
+        """The factor of an encoded string column of ``data``: the
+        partition key's own where the column is the one the key was
+        taken from, as it came; else the column's, made once per chunk."""
+        if keys is not None and keys.source == name and keys.raw_str:
+            return keys
+        return column_factor(data, name)
+
+    def _lanes_for_keys(self, keys) -> np.ndarray:
         def grow(cap):
             # partition-axis growth invalidates the pre-carries held by
             # in-flight chunks (their P is the old width): retire them
@@ -477,7 +514,7 @@ class DevicePatternRuntime:
 
     # ------------------------------------------------------- sharded path
 
-    def _ingest_sharded(self, stream_code: int, data, keys: List[Any],
+    def _ingest_sharded(self, stream_code: int, data, keys_arr: np.ndarray,
                         n: int) -> None:
         """Route the chunk by consistent key hash and dispatch each
         shard's sub-block on that shard's own engine/device.  One hash
@@ -485,7 +522,6 @@ class DevicePatternRuntime:
         (row indices ascend inside each sub-block); NO collectives —
         every dispatch runs on operands committed to the shard's
         device."""
-        keys_arr = np.asarray(keys)
         cols = self._event_cols(data, n)
         ts_arr = np.asarray(data.timestamps, np.int64)
         for sid, rows in split_rows(keys_arr, len(self.shards)):
@@ -575,22 +611,19 @@ class DevicePatternRuntime:
                     f"device pattern path: stream '{stream_id}' has no "
                     f"partition key executor")
             with led.span("dispatch", "keys"):
-                keys = ex.keys(data)
-                keep = np.asarray([k is not None for k in keys], bool)
-                if not keep.all():
-                    data = data.mask(keep)
-                    keys = [k for k in keys if k is not None]
-                    n = len(data)
+                data, keys = _factored_keys(ex, data, self.app_name)
+                n = len(data)
             if n == 0:
                 return
             if self.shards is not None:
-                self._ingest_sharded(stream_code, data, keys, n)
+                self._ingest_sharded(stream_code, data, keys.keys(), n)
                 _record_block(self, prof, disp0, ticks0, stream_id, n,
                               junction=self._junctions.get(stream_id))
                 return
             with led.span("dispatch", "lanes"):
                 pids = self._lanes_for_keys(keys)
         else:
+            keys = None
             pids = np.zeros(n, np.int64)
         if self.nfa.mesh is not None:
             t_max = int(np.bincount(pids, minlength=1).max())
@@ -607,9 +640,9 @@ class DevicePatternRuntime:
             ts_arr = np.asarray(data.timestamps, np.int64)
             codes = np.full(n, stream_code, np.int32)
         with led.span("device"):
-            h = self.nfa.dispatch_events(pids, cols, ts_arr,
-                                         stream_codes=codes,
-                                         pad_t_pow2=True)
+            h = self.nfa.dispatch_events(
+                pids, cols, ts_arr, stream_codes=codes, pad_t_pow2=True,
+                factor_of=partial(self._column_factor, data, keys))
         stamp_submit(h)
         self._inflight.append(h)
         # with depth 0 every chunk retires here (synchronous: matches
@@ -1045,16 +1078,13 @@ class DeviceWindowedAggRuntime(PipelinedDeviceIngest):
         disp0 = prof.total_dispatches() if prof.enabled else 0
         ticks0 = prof.total_scan_ticks() if prof.enabled else 0
         with led.span("dispatch", "keys"):
-            keys = self.key_executor.keys(data)
-            keep = np.asarray([k is not None for k in keys], bool)
-            if not keep.all():
-                data = data.mask(keep)
-                keys = [k for k in keys if k is not None]
+            data, keys = _factored_keys(self.key_executor, data,
+                                        self.app_name)
         if data.is_empty:
             return
         n = len(data)
         if self.shards is not None:
-            self._ingest_sharded(data, keys)
+            self._ingest_sharded(data, keys.keys())
             _record_block(self, prof, disp0, ticks0, stream_id, n)
             return
         with led.span("dispatch", "lanes"):
@@ -1098,13 +1128,12 @@ class DeviceWindowedAggRuntime(PipelinedDeviceIngest):
                       "lanes": lanes, "rows": rows})
         _record_block(self, prof, disp0, ticks0, stream_id, n)
 
-    def _ingest_sharded(self, data, keys: List[Any]) -> None:
+    def _ingest_sharded(self, data, keys_arr: np.ndarray) -> None:
         """Hash-route the chunk and run each shard's sub-block through
         its own window slab.  The retire path is untouched: a work item
         carries its own lanes/rows/data, and _retire never mutates
         engine state, so shard works share the pipeline queue safely."""
         from ..ops.nfa import pack_blocks
-        keys_arr = np.asarray(keys)
         ts_all = np.asarray(data.timestamps, np.int64)
         for sid, rows_idx in split_rows(keys_arr, len(self.shards)):
             sh = self.shards[sid]
@@ -1361,15 +1390,12 @@ class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
         disp0 = prof.total_dispatches() if prof.enabled else 0
         ticks0 = prof.total_scan_ticks() if prof.enabled else 0
         if self.keyed:
-            keys = self.key_executor.keys(data)
-            keep = np.asarray([k is not None for k in keys], bool)
-            if not keep.all():
-                data = data.mask(keep)
-                keys = [k for k in keys if k is not None]
-                if data.is_empty:
-                    return
+            data, keys = _factored_keys(self.key_executor, data,
+                                        self.app_name)
+            if data.is_empty:
+                return
             if self.shards is not None:
-                self._ingest_sharded(data, keys)
+                self._ingest_sharded(data, keys.keys())
                 _record_block(self, prof, disp0, ticks0, stream_id,
                               len(data))
                 return
@@ -1385,12 +1411,11 @@ class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
         self._submit(work)
         _record_block(self, prof, disp0, ticks0, stream_id, len(data))
 
-    def _ingest_sharded(self, data, keys: List[Any]) -> None:
+    def _ingest_sharded(self, data, keys_arr: np.ndarray) -> None:
         """Hash-route the chunk; each shard's sub-block dispatches on its
         own engine.  Works carry a "shard" tag so the retire path decodes
         (and, on overflow, rewinds/replays) against the right engine
         while sibling shards' in-flight works stay queued untouched."""
-        keys_arr = np.asarray(keys)
         for sid, rows in split_rows(keys_arr, len(self.shards)):
             sh = self.shards[sid]
             m = np.zeros(len(data), bool)
