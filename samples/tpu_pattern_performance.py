@@ -1,5 +1,5 @@
 """TPU pattern-bank harness (the BASELINE north-star config at reduced
-default size; see bench.py for the full 1k x 10k measurement)."""
+default size; the measured configurations are benchmark/run.py's cells)."""
 import sys
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])

@@ -27,8 +27,6 @@ __version__ = "0.1.0"
 from .analysis import AnalysisResult, Diagnostic, analyze
 from .compiler import SiddhiCompiler
 from .core.event import Event, EventChunk
-from .core.profiling import (KernelProfiler, disable_profiling,
-                             enable_profiling, profiler)
 from .core.runtime import SiddhiAppRuntime, SiddhiManager
 from .core.statistics import StatisticsManager, prometheus_text
 from .core.tracing import Tracer, disable_tracing, enable_tracing, tracer
@@ -49,7 +47,6 @@ __all__ = [
     "SiddhiApp", "StreamDefinition", "Query", "Selector", "Expression",
     "Annotation", "AttrType",
     "StatisticsManager", "prometheus_text",
-    "KernelProfiler", "profiler", "enable_profiling", "disable_profiling",
     "Tracer", "tracer", "enable_tracing", "disable_tracing",
     "analyze", "AnalysisResult", "Diagnostic",
 ]

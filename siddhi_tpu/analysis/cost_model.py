@@ -6,8 +6,8 @@ Prices a compiled plan BEFORE any event is ingested:
     alive between steps.  For pattern automata the formulas mirror
     ``ops/nfa.make_carry`` exactly (slot rings, capture banks, per-kind
     extras), so the prediction is checked byte-exact against the real
-    carry in tests/test_plan_verify.py and against the KernelProfiler's
-    ``live_bytes`` gauge in bench.py (predicted-vs-measured columns).
+    carry in tests/test_plan_verify.py, and against the ``live_bytes``
+    the step's shape entry books at carry placement (plan/shapes.py).
   * **FLOPs per event** — a coarse per-ingested-event work estimate:
     every live slot of a lane evaluates each unit's condition program,
     so cost scales with (condition ops x slot ring width) summed over

@@ -237,7 +237,8 @@ CATALOG: Dict[str, CatalogEntry] = {e.code: e for e in [
        "A device-eligible `every` pattern without `within` will grow its "
        "slot ring as partials accumulate; every doubling rebuilds and "
        "re-JITs the NFA step kernel — an unbounded recompilation storm "
-       "the KernelProfiler surfaces as a rising compile_count.",
+       "the shape registry surfaces as a rising "
+       "siddhi_kernel_compile_count.",
        "Add `within <time>` so live partials are bounded and the ring "
        "never grows."),
     _C("SP002", _I, "retrace-lane-growth",
@@ -307,8 +308,7 @@ CATALOG: Dict[str, CatalogEntry] = {e.code: e for e in [
        "contribute to a match (statically-false skippable conditions, "
        "dead or-sides), shrinking the transition tables and capture "
        "banks.  Match output is unchanged — equivalence is test-asserted.",
-       "Nothing to do; informational.  Set SIDDHI_TPU_NFA_PRUNE=0 to "
-       "disable pruning when diffing against an unpruned plan."),
+       "Nothing to do; informational."),
     _C("PV005", _W, "within-starved",
        "The pattern's `within` bound is smaller than (or equal to) the "
        "summed `not ... for t` waiting times on the match path: every "
@@ -351,13 +351,13 @@ CATALOG: Dict[str, CatalogEntry] = {e.code: e for e in [
        "Static cost-model estimate for a compiled plan: persistent HBM "
        "state bytes (state banks, slot rings, capture banks, agg tables "
        "at current lane counts) and estimated FLOPs per ingested event.  "
-       "Predicted-vs-measured live bytes ride bench.py JSON.",
-       "Nothing to do; informational.  The numbers feed `rt.analysis`, "
-       "GET /stats and the bench.py --fail-on-hbm-budget gate."),
+       "The measured side is siddhi_kernel_live_bytes.",
+       "Nothing to do; informational.  The numbers feed `rt.analysis` "
+       "and GET /stats."),
     _C("PC002", _W, "hbm-budget-exceeded",
        "The plan's predicted persistent HBM footprint exceeds the "
-       "configured budget (analyze --plan --hbm-budget / bench.py "
-       "--fail-on-hbm-budget).  Slot-ring or lane growth at runtime "
+       "configured budget (analyze --plan --hbm-budget).  Slot-ring or "
+       "lane growth at runtime "
        "would start from an already-over-budget base.",
        "Shrink partition lanes / slots / window sizes, shard the plan "
        "across chips, or raise the budget deliberately."),

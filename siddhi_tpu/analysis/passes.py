@@ -195,8 +195,8 @@ def perf_pass(table: SymbolTable, q: Query, qname: Optional[str],
                     "SP001",
                     "within-less `every` pattern on the device path: "
                     "live partials grow the NFA slot ring, and every "
-                    "doubling re-JITs the step kernel (KernelProfiler "
-                    "compile_count rises per doubling)",
+                    "doubling re-JITs the step kernel "
+                    "(siddhi_kernel_compile_count rises per doubling)",
                     pos=pos_of(el) or nearest_pos(ins.state), query=qname)
                 break
 

@@ -338,10 +338,9 @@ def automaton_ir_from_nfa(nfa, query: str) -> AutomatonIR:
 
 
 def _shape_class_of(step) -> str:
-    """Shape-class signature of a (possibly profiler-wrapped) registered
-    jit, or '' — attribute inspection only, tolerant of unrouted fns."""
-    rj = getattr(step, "fn", step)          # unwrap ProfiledKernel
-    entry = getattr(rj, "entry", None)
+    """Shape-class signature of a registered jit, or '' — attribute
+    inspection only, tolerant of unrouted fns."""
+    entry = getattr(step, "entry", None)
     return getattr(entry, "signature", "") or ""
 
 

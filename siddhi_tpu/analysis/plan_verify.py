@@ -230,7 +230,8 @@ def sanitize_runtime(rt) -> List[Diagnostic]:
             for nm in dev._slanes.lane_names():
                 cols[nm] = jnp.zeros((1,), jnp.float32)
             diags += sanitize_step(
-                "filter.program", dev._program.fn, cols,
+                # the jit itself: tracing it is no launch to book
+                "filter.program", dev._program._jitted, cols,
                 jnp.zeros((1,), jnp.int32), jnp.zeros((1,), bool),
                 elementwise=True, query=qname)
     return diags

@@ -186,7 +186,7 @@ class EventChunk:
         # column to python scalars in C, and zip/map build the row lists
         # and Event objects without per-row bytecode.  Every call feeds
         # the always-on events-materialized counter — the columnar fast
-        # path is asserted to never reach here (bench --smoke rim phase)
+        # path is asserted to never reach here (tests/test_columnar_parity.py)
         n = len(self)
         if n == 0:
             return []

@@ -9,9 +9,9 @@ structured records, plus an incident hook bus that dumps a full bundle
 report + env/config) when something trips.
 
   * ``FlightRecorder.record_block`` — called by every device runtime's
-    ingest path (plan/planner.py, next to ``record_app_block``): block
-    id, stream, batch size, per-kernel dispatch/scan-tick deltas from
-    ``KernelProfiler``, junction queue depth/saturation, scheduler
+    ingest path (plan/planner.py ``_record_block``): block id, stream,
+    batch size, the block's launch and scan-tick deltas of the shape
+    registry's books, junction queue depth/saturation, scheduler
     fires, device telemetry, last errors.  A deque append under a lock —
     O(1), no device work, no allocation beyond the record dict.
   * ``FlightRecorder.emit`` — the incident bus.  Wired triggers:
@@ -242,10 +242,10 @@ class FlightRecorder:
                        "bundle_dir": bundle_dir()},
         }
         try:
-            from .profiling import profiler
-            prof = profiler()
-            bundle["kernels"] = prof.snapshot()
-            bundle["metrics"] = prof.prometheus_lines()
+            from ..plan.shapes import shape_registry
+            reg = shape_registry()
+            bundle["kernels"] = reg.kernels()
+            bundle["metrics"] = reg.prometheus_lines()
         except Exception:   # noqa: BLE001
             log.exception("flight bundle: kernel snapshot failed")
         try:

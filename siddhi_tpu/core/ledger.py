@@ -1,10 +1,9 @@
 """Per-chunk latency ledger, event-time lag watermarks and the SLO engine.
 
-BENCH rounds report one end-to-end number (188 ms p99 match latency as of
-round 11) with zero stage attribution.  This module generalizes the
-round-11 ``rim_ns`` discipline — one always-on counter, kill-switchable,
-overhead-bounded in ``bench --smoke`` — into a stage-bucketed wall-clock
-ledger over the whole ingest→publish path:
+One end-to-end latency number carries no stage attribution.  This module
+generalizes the ``rim_ns`` discipline — one always-on counter,
+kill-switchable — into a stage-bucketed wall-clock ledger over the whole
+ingest→publish path:
 
   ingress     input-handler admit (validate/encode, before junction.send)
   queue       @Async buffer wait (enqueue → worker dequeue; 0 when sync)
@@ -19,12 +18,12 @@ ledger over the whole ingest→publish path:
 Stages are recorded through nest-aware spans: a span's *exclusive* time
 (elapsed minus enclosed child spans) goes to its stage, so the per-stage
 sums reconcile against an independently measured end-to-end wall clock
-without double counting (``bench --phase waterfall`` asserts >= 95%
-coverage).  The span is the program's ONE span source, on two clocks at
-once: a span with a ``name`` also credits its exclusive time to the
-sub-span accumulator ``"<stage>.<name>"`` (:data:`SPAN_NAMES`) and records
-it, one entry per execution, in a per-app histogram under that key; and
-while a ``jax.profiler`` session runs every span is a
+without double counting.  The span is the program's ONE span source, on
+two clocks at once: a span with a ``name`` also credits its exclusive
+time to the sub-span accumulator ``"<stage>.<name>"``
+(:data:`SPAN_NAMES`) and records it, one entry per execution, in a
+per-app histogram under that key; and while a ``jax.profiler`` session
+runs every span is a
 ``TraceAnnotation("siddhi/<stage>[.<name>]", block=<seq>)``, so that it
 lies in the host plane of the same ``.xplane.pb`` as the device's ops, on
 the thread that ran it.  ``block`` is the junction's dequeue sequence
@@ -51,8 +50,8 @@ On top of the ledger:
     breaching window's waterfall.
 
 Always-on with a ``SIDDHI_TPU_LEDGER=0`` kill switch; the env is re-read
-per call so the bench overhead phase can toggle it per block.  Like
-``RimStats`` this is NOT gated on the profiler's ``enabled``.
+per call, so it can be toggled per block.  Like ``RimStats`` it is not
+gated on ``@app:statistics``.
 """
 from __future__ import annotations
 
@@ -529,6 +528,10 @@ class LatencyLedger:
         self._absent: Dict[str, list] = {}
         # app -> KEY_FACTOR_COUNTERS row, kept as ``_absent`` is
         self._keyfac: Dict[str, list] = {}
+        # app -> [device launches, ingest blocks]: the runtimes hand the
+        # launch delta of every ingest block to ``note_block``.  Kept as
+        # ``_absent`` is
+        self._blocks: Dict[str, list] = {}
         # app -> the most recent block's stage deltas (waterfall row)
         self._last_deltas: Dict[str, list] = {}
         # (app, stream) -> lag watermark state
@@ -668,12 +671,20 @@ class LatencyLedger:
 
     @hot_path("per-block stage-delta banking + SLO evaluation")
     def note_block(self, app: str, owner, runtime=None,
-                   want_row: bool = True) -> Optional[Dict[str, float]]:
-        """Bank one ingest block's stage deltas (global accumulators vs
-        ``owner``'s last snapshot — the flight ring's rim/kernel-split
-        convention), evaluate the app's SLO, and return the waterfall
-        row for the flight record (only built when ``want_row``; the
-        histogram fold is deferred — see ``_FOLD_EVERY``)."""
+                   want_row: bool = True,
+                   dispatches: int = 0) -> Optional[Dict[str, float]]:
+        """Bank one ingest block: the device launches it cost (kill
+        switch or not: they are counted, not timed) and its stage deltas
+        (global accumulators vs ``owner``'s last snapshot), evaluate the
+        app's SLO, and return the waterfall row for the flight record
+        (only built when ``want_row``; the histogram fold is deferred —
+        see ``_FOLD_EVERY``)."""
+        tot = self._blocks.get(app)
+        if tot is None:
+            with self._lock:
+                tot = self._blocks.setdefault(app, [0, 0])
+        tot[0] += dispatches
+        tot[1] += 1
         if not ledger_enabled():
             return None
         cur = _stage_values(self._ns)
@@ -779,6 +790,13 @@ class LatencyLedger:
             for key in [k for k in self._hist if k[0] == app]:
                 self._hist.pop(key, None)
 
+    def dispatches_per_block(self) -> Dict[str, float]:
+        """app -> device launches per ingest block, a running average
+        (``siddhi_app_dispatches_per_block``; a watchdog incident's
+        evidence: the session-timer storm was this ratio exploding)."""
+        return {app: d / n for app, (d, n) in sorted(self._blocks.items())
+                if n}
+
     def slo_breached(self, app: str) -> bool:
         st = self._slo.get(app)
         return bool(st is not None and st.breached)
@@ -846,6 +864,9 @@ class LatencyLedger:
                 lab = _fmt_labels({"app": app})
                 for name, n in zip(names, row):
                     lines.append(f"siddhi_{name}{lab} {n}")
+        for app, v in self.dispatches_per_block().items():
+            lab = _fmt_labels({"app": app})
+            lines.append(f"siddhi_app_dispatches_per_block{lab} {v:.9g}")
         for (app, stage), h in sorted(self._hist.items()):
             if not h.count:
                 continue
@@ -885,6 +906,7 @@ class LatencyLedger:
             self._retires.clear()
             self._absent.clear()
             self._keyfac.clear()
+            self._blocks.clear()
             self._last_deltas.clear()
             self._lag.clear()
             self._slo.clear()

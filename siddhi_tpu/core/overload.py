@@ -487,15 +487,13 @@ WD_CATALOG = {
 class DispatchWatchdog:
     """Tripwire for runaway timer/dispatch loops.
 
-    Rides the scheduler fire path (always-on — the kernel profiler's
-    dispatch counters only count when profiling is enabled): every timer
-    fire is checked against a per-target streak of fires with an
-    unchanged ingest-progress counter.  The streak deliberately ignores
-    the fire instant: the round-5 session re-arm pathology was a 1 ms
-    timer *crawl* (the re-arm instant advanced by one guard-bumped
-    millisecond per fire, 50k+ dispatches on a 60-event stream), so a
-    same-instant key would never see it.  A streak reaching
-    ``threshold`` trips the watchdog: the target is disarmed (its
+    Rides the scheduler fire path: every timer fire is checked against a
+    per-target streak of fires with an unchanged ingest-progress counter.
+    The streak deliberately ignores the fire instant: the round-5 session
+    re-arm pathology was a 1 ms timer *crawl* (the re-arm instant
+    advanced by one guard-bumped millisecond per fire, 50k+ dispatches
+    on a 60-event stream), so a same-instant key would never see it.
+    A streak reaching ``threshold`` trips the watchdog: the target is disarmed (its
     pending and future ``notify_at`` registrations are dropped), a
     WD001 incident is recorded for ``GET /health``, and an error-store
     entry (origin='watchdog') is written when a store is configured.
@@ -562,9 +560,13 @@ class DispatchWatchdog:
             "at": int(now), "since": int(since), "fires": fires,
             "detail": WD_CATALOG["WD001"],
         }
-        from .profiling import profiler, storm_snapshot
-        if profiler().enabled:
-            incident["kernel_dispatches"] = storm_snapshot()
+        # the storm's signature: launches per ingest block exploding
+        # (the session-timer storm: 300k+ dispatches on 60 events)
+        from .ledger import ledger
+        from ..plan.shapes import shape_registry
+        incident["kernel_dispatches"] = {
+            "total_dispatches": shape_registry().calls,
+            "dispatches_per_block": ledger().dispatches_per_block()}
         self.incidents.append(incident)
         if self.metrics is not None:
             self.metrics.watchdog_trips_total.inc(target=desc)

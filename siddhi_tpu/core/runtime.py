@@ -195,11 +195,6 @@ class SiddhiAppRuntime:
         if telemetry_on:
             from .statistics import DeviceTelemetry
             self.device_telemetry = DeviceTelemetry(self.name)
-        if enabled:
-            # kernel profiling rides @app:statistics: the per-kernel
-            # compile/device-time gauges feed the same /metrics surface
-            from .profiling import profiler
-            profiler().enable()
         if tracing_on:
             from .tracing import tracer
             tracer().enable()
@@ -643,10 +638,8 @@ class SiddhiAppRuntime:
 
     def enable_stats(self, enabled: bool = True):
         self.app_ctx.stats_enabled = enabled
-        from .profiling import profiler
         if enabled:
             self.app_ctx.statistics_manager.start_reporting()
-            profiler().enable()
             if not self.app_ctx.statistics_manager.throughput:
                 # late enable: wire junction trackers now
                 sm = self.app_ctx.statistics_manager
@@ -662,16 +655,16 @@ class SiddhiAppRuntime:
     @property
     def statistics(self) -> dict:
         from .ledger import ledger
-        from .profiling import profiler, rim_stats
+        from .profiling import rim_stats
+        from ..plan.shapes import shape_registry
         snap = self.app_ctx.statistics_manager.snapshot()
-        snap["kernels"] = profiler().snapshot()
-        # the always-on host-rim counters and the latency ledger ride
-        # every snapshot surface (/metrics, flight records, here) —
-        # rt.statistics must agree with them (tests/test_service.py
-        # asserts the parity)
+        # the always-on books — per-kind launch counters, host rim,
+        # latency ledger — ride every snapshot surface (/metrics, flight
+        # records, here): rt.statistics must agree with them
+        # (tests/test_service.py asserts the parity)
+        snap["kernels"] = shape_registry().kernels()
         snap["rim"] = rim_stats().snapshot()
         snap["ledger"] = ledger().snapshot(app=self.name)
-        from ..plan.shapes import shape_registry
         snap["shapes"] = shape_registry().snapshot()
         if self.device_telemetry is not None:
             snap["telemetry"] = self.device_telemetry.snapshot()

@@ -494,29 +494,6 @@ _TYPES = [
      "gauge", "Queued events in @Async junction buffers"),
     ("siddhi_counter_total", "counter", "App-defined counters"),
     ("siddhi_gauge", "gauge", "App-defined gauges"),
-    ("siddhi_kernel_calls_total",
-     "counter", "Device kernel invocations"),
-    ("siddhi_kernel_compile_count",
-     "gauge", "XLA compiles (incl. retraces) of a kernel"),
-    ("siddhi_kernel_device_time_seconds_total",
-     "gauge", "Blocked device time per kernel (profiling mode)"),
-    ("siddhi_kernel_dispatch_time_seconds_total",
-     "gauge", "Host-side dispatch time per kernel"),
-    ("siddhi_kernel_h2d_bytes_total",
-     "counter", "Host->device bytes fed to a kernel"),
-    ("siddhi_kernel_d2h_bytes_total",
-     "counter", "Device->host bytes retired from a kernel"),
-    ("siddhi_kernel_batch_events_total",
-     "counter", "Events carried through a kernel"),
-    ("siddhi_kernel_dispatches_total",
-     "counter", "Device executions launched by a kernel"),
-    ("siddhi_kernel_scan_ticks_total",
-     "counter", "lax.scan ticks executed inside a kernel"),
-    ("siddhi_kernel_live_bytes",
-     "gauge", "Live device-buffer bytes owned by a kernel"),
-    ("siddhi_kernel_batch_b", "gauge", "Events folded per scan tick (B)"),
-    ("siddhi_app_dispatches_per_block",
-     "gauge", "Device dispatches per ingest block (running average)"),
 ]
 
 #: Always-on host-rim accounting (core/profiling.RimStats): rendered on
@@ -575,6 +552,8 @@ LEDGER_TYPES = [
     ("siddhi_key_factor_reused_total",
      "counter", "Of those, ingests that found the factor already made by "
      "an earlier query of the partition"),
+    ("siddhi_app_dispatches_per_block",
+     "gauge", "Device dispatches per ingest block (running average)"),
     ("siddhi_ledger_stage_latency_ms",
      "gauge", "Per-app latency quantiles (ms): a stage per block, a named "
      "sub-span per execution, a wait per block in flight"),
@@ -737,11 +716,11 @@ class DeviceTelemetry:
         return lines
 
 
-def prometheus_text(managers: List[StatisticsManager],
-                    kernel_profiler=None, resilience=None,
+def prometheus_text(managers: List[StatisticsManager], resilience=None,
                     ingest=None, telemetry=None, tenants=None) -> str:
     """Full Prometheus/OpenMetrics text exposition over any number of app
-    StatisticsManagers plus the (process-global) kernel profiler, the
+    StatisticsManagers plus the process-global books (host rim, latency
+    ledger, shape registry with its per-kind launch books), the
     per-runtime ResilienceMetrics (core/resilience.py), the per-runtime
     IngestMetrics (core/overload.py) and the per-runtime DeviceTelemetry
     holders.  Every series family gets its # HELP/# TYPE header exactly
@@ -770,8 +749,6 @@ def prometheus_text(managers: List[StatisticsManager],
     lines.extend(process_lines())
     for sm in managers:
         lines.extend(sm.prometheus_lines())
-    if kernel_profiler is not None:
-        lines.extend(kernel_profiler.prometheus_lines())
     for rm in (resilience or []):
         lines.extend(rm.prometheus_lines())
     for im in (ingest or []):

@@ -9,7 +9,7 @@ exporter is enabled (``@app:statistics(tracing='true')``,
 buffer and its three read surfaces: ``SiddhiAppRuntime.dump_trace(path)``,
 ``GET /siddhi/apps/{app}/trace`` and the incident bundle's ``trace``.
 It holds no span and reads no clock.  The buffer is process-global for
-the same reason the kernel profiler is — compiled plan objects outlive
+the same reason the shape registry is — compiled plan objects outlive
 and predate individual app runtimes.
 """
 from __future__ import annotations

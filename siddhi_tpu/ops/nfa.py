@@ -67,7 +67,7 @@ ABSENT_CTR = ("armed", "fired", "fired_inblock", "killed")
 
 #: B-event micro-batching of the scan chain (round 6).  The env value is
 #: B itself: unset/empty → DEFAULT_BATCH_B; ``=1`` is the kill switch
-#: (legacy one-event ticks, no hoisting — mirrors SIDDHI_TPU_NFA_PRUNE).
+#: (legacy one-event ticks, no hoisting).
 BATCH_ENV = "SIDDHI_TPU_NFA_BATCH"
 DEFAULT_BATCH_B = 4
 

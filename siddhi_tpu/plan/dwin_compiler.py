@@ -281,22 +281,19 @@ class DeviceWindowProcessor(WindowProcessor):
         key = (self.capacity, T)
         fn = self._steps.get(key)
         if fn is None:
-            from ..core.profiling import wrap_kernel
             from .shapes import shape_registry
             # NO carry donation here: _step_work keeps a pre-carry
             # reference per work item and _read_work rewinds to it on
             # ring overflow (grow-and-replay), so the input buffers must
             # outlive the step.
-            fn = wrap_kernel(
+            fn = shape_registry().jit(
                 f"dwin.{self.kind}.step",
-                shape_registry().jit(
-                    f"dwin.{self.kind}.step",
-                    {"cap": self.capacity, "T": T, "nf": self.n_f,
-                     "ni": self.n_i, "telem": self.telemetry},
-                    build_dwin_step(self._spec()), static_argnums=7,
-                    # a second (capacity, T) key on a live window is a
-                    # ring grow, not a first build
-                    trigger="build" if not self._steps else "grow"))
+                {"cap": self.capacity, "T": T, "nf": self.n_f,
+                 "ni": self.n_i, "telem": self.telemetry},
+                build_dwin_step(self._spec()), static_argnums=7,
+                # a second (capacity, T) key on a live window is a
+                # ring grow, not a first build
+                trigger="build" if not self._steps else "grow")
             self._steps[key] = fn
         return fn
 
@@ -605,10 +602,9 @@ class DeviceWindowProcessor(WindowProcessor):
     # ------------------------------------------------------------ emission
 
     def on_data(self, chunk: EventChunk):
-        from ..core.profiling import profiler
-        prof = profiler()
-        disp0 = prof.total_dispatches() if prof.enabled else 0
-        ticks0 = prof.total_scan_ticks() if prof.enabled else 0
+        from .shapes import shape_registry
+        reg = shape_registry()
+        calls0 = reg.calls
         now = int(chunk.timestamps[-1])
         if self.kind in ("time", "delay", "timeLength", "session"):
             self.app_ctx.scheduler.notify_at(now + self.window_ms,
@@ -631,10 +627,7 @@ class DeviceWindowProcessor(WindowProcessor):
             fl.record_block(
                 getattr(rt, "name", ""), stream=sid,
                 batch=len(chunk.timestamps),
-                dispatches=(prof.total_dispatches() - disp0
-                            if prof.enabled else 0),
-                scan_ticks=(prof.total_scan_ticks() - ticks0
-                            if prof.enabled else 0),
+                dispatches=reg.calls - calls0,
                 junction=(rt.junctions.get(sid) if rt is not None
                           else None),
                 scheduler=self.app_ctx.scheduler,

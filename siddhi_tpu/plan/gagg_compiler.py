@@ -313,20 +313,19 @@ class CompiledGroupedAgg:
     # ------------------------------------------------------------ shapes
 
     def _build_step(self):
-        from ..core.profiling import wrap_kernel
         from .shapes import shape_registry
         # shape-class dims exclude lanes/groups: those grow under the
         # same jit (a plain retrace), only these facts change the program
         if self.window_kind == "time":
             # no donation: decode's GaggOverflow rewind replays from the
             # chunk's pre-carry, which must survive the step
-            self._step = wrap_kernel("gagg.time.step", shape_registry().jit(
+            self._step = shape_registry().jit(
                 "gagg.time.step",
                 {"win_ms": self.window_ms, "win": self.window,
                  "vf": self._n_float, "vi": self._n_int,
                  "forever": self.want_forever},
                 build_grouped_time_step(
-                    self.window_ms, self.window, self.want_forever)))
+                    self.window_ms, self.window, self.want_forever))
         else:
             # length/running carries donate (XLA aliases the [P, G, V]
             # slabs in place) UNLESS exact int sums are wanted — their
@@ -335,7 +334,7 @@ class CompiledGroupedAgg:
             # NUMGUARD (core/numguard.py): the sentinel flag appends a
             # 14th output, a different compiled program — so it is part
             # of the shape-class key, like every program-changing fact
-            self._step = wrap_kernel("gagg.step", shape_registry().jit(
+            self._step = shape_registry().jit(
                 "gagg.step",
                 {"kind": self.window_kind, "win": self.window,
                  "vf": self._n_float, "vi": self._n_int,
@@ -344,14 +343,14 @@ class CompiledGroupedAgg:
                 build_grouped_step(
                     self.window, self.want_minmax, self.want_forever,
                     numguard=self._numguard),
-                donate_argnums=donate))
+                donate_argnums=donate)
         if getattr(self, "selection", None) is not None:
             from ..ops.select import build_select_step
             p = self.selection
-            self._select = wrap_kernel("select.step", shape_registry().jit(
+            self._select = shape_registry().jit(
                 "select.step",
                 {"sig": p.key, "vf": self._n_float, "vi": self._n_int},
-                build_select_step(p)))
+                build_select_step(p))
 
     def _make_carry(self, n_lanes: int, n_groups: Optional[int] = None):
         g = self.n_groups if n_groups is None else n_groups

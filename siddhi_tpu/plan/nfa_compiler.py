@@ -24,7 +24,6 @@ core/pattern.py with a recorded reason for anything else):
 """
 from __future__ import annotations
 
-import os
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -574,9 +573,6 @@ def _prune_chain(low: _Lowering, query) -> Dict[str, Any]:
     return report
 
 
-PRUNE_ENV = "SIDDHI_TPU_NFA_PRUNE"
-
-
 @persistent_schema(
     "nfa-engine", version=1,
     schema=Struct(carry=Carry(), base_ts=Scalar("opt_int"),
@@ -592,7 +588,7 @@ class CompiledPatternNFA:
     def __init__(self, app_string, n_partitions: int,
                  n_slots: int = 8, query_name: Optional[str] = None,
                  parameterize: bool = False, query: Optional[Query] = None,
-                 mesh: Any = "auto", prune: Optional[bool] = None,
+                 mesh: Any = "auto", prune: bool = True,
                  batch_b: Optional[int] = None,
                  donate: Optional[bool] = None,
                  telemetry: bool = False):
@@ -601,9 +597,9 @@ class CompiledPatternNFA:
         jax.sharding.Mesh pins an explicit mesh; None forces single-device.
         The partition lane count rounds up to a mesh-size multiple.
 
-        prune: liveness pruning over the unit chain (on by default; env
-        SIDDHI_TPU_NFA_PRUNE=0 disables globally — the unpruned baseline
-        the equivalence tests diff against).  Pattern-bank mode
+        prune: liveness pruning over the unit chain (on by default;
+        False is the unpruned baseline the equivalence tests diff
+        against).  Pattern-bank mode
         (parameterize=True) always compiles unpruned: folding constants
         out of filters would desync the per-pattern parameter lanes.
 
@@ -633,8 +629,6 @@ class CompiledPatternNFA:
             raise SiddhiAppCreationError(
                 "TPU NFA path needs a PATTERN/SEQUENCE query")
         low = _Lowering(sis, app)
-        if prune is None:
-            prune = os.environ.get(PRUNE_ENV, "1") != "0"
         self.prune_enabled = bool(prune) and not parameterize
         if self.prune_enabled:
             self.prune_report = _prune_chain(low, query)
@@ -1011,7 +1005,7 @@ class CompiledPatternNFA:
             (not self.is_sequence and self.units[0].kind == "count")
         # fatter scan ticks (ops/nfa round 6): pinned at compile so every
         # consumer of this spec (engine step, mesh step, bank step, jaxpr
-        # sanitizer, cost model, profiler) sees one consistent B
+        # sanitizer, cost model, launch books) sees one consistent B
         self.batch_b = resolve_batch_b(batch_b)
         self.spec = NfaSpec(
             units=tuple(unit_specs), n_rows=len(rows), n_caps=C,
@@ -1542,10 +1536,10 @@ class CompiledPatternNFA:
         shard-pinned engine (parallel/shards.py round 15) commits its
         carry to its own device instead — jit dispatch follows committed
         operands, so every step (including growth re-placement) stays
-        shard-local with no collective.  When profiling is on, the placed
-        carry's total bytes feed the KernelProfiler ``live_bytes`` gauge
-        — the measured side of the static cost model's HBM prediction
-        (analysis/cost_model.py)."""
+        shard-local with no collective.  The placed carry's total bytes
+        are this engine's share of its step's ``live_bytes``
+        (plan/shapes.py) — the measured side of the static cost model's
+        HBM prediction (analysis/cost_model.py)."""
         if self.mesh is None:
             dev = getattr(self, "shard_device", None)
             if dev is not None:
@@ -1556,12 +1550,11 @@ class CompiledPatternNFA:
         else:
             from ..parallel.mesh import shard_carry
             placed = shard_carry(carry, self.mesh)
-        from ..core.profiling import profiler
-        prof = profiler()
-        if prof.enabled:
-            prof.set_live_bytes(
-                "nfa.step" if self.mesh is None else "nfa.mesh_step",
-                sum(int(getattr(v, "nbytes", 0)) for v in placed.values()))
+        self._live_bytes = sum(
+            int(getattr(v, "nbytes", 0)) for v in placed.values())
+        step = self.__dict__.get("_step")
+        if step is not None:    # else the first _jit_step books it
+            step.book_live(self, self._live_bytes)
         return placed
 
     # ------------------------------------------------ partition shard-out
@@ -1591,6 +1584,7 @@ class CompiledPatternNFA:
                 "shard clones require a single-device template "
                 "(mesh=None)")
         cl = copy.copy(self)
+        cl.__dict__.pop("_live_book", None)    # the template's, not its
         cl.shard_device = device
         cl.carry = cl._place_carry(make_carry(cl.spec, cl.n_partitions))
         cl.base_ts = None
@@ -1620,16 +1614,7 @@ class CompiledPatternNFA:
         return not self._effective_donate()
 
     def _jit_step(self, trigger: str = "build"):
-        from ..core.profiling import wrap_kernel
         from .shapes import nfa_shape_dims, shape_registry
-        batch_of = (lambda carry, block:
-                    int(block["__ts"].size) if "__ts" in block else 0)
-        B = max(self.batch_b, 1)
-        # sequential ticks per dispatch: ⌈T/B⌉ (the fatter-tick win the
-        # profiler exposes as scan_ticks next to batch_b)
-        ticks_of = (lambda carry, block:
-                    (-(-int(block["__ts"].shape[-1]) // B), B)
-                    if "__ts" in block else (0, B))
         if self.mesh is None:
             # default: no donation — the engine path replays a chunk from
             # the pre-chunk carry after a slot overflow (grow-and-replay),
@@ -1644,19 +1629,19 @@ class CompiledPatternNFA:
                 first_call_hook=self._ladder_hook(donate),
                 prewarm_owner=id(self),
                 donate_argnums=donate)
-            return wrap_kernel("nfa.step", rj,
-                               batch_of=batch_of, ticks_of=ticks_of)
-        from ..parallel.mesh import jit_engine_step
-        rj = shape_registry().adopt(
-            "nfa.mesh_step",
-            nfa_shape_dims(self.spec, self.n_partitions, self.batch_b,
-                           donate=self._effective_donate(),
-                           mesh=self.mesh.size),
-            jit_engine_step(self.spec, self.mesh,
-                            donate=self._effective_donate()),
-            trigger=trigger)
-        return wrap_kernel("nfa.mesh_step", rj,
-                           batch_of=batch_of, ticks_of=ticks_of)
+        else:
+            from ..parallel.mesh import jit_engine_step
+            rj = shape_registry().adopt(
+                "nfa.mesh_step",
+                nfa_shape_dims(self.spec, self.n_partitions, self.batch_b,
+                               donate=self._effective_donate(),
+                               mesh=self.mesh.size),
+                jit_engine_step(self.spec, self.mesh,
+                                donate=self._effective_donate()),
+                trigger=trigger)
+        # the carry this engine holds is this step's state from now on
+        rj.book_live(self, self._live_bytes)
+        return rj
 
     #: carry leaves whose axis 1 is the K (slot) axis — the ones a grow
     #: widens, so the prewarm ladder widens the same set.
@@ -1852,6 +1837,7 @@ class CompiledPatternNFA:
             block = {k: jax.device_put(v, sh) for k, v in block.items()}
         self.carry, (mask, caps, ts, enter, seq) = self._step(self.carry,
                                                              block)
+        self._step.note_ticks(block["__ts"].shape[-1])
         return mask, caps, ts, enter, seq
 
     def _egress_pack_fn(self):
@@ -1900,16 +1886,13 @@ class CompiledPatternNFA:
 
     def _ensure_egress_jit(self):
         if not hasattr(self, "_egress_jit"):
-            from ..core.profiling import wrap_kernel
             from .shapes import shape_registry
             R = max(self.spec.n_rows, 1)
             C = max(self.spec.n_caps, 1)
-            self._egress_jit = wrap_kernel(
+            self._egress_jit = shape_registry().jit(
                 "nfa.egress_pack",
-                shape_registry().jit(
-                    "nfa.egress_pack",
-                    {"R": R, "C": C, "absent": self.has_absent},
-                    self._egress_pack_fn(), static_argnums=8))
+                {"R": R, "C": C, "absent": self.has_absent},
+                self._egress_pack_fn(), static_argnums=8)
         return self._egress_jit
 
     def egress_dispatch(self, outs):
@@ -1969,8 +1952,7 @@ class CompiledPatternNFA:
             buf = np.asarray(handle["buf"])
             if handle.get("telem") is not None:
                 self.last_telemetry = np.asarray(handle["telem"])
-            from ..core.profiling import profiler
-            profiler().record_d2h("nfa.egress_pack", buf.nbytes)
+            self._egress_jit.entry.d2h_bytes += buf.nbytes
         count = int(buf[-1, 0])
         self.last_dropped_total = int(buf[-1, 1])
         while count > handle["cap"]:
@@ -2460,7 +2442,6 @@ class CompiledPatternBank:
         # surfaced in Plan-IR dumps (analysis/plan_ir.automaton_ir_from_nfa)
         self.nfa._stacked = self.stacked
         self.nfa._dispatches_per_block = 1 if self.stacked else self.n_chunks
-        self._set_live_bytes()
         self._build_step()
         self.base_ts: Optional[int] = None
 
@@ -2474,45 +2455,28 @@ class CompiledPatternBank:
                     for ci in range(self.n_chunks)]
         return self._carries
 
-    def _set_live_bytes(self):
-        from ..core.profiling import profiler
-        if not profiler().enabled:
-            return
-        # logical carry footprint (broadcast views materialize dense on
-        # the first donated step) — the measured side of the cost model's
-        # bank_state_bytes / stacked_bank_state_bytes prediction
-        if self.stacked:
-            nbytes = sum(int(v.nbytes) for v in self._stack_carry.values())
-        else:
-            nbytes = sum(int(getattr(v, "nbytes", 0))
-                         for c in self._carries for v in c.values())
-        profiler().set_live_bytes("nfa.bank_step", nbytes)
-
     def _build_step(self):
         from ..ops.nfa import build_bank_step, build_super_bank_step
-        from ..core.profiling import wrap_kernel
         from .shapes import nfa_shape_dims, shape_registry
         build = build_super_bank_step if self.stacked else build_bank_step
         # replayable banks rewind to the pre-block carry after a slot
         # overflow, so the input carry must survive the step; otherwise
         # donate — XLA aliases the carry slabs in place
         donate = () if self.replayable else (0,)
-        B = max(self.nfa.batch_b, 1)
         dims = nfa_shape_dims(
             self.nfa.spec, self.nfa.n_partitions, self.nfa.batch_b,
             donate=bool(donate), ring=self.ring,
             chunks=self.n_chunks, stacked=self.stacked)
-        self._step = wrap_kernel(
-            "nfa.bank_step",
-            shape_registry().jit(
-                "nfa.bank_step", dims,
-                build(self.nfa.spec, ring=self.ring),
-                donate_argnums=donate),
-            batch_of=lambda carry, block, params:
-                int(block["__ts"].size) if "__ts" in block else 0,
-            ticks_of=lambda carry, block, params:
-                (-(-int(block["__ts"].shape[-1]) // B), B)
-                if "__ts" in block else (0, B))
+        self._step = shape_registry().jit(
+            "nfa.bank_step", dims, build(self.nfa.spec, ring=self.ring),
+            donate_argnums=donate)
+        # logical carry footprint (broadcast views materialize dense on
+        # the first donated step) — the measured side of the cost model's
+        # bank_state_bytes / stacked_bank_state_bytes prediction
+        carries = [self._stack_carry] if self.stacked else self._carries
+        self._step.book_live(self, sum(
+            int(getattr(v, "nbytes", 0)) for c in carries
+            for v in c.values()))
 
     def _default_chunk(self, n_partitions: int, n_slots: int) -> int:
         # carry bytes × ~16 for scan/vmap intermediates, ×2 for a decode
@@ -2537,9 +2501,11 @@ class CompiledPatternBank:
         Stacked banks (SIDDHI_TPU_NFA_STACK, the default with >1 chunk)
         pay ONE device dispatch here; the legacy path dispatches once per
         chunk."""
+        T = block["__ts"].shape[-1]
         if self.stacked:
             self._stack_carry, res = self._step(self._stack_carry, block,
                                                 self._stack_params)
+            self._step.note_ticks(T)
             if not self.ring:
                 return res.reshape(-1)                # [C, n] → [N]
             return tuple(r.reshape((-1,) + r.shape[2:]) for r in res)
@@ -2547,6 +2513,7 @@ class CompiledPatternBank:
         for ci in range(self.n_chunks):
             self._carries[ci], res = self._step(self._carries[ci], block,
                                                 self.params[ci])
+            self._step.note_ticks(T)
             outs.append(res)
         if not self.ring:
             return jnp.concatenate(outs)
@@ -2600,7 +2567,6 @@ class CompiledPatternBank:
         # keep the inner (parameterized) NFA's spec/step consistent —
         # it owns the NfaSpec the bank compiles against
         self.nfa.grow_slots(n_slots)
-        self._set_live_bytes()
         self._build_step()
 
     def process_block_replayed(self, block):

@@ -45,6 +45,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional
 
 from ..core.ledger import ON_DEPTH, ON_FLUSH, ON_READY, ledger as _ledger
 from ..query_api.annotation import find_annotation
+from .shapes import shape_registry
 
 _LED = _ledger()
 
@@ -312,8 +313,10 @@ class _FuseGroup:
                     self._host = np.asarray(self._slab)   # the ONE D2H
                 self.fuser.d2h_count += 1
                 self.fuser.last_slab_bytes = self._host.nbytes
-                from ..core.profiling import profiler
-                profiler().record_d2h("egress.fuse", self._host.nbytes)
+                # the slab is the eager concat's, a launch of no kind
+                # of its own: its read is booked where its compiles are
+                shape_registry().entry("other", {}).d2h_bytes += \
+                    self._host.nbytes
             out: List[Any] = []
             off = 0
             host = self._host

@@ -42,6 +42,7 @@ from ..parallel.shards import build_shards, resolve_shards, split_rows
 from .nfa_compiler import CompiledPatternNFA
 from .pipeline import (PipelinedDeviceIngest, note_retire,
                        retire_after_submit, settle_inflight, stamp_submit)
+from .shapes import shape_registry
 
 ENGINE_ENV = "SIDDHI_TPU_ENGINE"
 DEFAULT_SLOTS = 8
@@ -51,7 +52,7 @@ GROW_START = 8          # initial keyed-lane capacity (doubles on demand)
 def initial_lanes(app, n_shards: int = 0) -> int:
     """``@app:lanes('N')`` — declared distinct-key population.  Keyed
     slabs start at the next power of two ≥ N instead of GROW_START, so a
-    known-large key domain (bench.py shardscale runs 1M keys) skips the
+    known-large key domain (1M keys, say) skips the
     log2(N/8) grow ladder and its per-double jit retrace.  Sharded
     runtimes split the population: each shard pre-sizes to ceil(N/S)."""
     ann = find_annotation(app.annotations, "app:lanes") or \
@@ -66,22 +67,18 @@ def initial_lanes(app, n_shards: int = 0) -> int:
     return 1 << (n - 1).bit_length()
 
 
-def _record_block(rt_obj, prof, disp0: int, ticks0: int, stream: str,
-                  batch: int, junction=None, telemetry=None) -> None:
-    """Per-ingest-block accounting shared by every device runtime: the
-    profiler's dispatches-per-block gauge (when profiling is on), the
-    latency ledger's per-app stage fold + SLO evaluation (core/ledger.py,
-    always-cheap), plus a flight-recorder ring record (core/flight.py)."""
+def _record_block(rt_obj, marks, stream: str, batch: int, junction=None,
+                  telemetry=None) -> None:
+    """Per-ingest-block accounting shared by every device runtime:
+    ``marks`` is ``shape_registry().marks()`` as the ingest began, so the
+    launches and scan ticks this block cost are two subtractions.  They
+    go to the latency ledger with the per-app stage fold + SLO evaluation
+    (core/ledger.py: the ``siddhi_app_dispatches_per_block`` gauge), and
+    onto the flight-recorder ring record (core/flight.py)."""
     from ..core.flight import flight
     from ..core.ledger import ledger
-    from ..core.profiling import rim_stats
-    d = prof.total_dispatches() - disp0 if prof.enabled else 0
-    t = prof.total_scan_ticks() - ticks0 if prof.enabled else 0
-    if prof.enabled:
-        # the measured side of the consolidation claim: device launches
-        # this ingest block cost (the siddhi_app_dispatches_per_block
-        # gauge)
-        prof.record_app_block(rt_obj.app_name, d)
+    calls, ticks = shape_registry().marks()
+    d = calls - marks[0]
     app = getattr(rt_obj.qr, "app_runtime", None)
     fl = flight()
     # per-block stage waterfall: bank the stage deltas since this
@@ -91,7 +88,7 @@ def _record_block(rt_obj, prof, disp0: int, ticks0: int, stream: str,
     # flight ring will actually store it)
     led = ledger()
     ledger_row = led.note_block(rt_obj.app_name, rt_obj, runtime=app,
-                                want_row=fl.enabled)
+                                want_row=fl.enabled, dispatches=d)
     if not fl.enabled:
         return
     sched = getattr(app.app_ctx, "scheduler", None) if app is not None \
@@ -108,22 +105,10 @@ def _record_block(rt_obj, prof, disp0: int, ticks0: int, stream: str,
         # the gang launch (flight rows already carry the app label)
         extra = dict(extra or {}, xtenant={"bucket": bucket.label,
                                            "tenants": len(bucket.tenants)})
-    # rim-vs-kernel ms split: delta of the always-on host-rim clock (and,
-    # when profiling is on, the kernel dispatch clock) since this
-    # runtime's previous block — per-block attribution for the ring
-    rim_now = rim_stats().rim_ns
-    kern_now = prof.total_dispatch_ns() if prof.enabled else 0
-    rim_prev = getattr(rt_obj, "_flight_rim_ns0", None)
-    if rim_prev is not None:
-        split = {"rim_ms": (rim_now - rim_prev) / 1e6,
-                 "kernel_ms": (kern_now - rt_obj._flight_kern_ns0) / 1e6}
-        extra = dict(extra or {}, **split)
-    rt_obj._flight_rim_ns0 = rim_now
-    rt_obj._flight_kern_ns0 = kern_now
     fl.record_block(rt_obj.app_name, stream=stream, batch=batch,
-                    dispatches=d, scan_ticks=t, junction=junction,
-                    scheduler=sched, telemetry=telemetry, extra=extra,
-                    ledger=ledger_row)
+                    dispatches=d, scan_ticks=ticks - marks[1],
+                    junction=junction, scheduler=sched, telemetry=telemetry,
+                    extra=extra, ledger=ledger_row)
 
 
 class KeyLanes(dict):
@@ -595,14 +580,11 @@ class DevicePatternRuntime:
 
     def ingest(self, stream_code: int, stream_id: str, chunk) -> None:
         from ..core.event import CURRENT, EventChunk
-        from ..core.profiling import profiler
         data = chunk.only(CURRENT)
         if data.is_empty:
             return
-        prof = profiler()
+        marks = shape_registry().marks()
         led = _ledger()
-        disp0 = prof.total_dispatches() if prof.enabled else 0
-        ticks0 = prof.total_scan_ticks() if prof.enabled else 0
         n = len(data)
         if self.keyed:
             ex = self.key_executors.get(stream_id)
@@ -617,7 +599,7 @@ class DevicePatternRuntime:
                 return
             if self.shards is not None:
                 self._ingest_sharded(stream_code, data, keys.keys(), n)
-                _record_block(self, prof, disp0, ticks0, stream_id, n,
+                _record_block(self, marks, stream_id, n,
                               junction=self._junctions.get(stream_id))
                 return
             with led.span("dispatch", "lanes"):
@@ -654,7 +636,7 @@ class DevicePatternRuntime:
         retire_after_submit(self._inflight, self.pipeline_depth,
                             self._retire_one)
         tel = self.nfa.last_telemetry
-        _record_block(self, prof, disp0, ticks0, stream_id, n,
+        _record_block(self, marks, stream_id, n,
                       junction=self._junctions.get(stream_id),
                       telemetry=(tel.sum(axis=0) if tel is not None
                                  else None))
@@ -1068,15 +1050,12 @@ class DeviceWindowedAggRuntime(PipelinedDeviceIngest):
 
     def ingest(self, stream_code: int, stream_id: str, chunk) -> None:
         from ..core.event import CURRENT
-        from ..core.profiling import profiler
         from ..ops.nfa import pack_blocks
         data = chunk.only(CURRENT)
         if data.is_empty:
             return
-        prof = profiler()
+        marks = shape_registry().marks()
         led = _ledger()
-        disp0 = prof.total_dispatches() if prof.enabled else 0
-        ticks0 = prof.total_scan_ticks() if prof.enabled else 0
         with led.span("dispatch", "keys"):
             data, keys = _factored_keys(self.key_executor, data,
                                         self.app_name)
@@ -1085,7 +1064,7 @@ class DeviceWindowedAggRuntime(PipelinedDeviceIngest):
         n = len(data)
         if self.shards is not None:
             self._ingest_sharded(data, keys.keys())
-            _record_block(self, prof, disp0, ticks0, stream_id, n)
+            _record_block(self, marks, stream_id, n)
             return
         with led.span("dispatch", "lanes"):
             lanes = map_keys_to_lanes(self.key_lanes, keys,
@@ -1126,7 +1105,7 @@ class DeviceWindowedAggRuntime(PipelinedDeviceIngest):
                 o.copy_to_host_async()
         self._submit({"outs": outs, "fuse": token, "data": data,
                       "lanes": lanes, "rows": rows})
-        _record_block(self, prof, disp0, ticks0, stream_id, n)
+        _record_block(self, marks, stream_id, n)
 
     def _ingest_sharded(self, data, keys_arr: np.ndarray) -> None:
         """Hash-route the chunk and run each shard's sub-block through
@@ -1382,13 +1361,10 @@ class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
 
     def ingest(self, stream_code: int, stream_id: str, chunk) -> None:
         from ..core.event import CURRENT
-        from ..core.profiling import profiler
         data = chunk.only(CURRENT)
         if data.is_empty:
             return
-        prof = profiler()
-        disp0 = prof.total_dispatches() if prof.enabled else 0
-        ticks0 = prof.total_scan_ticks() if prof.enabled else 0
+        marks = shape_registry().marks()
         if self.keyed:
             data, keys = _factored_keys(self.key_executor, data,
                                         self.app_name)
@@ -1396,7 +1372,7 @@ class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
                 return
             if self.shards is not None:
                 self._ingest_sharded(data, keys.keys())
-                _record_block(self, prof, disp0, ticks0, stream_id,
+                _record_block(self, marks, stream_id,
                               len(data))
                 return
             lanes = map_keys_to_lanes(self.key_lanes, keys,
@@ -1409,7 +1385,7 @@ class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
         if work is None:
             return
         self._submit(work)
-        _record_block(self, prof, disp0, ticks0, stream_id, len(data))
+        _record_block(self, marks, stream_id, len(data))
 
     def _ingest_sharded(self, data, keys_arr: np.ndarray) -> None:
         """Hash-route the chunk; each shard's sub-block dispatches on its
@@ -1730,16 +1706,11 @@ class DeviceFilterRuntime(PipelinedDeviceIngest):
                     for ce in dev_exprs]
             return ok, outs
 
-        from ..core.profiling import wrap_kernel
-        from .shapes import shape_registry
-        self._program = wrap_kernel(
+        self._program = shape_registry().jit(
             "filter.program",
-            shape_registry().jit(
-                "filter.program",
-                {"filters": len(filters), "outs": len(dev_exprs),
-                 "lanes": len(self.numeric)},
-                program),
-            batch_of=lambda cols, ts, valid: int(ts.shape[0]))
+            {"filters": len(filters), "outs": len(dev_exprs),
+             "lanes": len(self.numeric)},
+            program)
 
         # trace now so incompatibilities reject at plan time
         try:
@@ -1773,13 +1744,10 @@ class DeviceFilterRuntime(PipelinedDeviceIngest):
 
     def ingest(self, stream_code: int, stream_id: str, chunk) -> None:
         import jax.numpy as jnp
-        from ..core.profiling import profiler
         n = len(chunk)
         if n == 0:
             return
-        prof = profiler()
-        disp0 = prof.total_dispatches() if prof.enabled else 0
-        ticks0 = prof.total_scan_ticks() if prof.enabled else 0
+        marks = shape_registry().marks()
         n_pad = 1 << (n - 1).bit_length()
         cols = {}
         for a in self.numeric:
@@ -1811,13 +1779,11 @@ class DeviceFilterRuntime(PipelinedDeviceIngest):
                 o.copy_to_host_async()
         self._submit({"ok": ok, "outs": outs, "fuse": token,
                       "chunk": chunk, "n": n})
-        _record_block(self, prof, disp0, ticks0, stream_id, n)
+        _record_block(self, marks, stream_id, n)
 
     def _retire(self, work) -> None:
         from ..core.event import TIMER, RESET, EventChunk
-        from ..core.profiling import profiler
         chunk, n, outs = work["chunk"], work["n"], work["outs"]
-        prof = profiler()
         if work.get("fuse") is not None:
             fetched = work["fuse"].fetch()
             ok = fetched[0][:n]
@@ -1826,9 +1792,8 @@ class DeviceFilterRuntime(PipelinedDeviceIngest):
             with _ledger().span("egress_d2h"):
                 ok = np.asarray(work["ok"])[:n]
                 outs = [np.asarray(o) for o in outs]
-            if prof.enabled:
-                prof.record_d2h("filter.program", ok.nbytes + sum(
-                    getattr(o, "nbytes", 0) for o in outs))
+            self._program.entry.d2h_bytes += ok.nbytes + sum(
+                o.nbytes for o in outs)
         # TIMER/RESET rows always pass (host FilterProcessor parity)
         ok = ok | (chunk.types == TIMER) | (chunk.types == RESET)
         if not ok.any():

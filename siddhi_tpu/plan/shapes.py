@@ -28,6 +28,11 @@ point:
     the grow ladder (K doublings of live NFA shapes) in a background
     ``siddhi-prewarm`` thread via ``jit(...).lower(abstract).compile()``
     so grow-and-replay pays a cache hit, not a cold compile.
+  * **Launch books** — :class:`RegisteredJit` is the one wrapper a
+    launch passes: it counts the call, the numpy bytes it hands the
+    device and its compiles on the shape class's :class:`ShapeEntry`;
+    the retire and carry-placement sites add D2H bytes, live bytes and
+    scan ticks there (``siddhi_kernel_*`` on /metrics, per kind).
   * **Compile telemetry** — per-shape-class ledger (compile count,
     attributed XLA seconds, call-blocking wall seconds, persistent-cache
     hits/misses, trigger = build|grow|rebucket|prewarm|restart), folded
@@ -53,8 +58,11 @@ from __future__ import annotations
 import os
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import numpy as np
 
 from ..core.ledger import ledger as _ledger
 
@@ -198,6 +206,7 @@ class ShapeEntry:
     __slots__ = ("signature", "kind", "dims", "compiles", "compile_seconds",
                  "trace_seconds", "lower_seconds", "backend_seconds",
                  "blocked_seconds", "cache_hits", "cache_misses", "calls",
+                 "h2d_bytes", "d2h_bytes", "live_bytes", "scan_ticks",
                  "triggers", "last_trigger", "last_compile_unix", "prewarmed")
 
     def __init__(self, signature: str, kind: str, dims: Dict[str, Any]):
@@ -213,7 +222,13 @@ class ShapeEntry:
         self.blocked_seconds = 0.0     # caller wall blocked on a compile
         self.cache_hits = 0            # persistent-cache hits
         self.cache_misses = 0
-        self.calls = 0
+        self.calls = 0                 # launches
+        self.h2d_bytes = 0             # numpy leaves of the calls' arguments
+        self.d2h_bytes = 0             # read back by the retire sites
+        # persistent device state (a gauge): set by the carry-placement
+        # sites; the measured side of analysis/cost_model.py's prediction
+        self.live_bytes = 0
+        self.scan_ticks = 0            # sequential NFA ticks, ceil(T/B) a call
         self.triggers: Dict[str, int] = {}
         self.last_trigger = ""
         self.last_compile_unix = 0.0
@@ -231,10 +246,33 @@ class ShapeEntry:
                 "blocked_seconds": round(self.blocked_seconds, 6),
                 "cache_hits": self.cache_hits,
                 "cache_misses": self.cache_misses,
-                "calls": self.calls, "triggers": dict(self.triggers),
+                "calls": self.calls, "h2d_bytes": self.h2d_bytes,
+                "d2h_bytes": self.d2h_bytes, "live_bytes": self.live_bytes,
+                "scan_ticks": self.scan_ticks,
+                "triggers": dict(self.triggers),
                 "last_trigger": self.last_trigger,
                 "last_compile_unix": round(self.last_compile_unix, 3),
                 "prewarmed": self.prewarmed is not None}
+
+
+def _host_bytes(args) -> int:
+    """nbytes of the numpy leaves of a call's arguments: the H2D transfer
+    the call implies (device-resident jax arrays transfer nothing)."""
+    total = 0
+    stack = list(args)
+    while stack:
+        a = stack.pop()
+        if isinstance(a, np.ndarray):
+            total += a.nbytes
+        elif isinstance(a, dict):
+            stack.extend(a.values())
+        elif isinstance(a, (list, tuple)):
+            stack.extend(a)
+    return total
+
+
+def _unbook_live(book: list) -> None:
+    book[0].live_bytes -= book[1]
 
 
 class _AotHandoff:
@@ -268,12 +306,12 @@ class _AotHandoff:
 
 
 class RegisteredJit:
-    """The registry's wrapper around one jitted callable.  Sits INSIDE
-    ``wrap_kernel`` (the profiler wraps this), so profiling keeps its
-    retrace detection via the delegated ``_cache_size``.  Per call it
-    pushes a thread-local attribution frame (so jax.monitoring compile
-    durations and cache hit/miss events credit this shape class) and
-    detects compiles via the jit's in-memory cache-size delta."""
+    """The registry's wrapper around one jitted callable, and the only
+    one a launch passes.  Per call it pushes a thread-local attribution
+    frame (so jax.monitoring compile durations and cache hit/miss events
+    credit this shape class), counts the launch and the host bytes it
+    carries, and detects compiles via the jit's in-memory cache-size
+    delta."""
 
     __slots__ = ("_jitted", "entry", "registry", "trigger",
                  "_first_call_hook", "_last_cs", "_span")
@@ -290,9 +328,9 @@ class RegisteredJit:
         self._first_call_hook = first_call_hook
         self._last_cs = 0
 
-    # profiling compat: ProfiledKernel reads fn._cache_size for its own
-    # per-wrapper retrace delta
     def _cache_size(self) -> int:
+        """The jit's in-memory cache size.  A rebuilt step is a new jit
+        with a cache of its own, so the compile delta is per wrapper."""
         fn = getattr(self._jitted, "_cache_size", None)
         try:
             return int(fn()) if fn is not None else 0
@@ -301,6 +339,30 @@ class RegisteredJit:
 
     def lower(self, *args, **kwargs):
         return self._jitted.lower(*args, **kwargs)
+
+    def note_ticks(self, T: int) -> None:
+        """The launch just made scanned a block of depth ``T``:
+        ``ceil(T/B)`` sequential ticks, B the entry's events per tick."""
+        n = -(-T // self.entry.dims["B"])
+        reg = self.registry
+        with reg._lock:
+            self.entry.scan_ticks += n
+            reg.scan_ticks += n
+
+    def book_live(self, owner: Any, nbytes: int) -> None:
+        """``owner`` (an engine) now holds ``nbytes`` of device state
+        under this launch's shape class.  The gauge is the sum over the
+        engines that hold state there: what ``owner`` booked before,
+        under whichever class, is taken back first, and goes when
+        ``owner`` does."""
+        book = owner.__dict__.get("_live_book")
+        with self.registry._lock:
+            if book is None:
+                book = owner._live_book = [self.entry, 0]
+                weakref.finalize(owner, _unbook_live, book)
+            book[0].live_bytes -= book[1]
+            book[0], book[1] = self.entry, int(nbytes)
+            self.entry.live_bytes += book[1]
 
     def __call__(self, *args, **kwargs):
         reg = self.registry
@@ -315,13 +377,17 @@ class RegisteredJit:
         finally:
             t1 = time.perf_counter_ns()
             stack.pop()
-        self.entry.calls += 1
+        e = self.entry
+        nb = _host_bytes(args)
+        with reg._lock:     # several junction and shard workers launch
+            e.calls += 1
+            e.h2d_bytes += nb
+            reg.calls += 1
         cs = self._cache_size()
         if cs > self._last_cs:
             n = cs - self._last_cs
             self._last_cs = cs
-            reg._note_compile(self.entry, self.trigger, n,
-                              (t1 - t0) / 1e9)
+            reg._note_compile(e, self.trigger, n, (t1 - t0) / 1e9)
         if self._first_call_hook is not None:
             hook, self._first_call_hook = self._first_call_hook, None
             try:
@@ -341,6 +407,10 @@ class ShapeRegistry:
         self._entries: Dict[str, ShapeEntry] = {}
         self._events: "deque" = deque(maxlen=EVENT_RING)
         self._tls = threading.local()
+        # every entry's calls / scan_ticks, summed as they are made: a
+        # runtime diffs :meth:`marks` around an ingest block
+        self.calls = 0
+        self.scan_ticks = 0
         # prewarm worker state: a transient thread that exits when the
         # queue drains (the tier-1 thread-leak sentinel treats lingering
         # siddhi- threads as failures)
@@ -436,6 +506,8 @@ class ShapeRegistry:
                                  "kind": e.kind, "trigger": trigger,
                                  "compiles": n,
                                  "blocked_s": round(blocked_s, 4)})
+        # the call itself is a span already, ``device.issue/<kind>``
+        _ledger().instant(f"jit-compile:{e.kind}", cat="jit")
         try:
             from ..core.flight import flight
             fl = flight()
@@ -561,7 +633,7 @@ class ShapeRegistry:
 
     def prewarm_join(self, timeout: float = 60.0) -> bool:
         """Block until the ladder queue drains and the worker exits
-        (tests and the coldstart bench synchronize here)."""
+        (tests synchronize here)."""
         ok = self._pw_idle.wait(timeout)
         t = self._pw_thread
         if t is not None:
@@ -573,6 +645,23 @@ class ShapeRegistry:
             return len(self._pw_queue)
 
     # ------------------------------------------------------------ reads
+
+    def marks(self) -> Tuple[int, int]:
+        """(launches, scan ticks) made so far, process-wide."""
+        return self.calls, self.scan_ticks
+
+    def kernels(self) -> Dict[str, Dict[str, int]]:
+        """The launch books per kind, summed over the kind's shape
+        classes (``rt.statistics["kernels"]``, ``GET /stats``, the flight
+        bundle, ``siddhi_kernel_*``)."""
+        with self._lock:
+            es = list(self._entries.values())
+        out: Dict[str, Dict[str, int]] = {}
+        for e in es:
+            row = out.setdefault(e.kind, dict.fromkeys(_KERNEL_BOOKS, 0))
+            for f in _KERNEL_BOOKS:
+                row[f] += getattr(e, f)
+        return out
 
     def totals(self) -> Dict[str, Any]:
         with self._lock:
@@ -627,6 +716,10 @@ class ShapeRegistry:
                 f"siddhi_compile_cache_hits_total{lb} {e.cache_hits}")
             lines.append(
                 f"siddhi_compile_cache_misses_total{lb} {e.cache_misses}")
+        for kind, row in sorted(self.kernels().items()):
+            lb = f'{{kernel="{kind}"}}'
+            for f, series in _KERNEL_BOOKS.items():
+                lines.append(f"{series}{lb} {row[f]}")
         lines.append(f"siddhi_shape_classes {len(es)}")
         lines.append(f"siddhi_prewarm_compiled_total {self.prewarm_compiled}")
         lines.append(f"siddhi_prewarm_skipped_total {self.prewarm_skipped}")
@@ -648,6 +741,8 @@ class ShapeRegistry:
             self._events.clear()
             self._pw_queue.clear()
             self._pw_queued.clear()
+            self.calls = 0
+            self.scan_ticks = 0
             self.prewarm_compiled = 0
             self.prewarm_skipped = 0
             self.prewarm_errors = 0
@@ -655,9 +750,31 @@ class ShapeRegistry:
             self.prewarm_seconds = 0.0
 
 
+#: ShapeEntry field -> the /metrics series that carries its per-kind sum
+_KERNEL_BOOKS = {
+    "calls": "siddhi_kernel_dispatches_total",
+    "compiles": "siddhi_kernel_compile_count",
+    "h2d_bytes": "siddhi_kernel_h2d_bytes_total",
+    "d2h_bytes": "siddhi_kernel_d2h_bytes_total",
+    "live_bytes": "siddhi_kernel_live_bytes",
+    "scan_ticks": "siddhi_kernel_scan_ticks_total",
+}
+
 #: /metrics HELP/TYPE headers — rendered exactly once by
 #: core/statistics.prometheus_text before any samples.
 SHAPES_TYPES = [
+    ("siddhi_kernel_dispatches_total", "counter",
+     "Device launches of a kernel kind"),
+    ("siddhi_kernel_compile_count", "gauge",
+     "XLA compiles (incl. retraces) of a kernel kind"),
+    ("siddhi_kernel_h2d_bytes_total", "counter",
+     "Host bytes (numpy arguments) handed to a kernel kind's launches"),
+    ("siddhi_kernel_d2h_bytes_total", "counter",
+     "Device bytes read back from a kernel kind's results"),
+    ("siddhi_kernel_live_bytes", "gauge",
+     "Persistent device state (carry) placed for a kernel kind"),
+    ("siddhi_kernel_scan_ticks_total", "counter",
+     "Sequential scan ticks, ceil(T/B) a launch, of an NFA kernel kind"),
     ("siddhi_compile_seconds_total", "counter",
      "Attributed XLA trace+compile seconds per shape class"),
     ("siddhi_compile_phase_seconds_total", "counter",

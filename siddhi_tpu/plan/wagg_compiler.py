@@ -199,19 +199,15 @@ class CompiledWindowedAgg:
         # no donation on the time path: overflow replay re-steps the block
         # from the PREVIOUS carry, which donation would have invalidated
         donate = (0,) if self.window_kind == "length" else ()
-        from ..core.profiling import wrap_kernel
         from .shapes import shape_registry
-        self._step = wrap_kernel(
+        self._step = shape_registry().jit(
             f"wagg.{self.window_kind}.step",
-            shape_registry().jit(
-                f"wagg.{self.window_kind}.step",
-                {"win": self.window,
-                 "win_ms": getattr(self, "window_ms", 0),
-                 "filters": len(self.filters),
-                 "minmax": self.want_minmax, "pallas": self.use_pallas,
-                 "donate": bool(donate)},
-                full_step, donate_argnums=donate),
-            batch_of=lambda carry, block: int(block["__ts"].size))
+            {"win": self.window,
+             "win_ms": getattr(self, "window_ms", 0),
+             "filters": len(self.filters),
+             "minmax": self.want_minmax, "pallas": self.use_pallas,
+             "donate": bool(donate)},
+            full_step, donate_argnums=donate)
 
     def _make_carry(self, n: int):
         return (make_wagg_carry(n, self.window)

@@ -17,7 +17,7 @@ query kinds*:
     pending tenant in ONE jitted *gang* dispatch: the gang function
     unrolls each tenant's own ``build_block_step(spec)`` AND its egress
     pack at trace time, so heterogeneous condition programs coexist in
-    a single XLA executable (`nfa.xstep` on the profiler);
+    a single XLA executable (kind `nfa.xstep` in the shape registry);
   - co-scheduled tenants register their match buffers on one shared
     :class:`~..plan.pipeline.EgressFuser` — one concatenated D2H slab
     per bucket flush, with per-tenant decode offsets (`seal_block`).
@@ -101,7 +101,6 @@ def _build_gang(nfas: List[Any], trigger: str = "build"):
     device launch per bucket flush.  Tenants' condition programs are
     heterogeneous (different closures), so this is a trace-time unroll,
     not a vmap; the bucket cap bounds the unroll width."""
-    from ..core.profiling import wrap_kernel
     from ..ops.nfa import build_block_step
     from .shapes import shape_registry
     steps = [build_block_step(n.spec) for n in nfas]
@@ -123,15 +122,6 @@ def _build_gang(nfas: List[Any], trigger: str = "build"):
                         nc.get("telem") if telem[i] else None))
         return out
 
-    def batch_of(carries, blocks):
-        return sum(int(b["__ts"].size) for b in blocks if "__ts" in b)
-
-    def ticks_of(carries, blocks):
-        B = max(max((n.batch_b for n in nfas), default=1), 1)
-        t = max((int(b["__ts"].shape[-1]) for b in blocks
-                 if "__ts" in b), default=0)
-        return (-(-t // B), B)
-
     # shape-class dims: the bucket's shared shape key (every co-ganged
     # tenant matches it — see _shape_key) plus the gang's unroll width
     # and per-tenant egress caps, which are baked into the executable
@@ -141,9 +131,8 @@ def _build_gang(nfas: List[Any], trigger: str = "build"):
             "R": max(n0.spec.n_rows, 1), "C": max(n0.spec.n_caps, 1),
             "telem": bool(n0.spec.telemetry), "n": len(nfas),
             "caps": tuple(caps)}
-    rj = shape_registry().jit("nfa.xstep", dims, gang, trigger=trigger)
-    return wrap_kernel("nfa.xstep", rj,
-                       batch_of=batch_of, ticks_of=ticks_of), caps
+    return shape_registry().jit("nfa.xstep", dims, gang,
+                                trigger=trigger), caps
 
 
 class TenantBucket:
@@ -234,6 +223,7 @@ class TenantBucket:
         pres = [(n.carry, n.base_ts) for n in nfas]
         t_issue = time.perf_counter_ns()
         out = gang([n.carry for n in nfas], [e[1] for e in entries])
+        gang.note_ticks(max(e[1]["__ts"].shape[-1] for e in entries))
         self.flush_total += 1
         for (nfa, block, h), (nc, buf, outs, tele), (pc, pb), cap in \
                 zip(entries, out, pres, caps):

@@ -10,7 +10,8 @@ snapshot/restore — all JSON over stdlib http.server (zero dependencies).
 
 Observability surface: ``GET /metrics`` serves the Prometheus/
 OpenMetrics text exposition over every deployed app's StatisticsManager
-plus the process-global kernel profiler and the opt-in device telemetry
+plus the process-global books (shape registry, ledger, host rim) and
+the opt-in device telemetry
 (core/statistics.prometheus_text); ``GET /stats`` serves the same data
 as JSON.  Flight-recorder endpoints: ``GET /incidents`` lists incident
 summaries, ``GET /incidents/{id}/bundle`` returns a full bundle,
@@ -260,7 +261,6 @@ class SiddhiService:
     # ------------------------------------------------------------ metrics
 
     def _send_metrics(self, h):
-        from ..core.profiling import profiler
         from ..core.statistics import prometheus_text
         managers = [rt.app_ctx.statistics_manager
                     for rt in self.manager.runtimes.values()
@@ -276,8 +276,7 @@ class SiddhiService:
                      if getattr(rt, "device_telemetry", None) is not None]
         from ..core.overload import fair_share
         from ..plan.xtenant import tenant_packer
-        body = prometheus_text(managers, profiler(), resilience,
-                               ingest, telemetry,
+        body = prometheus_text(managers, resilience, ingest, telemetry,
                                tenants=[fair_share(), tenant_packer()]
                                ).encode()
         h.send_response(200)
@@ -289,7 +288,7 @@ class SiddhiService:
 
     def _stats_json(self) -> dict:
         from ..core.ledger import ledger
-        from ..core.profiling import profiler, rim_stats
+        from ..core.profiling import rim_stats
         apps = {}
         for name, rt in self.manager.runtimes.items():
             if rt.app_ctx.statistics_manager is None:
@@ -339,7 +338,7 @@ class SiddhiService:
         # process-global surfaces, mirrored from rt.statistics so the
         # three snapshot surfaces (/metrics, rt.statistics, here) agree
         from ..plan.shapes import shape_registry
-        return {"apps": apps, "kernels": profiler().snapshot(),
+        return {"apps": apps, "kernels": shape_registry().kernels(),
                 "rim": rim_stats().snapshot(),
                 "shapes": shape_registry().snapshot()}
 

@@ -1,8 +1,8 @@
 """Compile-time semantic analyzer (siddhi_tpu/analysis): one positive +
 one clean fixture per diagnostic code, strict-mode promotion, source
 spans, CLI, /stats embedding, and an end-to-end validation of the
-SP001 retrace-hazard prediction against the PR 1 KernelProfiler
-compile counters."""
+SP001 retrace-hazard prediction against the shape registry's compile
+counters."""
 import json
 import os
 import sys
@@ -375,23 +375,21 @@ def test_catalog_docs_are_generated_verbatim():
         "python -m siddhi_tpu.analyze --catalog-md")
 
 
-# ------------------------------------------- SP001 vs KernelProfiler (e2e)
+# ------------------------------------------- SP001 vs compile counts (e2e)
 
-def test_sp001_prediction_matches_kernel_profiler_retraces():
+def test_sp001_prediction_matches_registry_retraces():
     """The retrace-hazard pass predicts that a within-less `every`
     pattern grows its slot ring and re-JITs.  Validate end-to-end: feed
     enough arming events to overflow the default 8-slot ring and assert
-    the KernelProfiler compile counters actually rose — the analyzer's
+    the shape registry's compile counters actually rose — the analyzer's
     SP001 is a *prediction* of exactly this counter movement."""
-    from siddhi_tpu import enable_profiling, profiler
+    from siddhi_tpu.plan.shapes import shape_registry
 
     app = (S + "@info(name='q') "
            "from every e1=S[vol == 0] -> e2=S[vol == 1 and "
            "price > e1.price] select e1.price as p1 insert into Out;")
     assert "SP001" in codes(app)
 
-    was_enabled = profiler().enabled
-    enable_profiling()
     m = SiddhiManager()
     rt = m.create_siddhi_app_runtime(app)
     try:
@@ -411,28 +409,12 @@ def test_sp001_prediction_matches_kernel_profiler_retraces():
 
         arm_batch(1_000)             # warmup: compiles, fills 8 slots
         rt.flush()
-        before = sum(k["compile_count"]
-                     for k in profiler().snapshot().values())
+        before = shape_registry().totals()["compiles"]
         arm_batch(2_000)             # same shape → only growth recompiles
         rt.flush()
-        after = sum(k["compile_count"]
-                    for k in profiler().snapshot().values())
+        after = shape_registry().totals()["compiles"]
         assert after > before, (
             "slot-ring growth should have re-JIT'd the NFA step "
             f"(compile_count {before} -> {after})")
     finally:
         rt.shutdown()
-        if not was_enabled:
-            from siddhi_tpu import disable_profiling
-            disable_profiling()
-
-
-def test_bench_retrace_counter_helper():
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    prof_a = {"nfa.step": {"compile_count": 4},
-              "egress": {"compile_count": 1}}
-    prof_b = {"filter.program": {"compile_count": 2}}
-    assert bench.retrace_count(prof_a, prof_b, None) == 4
-    assert bench.retrace_count({}) == 0

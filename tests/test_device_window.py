@@ -89,20 +89,14 @@ def test_session_timer_dispatch_bounded():
     advance_to() fire the same virtual ms forever (300k+ device
     dispatches on this 60-event stream before the fix).  Bound the
     MEASURED dispatch count, not wall time."""
-    from siddhi_tpu.core.profiling import profiler
+    from siddhi_tpu.plan.shapes import shape_registry
     app = CSE + f"@info(name='q') from cse{KIND_QUERIES['session']} " \
         "select symbol, price, volume insert all events into out;"
     chunks = _random_chunks(seed=zlib.crc32(b"session"))
-    prof = profiler()
-    was = prof.enabled
-    prof.enable()
-    try:
-        d0 = prof.total_dispatches()
-        bd, _ = _run(app, chunks)
-        n_steps = prof.total_dispatches() - d0
-    finally:
-        if not was:
-            prof.disable()
+    reg = shape_registry()
+    d0 = reg.calls
+    bd, _ = _run(app, chunks)
+    n_steps = reg.calls - d0
     assert bd == "device"
     # 18 chunks + one timer per chunk-end+gap instant, plus compile-time
     # warmup steps: orders of magnitude below the runaway regime

@@ -9,7 +9,7 @@ path, for B in {1, 4} and through a forced grow-and-replay — the same
 proof style as tests/test_nfa_batch.py.
 
 Plus the structural claims: a C-chunk bank REALLY pays one device
-dispatch per block (profiler dispatch_count) from ONE compiled
+dispatch per block (the shape registry's calls) from ONE compiled
 executable (compile_count), the donated input carry is REALLY deleted
 after the step, the stacked [C, N, ...] carry is byte-identical to C
 separate chunk carries (asserted against cost_model), the default chunk
@@ -28,7 +28,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from siddhi_tpu.ops.nfa import (STACK_ENV, pack_blocks,  # noqa: E402
                                 resolve_stack)
 from siddhi_tpu.plan.nfa_compiler import CompiledPatternBank  # noqa: E402
-from siddhi_tpu.core.profiling import profiler  # noqa: E402
+from siddhi_tpu.plan.shapes import shape_registry  # noqa: E402
 
 STREAM = "define stream S (partition int, price float, kind int);\n"
 P = 16          # partitions
@@ -126,35 +126,35 @@ def test_grow_and_replay_parity():
 
 
 def test_dispatch_count_drops_c_to_1():
-    """The profiler's dispatch_count sees C device executions per block
+    """The registry's launch count sees C device executions per block
     on the sequential path and exactly ONE on the stacked path, and the
     stacked bank compiles ONE executable for any number of blocks."""
-    prof = profiler()
-    was = prof.enabled
-    prof.enable()
-    try:
-        rng = np.random.default_rng(0)
-        seq = _bank(8, 2, stack=False)
-        stk = _bank(8, 2, stack=True)
-        assert seq.n_chunks == 4 and stk.n_chunks == 4
+    reg = shape_registry()
+    rng = np.random.default_rng(0)
+    seq = _bank(8, 2, stack=False)
+    stk = _bank(8, 2, stack=True)
+    assert seq.n_chunks == 4 and stk.n_chunks == 4
 
-        def dispatches(bank, block):
-            d0 = prof.total_dispatches()
-            np.asarray(bank.process_block(block)[0])
-            return prof.total_dispatches() - d0
+    def dispatches(bank, block):
+        d0, k0 = reg.calls, bank._step.entry.calls
+        np.asarray(bank.process_block(block)[0])
+        # every launch made is a launch of the bank's own step
+        assert reg.calls - d0 == bank._step.entry.calls - k0
+        return reg.calls - d0
 
-        b1, b2 = _block(rng, BASE), _block(rng, BASE + T * GAP)
-        assert dispatches(seq, b1) == 4
-        assert dispatches(seq, b2) == 4
-        c0 = prof.stats("nfa.bank_step").compile_count
-        assert dispatches(stk, b1) == 1
-        assert dispatches(stk, b2) == 1
-        # one executable covers every block of this shape: the only
-        # compile is the first stacked step's
-        assert prof.stats("nfa.bank_step").compile_count - c0 == 1
-    finally:
-        if not was:
-            prof.disable()
+    b1, b2 = _block(rng, BASE), _block(rng, BASE + T * GAP)
+    assert dispatches(seq, b1) == 4
+    assert dispatches(seq, b2) == 4
+    c0 = stk._step.entry.compiles
+    t0 = stk._step.entry.scan_ticks
+    assert dispatches(stk, b1) == 1
+    assert dispatches(stk, b2) == 1
+    # each launch scans its block in ceil(T/B) ticks
+    assert stk._step.entry.scan_ticks - t0 == \
+        2 * -(-T // stk._step.entry.dims["B"]) > 0
+    # one executable covers every block of this shape: the only
+    # compile is the first stacked step's
+    assert stk._step.entry.compiles - c0 == 1
 
 
 def test_donated_carry_is_deleted():
@@ -311,17 +311,13 @@ def test_fused_egress_one_d2h_per_block(monkeypatch):
 def test_app_dispatches_per_block_gauge():
     """The per-app dispatches/block gauge ticks from real ingest deltas
     and exports on /metrics."""
-    prof = profiler()
-    was = prof.enabled
-    prof.enable()
-    try:
-        _run_fuse_app(2)
-        apps = [a for a in prof.app_blocks if prof.app_blocks[a][1] > 0]
-        assert apps, "no app recorded ingest-block dispatch deltas"
-        assert any(prof.dispatches_per_block(a) > 0 for a in apps)
-        lines = "\n".join(prof.prometheus_lines())
-        assert "siddhi_app_dispatches_per_block" in lines
-        assert "siddhi_kernel_dispatches_total" in lines
-    finally:
-        if not was:
-            prof.disable()
+    from siddhi_tpu.core.ledger import ledger
+    ledger().reset()
+    _run_fuse_app(2)
+    per_block = ledger().dispatches_per_block()
+    assert per_block, "no app recorded ingest-block dispatch deltas"
+    assert any(v > 0 for v in per_block.values())
+    assert "siddhi_app_dispatches_per_block" in \
+        "\n".join(ledger().prometheus_lines())
+    assert "siddhi_kernel_dispatches_total" in \
+        "\n".join(shape_registry().prometheus_lines())

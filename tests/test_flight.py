@@ -32,10 +32,7 @@ def _clean_flight(tmp_path, monkeypatch):
     flight().reset()
     yield
     flight().reset()
-    from siddhi_tpu.core.profiling import profiler
     from siddhi_tpu.core.tracing import tracer
-    profiler().disable()
-    profiler().reset()
     tracer().disable()
     tracer().clear()
 

@@ -157,24 +157,18 @@ def test_packed_pays_fewer_dispatches(monkeypatch):
     """The structural point of the layer: N co-bucketed tenants fed
     round-robin pay ~O(1) gang dispatches per wall packed, O(N) with
     the SIDDHI_TPU_XTENANT=0 kill switch."""
-    from siddhi_tpu.core.profiling import profiler
-    prof = profiler()
-    was = prof.enabled
-    prof.enable()
+    from siddhi_tpu.plan.shapes import shape_registry
+    reg = shape_registry()
     apps = [_pattern_app(i, 0.1 * (i % 5)) for i in range(4)]
 
     def measured(packed):
-        d0 = prof.total_dispatches()
+        d0 = reg.calls
         _run_tenants(apps, 7, packed=packed, walls=3)
-        return prof.total_dispatches() - d0
+        return reg.calls - d0
 
-    try:
-        dp, du = measured(True), measured(False)
-        assert dp < du, f"packed {dp} dispatches !< unpacked {du}"
-        assert prof.stats("nfa.xstep").dispatch_count > 0
-    finally:
-        if not was:
-            prof.disable()
+    dp, du = measured(True), measured(False)
+    assert dp < du, f"packed {dp} dispatches !< unpacked {du}"
+    assert reg.kernels()["nfa.xstep"]["calls"] > 0
 
 
 def test_grow_and_replay_bucket_granularity():

@@ -9,7 +9,7 @@ min-0 kleene), randomized feeds produce identical matches, payloads and
 liveness pruning was proven in tests/test_plan_verify.py.
 
 Plus the structural claims: the jaxpr scan length genuinely drops
-T -> ceil(T/B), and the KernelProfiler records scan_ticks/batch_b.
+T -> ceil(T/B), and the step's shape entry records scan_ticks beside B.
 Runs on the conftest-forced virtual 8-device CPU mesh.
 """
 import os
@@ -168,27 +168,22 @@ def test_jaxpr_tick_count_drops():
     assert T in lens1
 
 
-def test_profiler_records_scan_ticks_and_batch_b():
-    from siddhi_tpu.core.profiling import profiler
-    prof = profiler()
-    was = prof.enabled
-    prof.enable()
-    try:
-        prof.stats("nfa.step").scan_ticks = 0
-        nfa = CompiledPatternNFA(STREAM + SHAPES["every_within"],
-                                 n_partitions=2, mesh=None, batch_b=4)
-        pids = np.zeros(10, np.int64)      # one lane -> T = 10
-        cols = {"price": np.linspace(1, 99, 10).astype(np.float32),
-                "kind": np.tile([0.0, 1.0], 5).astype(np.float32)}
-        ts = 1_000_000 + np.arange(10, dtype=np.int64) * 100
-        nfa.process_events(pids, cols, ts)
-        st = prof.snapshot()["nfa.step"]
-        assert st["batch_b"] == 4
-        assert st["scan_ticks"] == -(-10 // 4)      # ceil(T/B) = 3
-        assert "scan_ticks" in st and "batch_b" in st
-    finally:
-        if not was:
-            prof.disable()
+def test_registry_records_scan_ticks_and_batch_b():
+    from siddhi_tpu.plan.shapes import shape_registry
+    reg = shape_registry()
+    nfa = CompiledPatternNFA(STREAM + SHAPES["every_within"],
+                             n_partitions=2, mesh=None, batch_b=4)
+    entry = nfa._step.entry
+    ticks0, total0 = entry.scan_ticks, reg.scan_ticks
+    pids = np.zeros(10, np.int64)      # one lane -> T = 10
+    cols = {"price": np.linspace(1, 99, 10).astype(np.float32),
+            "kind": np.tile([0.0, 1.0], 5).astype(np.float32)}
+    ts = 1_000_000 + np.arange(10, dtype=np.int64) * 100
+    nfa.process_events(pids, cols, ts)
+    assert entry.dims["B"] == 4
+    assert entry.scan_ticks - ticks0 == -(-10 // 4)      # ceil(T/B) = 3
+    assert reg.scan_ticks - total0 == 3
+    assert reg.kernels()["nfa.step"]["scan_ticks"] >= 3
 
 
 def test_env_kill_switch(monkeypatch):

@@ -1,10 +1,11 @@
 """Observability layer tests: histogram metrics, reporter lifecycle,
-kernel profiling, span tracing, Prometheus exposition, and the
-no-overhead-when-disabled contract.
+the per-kind launch books, span tracing, Prometheus exposition, and the
+no-trackers-when-disabled contract.
 
 (reference shapes: managment/StatisticsTestCase — here extended to the
-full observability PR surface: core/statistics.py, core/profiling.py,
-core/tracing.py, service/rest.py /metrics + /stats.)"""
+full observability surface: core/statistics.py, plan/shapes.py's books,
+core/tracing.py, service/rest.py /metrics + /stats; the books' own tests
+are tests/test_launch_books.py.)"""
 import json
 import threading
 import time
@@ -14,7 +15,6 @@ import numpy as np
 import pytest
 
 from siddhi_tpu import SiddhiManager, StreamCallback
-from siddhi_tpu.core.profiling import profiler
 from siddhi_tpu.core.statistics import (BufferedEventsTracker, Counter,
                                         Gauge, Histogram, LatencyTracker,
                                         StatisticsManager, ThroughputTracker,
@@ -24,14 +24,10 @@ from siddhi_tpu.core.tracing import tracer
 
 @pytest.fixture(autouse=True)
 def _clean_globals():
-    """The profiler and tracer are process-global; isolate each test."""
-    profiler().disable()
-    profiler().reset()
+    """The tracer is process-global; isolate each test."""
     tracer().disable()
     tracer().clear()
     yield
-    profiler().disable()
-    profiler().reset()
     tracer().disable()
     tracer().clear()
 
@@ -185,8 +181,7 @@ def test_statistics_annotation_parsing_and_snapshot_shape():
 
 
 def test_stats_disabled_registers_zero_trackers():
-    """No @app:statistics → no trackers, no profiler enablement: the hot
-    path carries zero observability overhead."""
+    """No @app:statistics → no trackers on the hot path."""
     m = SiddhiManager()
     rt = m.create_siddhi_app_runtime("""
         define stream S (v int);
@@ -199,44 +194,15 @@ def test_stats_disabled_registers_zero_trackers():
     sm = rt.app_ctx.statistics_manager
     rt.shutdown()
     assert sm.throughput == {} and sm.latency == {} and sm.buffered == {}
-    assert not profiler().enabled
     assert all(j.throughput_tracker is None
                for j in rt.junctions.values())
 
 
-# ------------------------------------------------------------- profiling
+# ---------------------------------------------------------- launch books
 
-def test_kernel_profiler_counts_calls_and_compiles():
-    import jax
-    import jax.numpy as jnp
-    from siddhi_tpu.core.profiling import wrap_kernel
-    profiler().enable()
-    fn = wrap_kernel("test.kernel", jax.jit(lambda x: x + 1),
-                     batch_of=lambda x: int(x.size))
-    fn(jnp.zeros(8))
-    fn(jnp.zeros(8))
-    fn(jnp.zeros(16))        # retrace: new shape
-    st = profiler().stats("test.kernel")
-    assert st.calls == 3
-    assert st.compile_count == 2
-    assert st.batch_events == 32 and st.max_batch == 16
-    snap = profiler().snapshot()["test.kernel"]
-    assert snap["compile_count"] == 2 and snap["calls"] == 3
-
-
-def test_kernel_profiler_disabled_is_passthrough():
-    import jax
-    import jax.numpy as jnp
-    from siddhi_tpu.core.profiling import wrap_kernel
-    fn = wrap_kernel("test.off", jax.jit(lambda x: x * 2))
-    out = fn(jnp.ones(4))
-    assert float(out.sum()) == 8.0
-    assert profiler().snapshot()["test.off"]["calls"] == 0
-
-
-def test_engine_device_path_profiles_kernels():
-    """@app:statistics turns kernel profiling on; the device filter
-    program shows up with calls + a compile count."""
+def test_engine_device_path_books_kernels():
+    """The device filter program shows up in ``rt.statistics`` with
+    calls + a compile count."""
     m = SiddhiManager()
     rt = m.create_siddhi_app_runtime("""
         @app:statistics(reporter='console', interval='300')
@@ -254,7 +220,7 @@ def test_engine_device_path_profiles_kernels():
     assert len(got) == 2
     assert "filter.program" in snap, snap
     k = snap["filter.program"]
-    assert k["calls"] >= 1 and k["compile_count"] >= 1
+    assert k["calls"] >= 1 and k["compiles"] >= 1
 
 
 # --------------------------------------------------------------- tracing
@@ -366,8 +332,7 @@ def test_metrics_endpoint_serves_prometheus_text():
                    for ln in lines)
         # per-kernel gauges from the device filter program
         assert any("siddhi_kernel_compile_count{" in ln for ln in lines)
-        assert any("siddhi_kernel_device_time_seconds_total{" in ln
-                   for ln in lines)
+        assert any("siddhi_kernel_dispatches_total{" in ln for ln in lines)
         # histogram bucket invariants: cumulative, count == +Inf bucket
         buckets = [ln for ln in lines
                    if ln.startswith("siddhi_latency_seconds_bucket{")

@@ -182,9 +182,8 @@ def test_prune_keeps_referenced_dead_capture():
         assert _matches(a, feed) == _matches(b, feed)
 
 
-def test_prune_env_kill_switch(monkeypatch):
-    monkeypatch.setenv("SIDDHI_TPU_NFA_PRUNE", "0")
-    nfa = _nfa(PRUNABLE_KLEENE)
+def test_prune_false_is_the_unpruned_reference():
+    nfa = _nfa(PRUNABLE_KLEENE, prune=False)
     assert not nfa.prune_enabled
     assert nfa.prune_report["pruned_states"] == 0
 
@@ -337,27 +336,26 @@ def test_hbm_prediction_byte_exact(app):
 
 
 def test_bank_prediction_matches_live_bytes_gauge():
-    from siddhi_tpu.core.profiling import profiler
+    import gc
+
     from siddhi_tpu.plan.nfa_compiler import CompiledPatternBank
-    prof = profiler()
-    was = prof.enabled
-    prof.enable()
-    try:
-        apps = [STREAM + f"from every e1=S[kind == 0 and price > {t}] -> "
-                "e2=S[kind == 1] within 10 sec "
-                "select e1.price as p1 insert into Out;"
-                for t in (10.0, 50.0)]
-        bank = CompiledPatternBank(apps, n_partitions=4, n_slots=4,
-                                   pattern_chunk=2)
-        ir = automaton_ir_from_nfa(bank.nfa, "bank")
-        predicted = bank_state_bytes(ir, 2, n_partitions=4)
-        measured = prof.snapshot()["nfa.bank_step"]["live_bytes"]
-        assert measured > 0
-        # acceptance bound is 2x; the formulas are in fact byte-exact
-        assert predicted == measured
-    finally:
-        if not was:
-            prof.disable()
+    from siddhi_tpu.plan.shapes import shape_registry
+    apps = [STREAM + f"from every e1=S[kind == 0 and price > {t}] -> "
+            "e2=S[kind == 1] within 10 sec "
+            "select e1.price as p1 insert into Out;"
+            for t in (10.0, 50.0)]
+    gc.collect()    # the gauge sums the live engines of a kind
+    held = shape_registry().kernels().get("nfa.bank_step",
+                                          {"live_bytes": 0})["live_bytes"]
+    bank = CompiledPatternBank(apps, n_partitions=4, n_slots=4,
+                               pattern_chunk=2)
+    ir = automaton_ir_from_nfa(bank.nfa, "bank")
+    predicted = bank_state_bytes(ir, 2, n_partitions=4)
+    measured = shape_registry().kernels()["nfa.bank_step"]["live_bytes"] \
+        - held
+    assert measured > 0
+    # acceptance bound is 2x; the formulas are in fact byte-exact
+    assert predicted == measured
 
 
 # ================================================== surfaces

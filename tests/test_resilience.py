@@ -206,7 +206,7 @@ def test_flaky_sink_zero_loss_and_nonblocking_ingest():
     p99 = lat.percentiles_ms()["p99_ms"]
     assert p99 < 50.0, f"ingest p99 {p99:.1f} ms — retries blocked the sender"
 
-    text = prometheus_text([], None, [m])
+    text = prometheus_text([], [m])
     assert '# TYPE siddhi_sink_retry_total counter' in text
     assert 'siddhi_sink_retry_total{app="' + rt.name + '",sink="outs"}' \
         in text

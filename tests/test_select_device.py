@@ -265,7 +265,10 @@ def test_persist_restore_device_selector_state():
         lambda evs: out1.extend(tuple(e.data) for e in evs)))
     rt.start()
     rt.get_input_handler("S").send_batch(b1[0], timestamps=b1[1])
-    assert rt.query_runtimes["q"].selection_route["backend"] == "device"
+    route = rt.query_runtimes["q"].selection_route
+    assert route["backend"] == "device"
+    # having + order-by + limit 3, all in the egress kernel's signature
+    assert route["sig"].startswith("h1o1l3"), route
     rt.persist()
     rt.shutdown()
 

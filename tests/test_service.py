@@ -206,7 +206,8 @@ def test_metrics_exposition_is_prometheus_clean():
 
     # the drifted kernel series and the new telemetry series are covered
     for name in ("siddhi_kernel_scan_ticks_total",
-                 "siddhi_kernel_live_bytes", "siddhi_kernel_batch_b",
+                 "siddhi_kernel_live_bytes",
+                 "siddhi_kernel_dispatches_total",
                  "siddhi_nfa_state_occupancy",
                  "siddhi_nfa_gate_pass_total"):
         assert name in helps, f"missing header for {name}"

@@ -11,8 +11,8 @@ regressed 3x".  This parses the output of
 ``<sec>s <call|setup|teardown> <file>::<test>``) and emits
 
   * a per-file timing table on stdout (seconds by phase, test count),
-  * optionally a bench-style JSON artifact (``-o T1_rNN.json``) so
-    rounds can be diffed the same way BENCH_rNN.json rounds are.
+  * optionally a JSON artifact (``-o T1_rNN.json``) so rounds can be
+    diffed.
 
 Also extracted: the pass/fail/skip/error tallies, total wall time, and
 the DOTS count (progress characters), which is the cross-round
