@@ -337,7 +337,10 @@ CATALOG: Dict[str, CatalogEntry] = {e.code: e for e in [
        "size, host round-trips mid-trace).  Under jit this retraces or "
        "falls back to host per batch.",
        "Use fixed-size forms (masking via where, nonzero with size=) so "
-       "the trace is shape-static."),
+       "the trace is shape-static.  Over a large mask nonzero with size= "
+       "lowers to a scatter-add of every mask element, which a TPU does "
+       "one element after another; the engine compacts by a search over "
+       "prefix counts instead (ops/compact.compact_indices)."),
     _C("PV013", _W, "jaxpr-unexpected-gather",
        "A jitted step that should be purely elementwise (e.g. the filter "
        "column program) contains gather/scatter primitives — lane-"

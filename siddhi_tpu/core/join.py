@@ -324,6 +324,7 @@ class JoinRuntime:
         try:
             import jax
             import jax.numpy as jnp
+            from ..ops.compact import compact_indices
             # device scope: numeric attrs mirror the joined scope's
             # wiring; string/double attrs never reach the program raw —
             # the rewritten condition reads their per-probe lanes (exact
@@ -381,14 +382,11 @@ class JoinRuntime:
                 m = jnp.broadcast_to(m, (lvalid.shape[0],
                                          rvalid.shape[0]))
                 m = m & lvalid[:, None] & rvalid[None, :]
-                flat = m.reshape(-1)
                 # device-side compaction: reading the full [n, m] mask
                 # back costs ~n*m bytes; the first-cap matching pair
                 # indices (row-major == host emission order) + the true
                 # count cost ~cap
-                (idx,) = jnp.nonzero(flat, size=cap, fill_value=-1)
-                return idx.astype(jnp.int32), \
-                    jnp.sum(flat.astype(jnp.int32))
+                return compact_indices(m, cap)
 
             from ..plan.shapes import shape_registry
             self._probe_jit = shape_registry().jit(

@@ -51,6 +51,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .compact import compact_indices
+
 TS_NONE = np.int32(2 ** 31 - 1)      # "never" / empty sentinel
 C_TIME, C_LEN, C_BATCH, C_EXPBATCH, C_DELAY = 1, 2, 3, 4, 5
 
@@ -151,8 +153,7 @@ def _pack_egress(emit_mask, pool_idx, evict_t, cause, pts, pf, pi,
     P, M = emit_mask.shape
     F = pf.shape[-1]
     I = pi.shape[-1]
-    flat = emit_mask.reshape(-1)
-    (idx,) = jnp.nonzero(flat, size=cap, fill_value=-1)
+    idx, count = compact_indices(emit_mask, cap)
     safe = jnp.maximum(idx, 0)
 
     def g(a):
@@ -164,7 +165,7 @@ def _pack_egress(emit_mask, pool_idx, evict_t, cause, pts, pf, pi,
         [idx[:, None], g(evict_t), g(cause), g(pts), f_bits, i_vals],
         axis=1)
     tail = jnp.zeros((1, 4 + F + I), jnp.int32)
-    tail = tail.at[0, 0].set(jnp.sum(flat.astype(jnp.int32)))
+    tail = tail.at[0, 0].set(count)
     for k, v in enumerate(tail_vals):
         tail = tail.at[0, 1 + k].set(v)
     if telem_row is not None:

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..compiler import SiddhiCompiler
+from ..ops.compact import compact_indices
 from ..ops.nfa import (ABSENT_CTR, CLOCK_KEY, COUNT_INF, NfaSpec,
                        UnitSpec, build_block_step,
                        make_carry, make_timer_block, pack_blocks,
@@ -1850,17 +1851,18 @@ class CompiledPatternNFA:
 
         def pack(mask, caps, ts, enter, seq, dropped, dl_st, dl, cap,
                  ctr=None):
-            flat = mask.reshape(-1)
-            (idx,) = jnp.nonzero(flat, size=cap, fill_value=-1)
+            idx, count = compact_indices(mask, cap)
             safe = jnp.maximum(idx, 0)
             g = lambda a: a.reshape(-1)[safe][:, None]
             caps_i = jax.lax.bitcast_convert_type(
                 caps, jnp.int32).reshape(-1, R * C)[safe]
             rows = jnp.concatenate(
                 [idx[:, None], g(ts), g(enter), g(seq), caps_i], axis=1)
-            tail = jnp.zeros((1, 4 + R * C), jnp.int32)
-            tail = tail.at[0, 0].set(jnp.sum(flat.astype(jnp.int32)))
-            tail = tail.at[0, 1].set(jnp.sum(dropped))
+            # a row of its own: the values, zero beyond them (a pad, not
+            # `.at[].set`, which lowers to a scatter of one element)
+            row = lambda v: jnp.pad(
+                v.astype(jnp.int32), (0, 4 + R * C - v.shape[0]))[None]
+            tail = [count, jnp.sum(dropped)]
             if dl is not None:
                 # earliest live absent-state deadline rides the egress
                 # tail (free column): the pipelined engine schedules its
@@ -1871,14 +1873,13 @@ class CompiledPatternNFA:
                     [u.kind == "absent" for u in self.spec.units] +
                     [False], bool)
                 waiting = absent[jnp.clip(dl_st, 0, S)] & (dl_st >= 0)
-                dmin = jnp.min(jnp.where(waiting, dl,
-                                         jnp.int32(2 ** 31 - 1)))
-                tail = tail.at[0, 2].set(dmin)
+                tail.append(jnp.min(jnp.where(waiting, dl,
+                                              jnp.int32(2 ** 31 - 1))))
+            tail = row(jnp.stack(tail))
             if ctr is not None:
                 # ABSENT_CTR, summed over the lanes, in a row of
                 # their own before the tail: the same transfer
-                crow = jnp.zeros((1, 4 + R * C), jnp.int32)
-                crow = crow.at[0, :ctr.shape[1]].set(jnp.sum(ctr, axis=0))
+                crow = row(jnp.sum(ctr, axis=0))
                 return jnp.concatenate([rows, crow, tail], axis=0)
             return jnp.concatenate([rows, tail], axis=0)
 
