@@ -69,6 +69,9 @@ def nfa_state_bytes(a: AutomatonIR,
     if "count" in kinds:
         b["cnt_cur"] = P * K * I32
         b["cnt_prev"] = P * K * I32
+        # started / appended / forwarded / frozen, per lane
+        # (ops/nfa.COUNT_CTR)
+        b["count_ctr"] = P * 4 * I32
     if a.eps_start and a.is_sequence:
         b["seq_froze"] = P * I32
     if "logical" in kinds:
@@ -78,9 +81,7 @@ def nfa_state_bytes(a: AutomatonIR,
         # armed / fired / fired in-block / killed, per lane
         # (ops/nfa.ABSENT_CTR)
         b["absent_ctr"] = P * 4 * I32
-    arm_once = (not a.is_every) or \
-        (not a.is_sequence and a.states and a.states[0].kind == "count")
-    if arm_once:
+    if a.arm_once:
         b["armed_total"] = P * I32
     if a.telemetry:
         # [occ[S] ‖ gate_pass[S] ‖ gate_fail[S] ‖ within_drops] per
@@ -91,10 +92,12 @@ def nfa_state_bytes(a: AutomatonIR,
 
 def nfa_egress_bytes(a: AutomatonIR) -> int:
     """Per-chunk compacted-egress buffer: (cap+1) x (4 + R*C) int32, and
-    one row more (the absent counters) with a `not … for t` unit."""
+    one row more with a `not … for t` unit (the absent counters) and one
+    with a kleene unit (the count counters)."""
     R = max(a.n_rows, 1)
     C = max(a.n_caps, 1)
-    rows = a.egress_cap + 1 + any(s.kind == "absent" for s in a.states)
+    rows = a.egress_cap + 1 + any(s.kind == "absent" for s in a.states) \
+        + any(s.kind == "count" for s in a.states)
     return rows * (4 + R * C) * I32
 
 

@@ -78,6 +78,7 @@ class AutomatonIR:
     is_every: bool = False
     is_sequence: bool = False
     eps_start: bool = False
+    arm_once: bool = False        # single-shot arming (NfaSpec.arm_once)
     dead_start: bool = False
     lead_absent: bool = False
     mid_every: Tuple[Tuple[int, int], ...] = ()
@@ -318,7 +319,8 @@ def automaton_ir_from_nfa(nfa, query: str) -> AutomatonIR:
         n_slots=spec.n_slots, n_rows=spec.n_rows, n_caps=spec.n_caps,
         n_attrs=len(spec.attr_names),
         is_every=spec.is_every, is_sequence=spec.is_sequence,
-        eps_start=spec.eps_start, dead_start=spec.dead_start,
+        eps_start=spec.eps_start, arm_once=spec.arm_once,
+        dead_start=spec.dead_start,
         lead_absent=spec.lead_absent, mid_every=tuple(spec.mid_every),
         tail_every_start=spec.tail_every_start,
         pruned_states=int(report.get("pruned_states", 0)),

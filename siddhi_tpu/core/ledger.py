@@ -163,6 +163,22 @@ ABSENT_COUNTERS = ("absent_armed_total", "absent_fired_total",
 #: the ones that found it made by an earlier query of the partition
 KEY_FACTOR_COUNTERS = ("key_factor_total", "key_factor_reused_total")
 
+#: the per-app counters of kleene `<m:n>` units on the device path, in the
+#: order of a ``_count`` row (ops/nfa.COUNT_CTR, counted on the device and
+#: read off the egress tail as the absent unit's are): chains started;
+#: events appended to a chain (once per chain that takes the event);
+#: chains that reached `m` and opened the next unit; chains that reached
+#: `n` and stopped absorbing
+COUNT_COUNTERS = ("count_armed_total", "count_appended_total",
+                  "count_forwarded_total", "count_frozen_total")
+
+#: the per-app counters of the keyed device runtimes' dense blocks
+#: (``ops/nfa.pack_blocks``), in the order of a ``_pack`` row: events
+#: placed, and the P x T cells of the blocks they were placed in (lanes
+#: times the block's depth, the fullest key's events rounded up to a
+#: power of two): their ratio is how full the blocks are
+PACK_COUNTERS = ("pack_events_total", "pack_cells_total")
+
 
 # os.environ.get pays ~0.9 us per call (key encode + value decode);
 # the ledger asks "am I on?" ~10x per ingest block, so that alone would
@@ -528,6 +544,9 @@ class LatencyLedger:
         self._absent: Dict[str, list] = {}
         # app -> KEY_FACTOR_COUNTERS row, kept as ``_absent`` is
         self._keyfac: Dict[str, list] = {}
+        # app -> COUNT_COUNTERS row and app -> PACK_COUNTERS row, likewise
+        self._count: Dict[str, list] = {}
+        self._pack: Dict[str, list] = {}
         # app -> [device launches, ingest blocks]: the runtimes hand the
         # launch delta of every ingest block to ``note_block``.  Kept as
         # ``_absent`` is
@@ -630,32 +649,39 @@ class LatencyLedger:
         row[0 if ready else 1] += 1
         row[cause] += 1
 
-    def note_absent(self, app: str, deltas) -> None:
-        """Add to an app's ABSENT_COUNTERS (a device pattern runtime, as
-        it retires a block or steps a TIMER)."""
-        row = self._absent.get(app)
+    def _add(self, rows: Dict[str, list], app: str, deltas) -> None:
+        row = rows.get(app)
         if row is None:
             with self._lock:
-                row = self._absent.setdefault(
-                    app, [0] * len(ABSENT_COUNTERS))
+                row = rows.setdefault(app, [0] * len(deltas))
         for i, d in enumerate(deltas):
             row[i] += int(d)
 
+    def note_absent(self, app: str, deltas) -> None:
+        """Add to an app's ABSENT_COUNTERS (a device pattern runtime, as
+        it retires a block or steps a TIMER)."""
+        self._add(self._absent, app, deltas)
+
+    def note_count(self, app: str, deltas) -> None:
+        """Add to an app's COUNT_COUNTERS (a device pattern runtime, as
+        it retires a block)."""
+        self._add(self._count, app, deltas)
+
+    def note_pack(self, app: str, events: int, cells: int) -> None:
+        """One dense block packed: its events and its P x T cells."""
+        self._add(self._pack, app, (events, cells))
+
     def note_key_factor(self, app: str, reused: bool) -> None:
         """One keyed device ingest asked for its block's factored keys."""
-        row = self._keyfac.get(app)
-        if row is None:
-            with self._lock:
-                row = self._keyfac.setdefault(
-                    app, [0] * len(KEY_FACTOR_COUNTERS))
-        row[0] += 1
-        row[1] += reused
+        self._add(self._keyfac, app, (1, reused))
 
     def _counter_rows(self):
         """(counter names, app -> row) of every per-app counter family."""
         return ((RETIRE_COUNTERS, self._retires),
                 (ABSENT_COUNTERS, self._absent),
-                (KEY_FACTOR_COUNTERS, self._keyfac))
+                (KEY_FACTOR_COUNTERS, self._keyfac),
+                (COUNT_COUNTERS, self._count),
+                (PACK_COUNTERS, self._pack))
 
     # ------------------------------------------------------ block fold
 
@@ -820,8 +846,9 @@ class LatencyLedger:
             "span_seconds": {s: self._ns[s] / 1e9 for s in SPAN_NAMES},
             "stage_spans": dict(self._spans),
         }
-        apps = sorted({a for (a, _s) in self._hist} | set(self._absent)
-                      | set(self._keyfac)) if app is None else [app]
+        apps = sorted({a for (a, _s) in self._hist}.union(
+            self._absent, self._keyfac, self._count, self._pack)) \
+            if app is None else [app]
         per_app = {}
         for a in apps:
             entry: Dict[str, Any] = {"stages_ms": self._stage_summary(a)}
@@ -903,9 +930,8 @@ class LatencyLedger:
             self._hist.clear()
             self._pending.clear()
             del self._named[:]
-            self._retires.clear()
-            self._absent.clear()
-            self._keyfac.clear()
+            for _names, rows in self._counter_rows():
+                rows.clear()
             self._blocks.clear()
             self._last_deltas.clear()
             self._lag.clear()

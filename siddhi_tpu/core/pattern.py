@@ -197,6 +197,15 @@ class StateUnit:
                                        else cl.timestamp + self.waiting_ms)
                 self._schedule(self.last_scheduled)
             return
+        if self.is_count and self.is_start and self.min_count >= 1 and \
+                self.state_type == StateType.PATTERN:
+            # `every e=A<m:n> -> ...`: the re-arm clone starts a chain of
+            # its own (the reference's addEveryState clears the clone's
+            # events from this state on); left holding the chain that
+            # made it, it could never reach `min` again and the alert
+            # would fire once per partition for good
+            cl.events[self.state_id] = None
+            cl.timestamp = -1
         self.new_list.append(cl)
         if self.is_absent:
             self.last_scheduled = se.timestamp + (self.waiting_ms or 0)

@@ -552,6 +552,24 @@ LEDGER_TYPES = [
     ("siddhi_key_factor_reused_total",
      "counter", "Of those, ingests that found the factor already made by "
      "an earlier query of the partition"),
+    ("siddhi_count_armed_total",
+     "counter", "Device kleene `<m:n>` chains started (a chain's first "
+     "event appended)"),
+    ("siddhi_count_appended_total",
+     "counter", "Events appended to a device kleene chain, once per chain "
+     "that takes the event"),
+    ("siddhi_count_forwarded_total",
+     "counter", "Device kleene chains that reached `m` and opened the "
+     "next unit"),
+    ("siddhi_count_frozen_total",
+     "counter", "Device kleene chains that reached `n` and stopped "
+     "absorbing"),
+    ("siddhi_pack_events_total",
+     "counter", "Events placed into the keyed device runtimes' dense "
+     "[P, T] blocks"),
+    ("siddhi_pack_cells_total",
+     "counter", "P x T cells of those blocks (lanes times the depth of "
+     "the fullest key, rounded up to a power of two)"),
     ("siddhi_app_dispatches_per_block",
      "gauge", "Device dispatches per ingest block (running average)"),
     ("siddhi_ledger_stage_latency_ms",

@@ -65,6 +65,14 @@ CLOCK_KEY = "__clock"
 #: slots killed by an arrival on the `not` stream
 ABSENT_CTR = ("armed", "fired", "fired_inblock", "killed")
 
+#: what the ``count_ctr`` carry leaf counts, per lane and cumulatively,
+#: over the automaton's kleene `<m:n>` units: chains started (the append
+#: that made a chain one event long); events appended (an event counts
+#: once per chain that takes it); chains that reached `m` and opened the
+#: next unit (a min-0 chain, open from the start, at its first event);
+#: chains that reached `n` and stopped absorbing
+COUNT_CTR = ("armed", "appended", "forwarded", "frozen")
+
 #: B-event micro-batching of the scan chain (round 6).  The env value is
 #: B itself: unset/empty → DEFAULT_BATCH_B; ``=1`` is the kill switch
 #: (legacy one-event ticks, no hoisting).
@@ -240,6 +248,8 @@ def make_carry(spec: NfaSpec, n_partitions: int) -> Dict[str, jnp.ndarray]:
     if _has(spec, "count"):
         carry["cnt_cur"] = jnp.zeros((P, K), jnp.int32)
         carry["cnt_prev"] = jnp.full((P, K), -1, jnp.int32)
+        # cumulative per lane, in COUNT_CTR order; read as absent_ctr is
+        carry["count_ctr"] = jnp.zeros((P, len(COUNT_CTR)), jnp.int32)
     if spec.eps_start and spec.is_sequence:
         # 1 when the leading kleene froze at max on the previous event:
         # the oracle's fresh virgin then finds the next unit's new-list
@@ -379,6 +389,10 @@ class _StepState:
         # zeros until an absent unit's code adds a traced count)
         self.actr = carry.get("absent_ctr")
         self.n_armed = self.n_fired = self.n_inblock = self.n_killed = 0
+        # and to its COUNT_CTR
+        self.cctr = carry.get("count_ctr")
+        self.c_armed = self.c_appended = self.c_forwarded = \
+            self.c_frozen = 0
         self.armed_total = carry.get("armed_total")
         self.m_mask = jnp.zeros((K,), bool)
         self.m_ts = jnp.zeros((K,), jnp.int32)
@@ -543,9 +557,22 @@ class _StepState:
                 [jnp.asarray(n, jnp.int32) for n in
                  (self.n_armed, self.n_fired, self.n_inblock,
                   self.n_killed)])
+        if self.cctr is not None:
+            out["count_ctr"] = self.cctr + jnp.stack(
+                [jnp.asarray(n, jnp.int32) for n in
+                 (self.c_armed, self.c_appended, self.c_forwarded,
+                  self.c_frozen)])
         if self.armed_total is not None:
             out["armed_total"] = self.armed_total
         return out
+
+    def count_chains(self, forwarded=None, frozen=None):
+        """Chains that reached their unit's min / its max this step."""
+        if forwarded is not None:
+            self.c_forwarded = self.c_forwarded + \
+                jnp.sum(forwarded.astype(jnp.int32))
+        if frozen is not None:
+            self.c_frozen = self.c_frozen + jnp.sum(frozen.astype(jnp.int32))
 
     def write_all(self, pred, row: int, ev_rows):
         """Write every lane of `row` for `pred` slots."""
@@ -561,6 +588,9 @@ class _StepState:
         __n lane on every append; e[last-j] banks shift behind the last
         bank (deepest first, BEFORE the new value lands) and e[k] banks
         capture the append that brings the chain to k+1 elements."""
+        self.c_armed = self.c_armed + jnp.sum(pred_first.astype(jnp.int32))
+        self.c_appended = self.c_appended + \
+            jnp.sum(pred_last.astype(jnp.int32))
         if row < 0:
             return
         spec = self.spec
@@ -869,6 +899,7 @@ def _one_partition_step(spec: NfaSpec, carry: Dict, event):
             reach = ok & (c2 == u.min_count)
             dead = reach & (c2 == u.max_count)
             s.land(reach, j, ts, fwd_cnt=c2, fwd_dead=dead)
+            s.count_chains(reach, dead)
             advanced = advanced | reach
             if spec.is_sequence and j == 1 and \
                     units[0].kind == "simple":
@@ -915,9 +946,11 @@ def _one_partition_step(spec: NfaSpec, carry: Dict, event):
                 s.start = jnp.where(ok & (s.cnt_prev == 0), ts, s.start)
             c2 = s.cnt_prev + 1
             s.write_count(ok & (s.cnt_prev == 0), ok, u.row_a, ev_rows, c2)
-            s.cnt_prev = jnp.where(ok, c2, s.cnt_prev)
             # max reached → the reference marks stateChanged and stops
             froze = ok & (c2 == u.max_count)
+            s.count_chains(ok & (s.cnt_prev == 0) if u.min_count == 0
+                           else None, froze)
+            s.cnt_prev = jnp.where(ok, c2, s.cnt_prev)
             s.cnt_prev = jnp.where(froze, -1, s.cnt_prev)
             appended = appended | ok
             if j == 0 and spec.eps_start and spec.is_sequence and \
@@ -1076,6 +1109,8 @@ def _one_partition_step(spec: NfaSpec, carry: Dict, event):
             if r in arm_n1_rows:
                 s.write_count(armed_here, armed_here, r, ev_rows,
                               jnp.full((K,), 1, jnp.int32))
+                s.count_chains(armed_here if u0.min_count <= 1 else None,
+                               armed_here if u0.max_count == 1 else None)
             else:
                 s.write_all(armed_here, r, ev_rows)
     emit_arm = armed_here & arm_match
@@ -1120,6 +1155,7 @@ def _one_partition_step(spec: NfaSpec, carry: Dict, event):
         s.write_count(seeded, seeded, u0.row_a, ev_rows,
                       jnp.full((K,), 1, jnp.int32))
         mx1 = u0.max_count == 1
+        s.count_chains(seeded, seeded if mx1 else None)
         s.cnt_prev = jnp.where(seeded, jnp.int32(-1 if mx1 else 1),
                                s.cnt_prev)
         s.cnt_cur = jnp.where(seeded, 0, s.cnt_cur)

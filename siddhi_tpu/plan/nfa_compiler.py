@@ -32,7 +32,7 @@ import numpy as np
 
 from ..compiler import SiddhiCompiler
 from ..ops.compact import compact_indices
-from ..ops.nfa import (ABSENT_CTR, CLOCK_KEY, COUNT_INF, NfaSpec,
+from ..ops.nfa import (ABSENT_CTR, CLOCK_KEY, COUNT_CTR, COUNT_INF, NfaSpec,
                        UnitSpec, build_block_step,
                        make_carry, make_timer_block, pack_blocks,
                        resolve_batch_b)
@@ -1000,10 +1000,17 @@ class CompiledPatternNFA:
 
         # single-shot arming: non-every queries (both modes — a non-every
         # sequence's one initial partial additionally dies on its first
-        # failed event, see ops/nfa.py), and every-leading-count patterns
-        # (the accumulator chain is shared with the re-arm clones)
+        # failed event, see ops/nfa.py), and the every-leading-count
+        # patterns whose re-arm clone cannot start a chain of its own: a
+        # min-0 kleene (its clone is the empty chain again) and a kleene
+        # inside a longer `every (...)` group (the clone keeps the later
+        # units' events and is dropped).  `every e=A<m:n> -> ...` with
+        # m >= 1 re-arms: a fresh chain opens when the last reached `m`
+        # (core/pattern.py add_every_state)
+        u0 = self.units[0]
         arm_once = (not is_every) or \
-            (not self.is_sequence and self.units[0].kind == "count")
+            (not self.is_sequence and u0.kind == "count" and
+             (u0.min_count == 0 or low.every_group_end > 0))
         # fatter scan ticks (ops/nfa round 6): pinned at compile so every
         # consumer of this spec (engine step, mesh step, bank step, jaxpr
         # sanitizer, cost model, launch books) sees one consistent B
@@ -1027,6 +1034,7 @@ class CompiledPatternNFA:
             cond_free=tuple(cond_free), batch_b=self.batch_b,
             telemetry=bool(telemetry))
         self.has_absent = any(u.kind == "absent" for u in self.units)
+        self.has_count = any(u.kind == "count" for u in self.units)
         self.last_min_deadline: Optional[int] = None
         # the playback clock as this engine's blocks have carried it: the
         # largest event (or TIMER) time stepped so far, absolute ms
@@ -1034,6 +1042,9 @@ class CompiledPatternNFA:
         # ABSENT_CTR summed over the lanes, as the last retired
         # block's egress tail gave them, and TIMER rows stepped
         self.absent_counts = np.zeros(len(ABSENT_CTR), np.int64)
+        # COUNT_CTR, read the same way, and what take_count_delta gave
+        self.count_counts = np.zeros(len(COUNT_CTR), np.int64)
+        self._count_taken = self.count_counts
         self.timer_rows_total = 0
         self.last_telemetry = None   # [P, 3S+1] host int32 after retire
         from ..parallel.mesh import auto_mesh, round_up_partitions
@@ -1850,12 +1861,19 @@ class CompiledPatternNFA:
         C = max(self.spec.n_caps, 1)
 
         def pack(mask, caps, ts, enter, seq, dropped, dl_st, dl, cap,
-                 ctr=None):
+                 ctr=None, cctr=None):
             idx, count = compact_indices(mask, cap)
             safe = jnp.maximum(idx, 0)
             g = lambda a: a.reshape(-1)[safe][:, None]
+            # the rows' captures, gathered from the slab as it lies, by
+            # lane, time row and slot: cut into [-1, R*C] rows first, the
+            # whole [P, T, K, R, C] slab is relaid with every row padded
+            # to a lane tile (4.8 GB of temporaries at 131,072 lanes and
+            # T = 8, against 1.1 this way)
+            _P, T, K = mask.shape
             caps_i = jax.lax.bitcast_convert_type(
-                caps, jnp.int32).reshape(-1, R * C)[safe]
+                caps[safe // (T * K), safe // K % T, safe % K],
+                jnp.int32).reshape(-1, R * C)
             rows = jnp.concatenate(
                 [idx[:, None], g(ts), g(enter), g(seq), caps_i], axis=1)
             # a row of its own: the values, zero beyond them (a pad, not
@@ -1875,13 +1893,12 @@ class CompiledPatternNFA:
                 waiting = absent[jnp.clip(dl_st, 0, S)] & (dl_st >= 0)
                 tail.append(jnp.min(jnp.where(waiting, dl,
                                               jnp.int32(2 ** 31 - 1))))
-            tail = row(jnp.stack(tail))
-            if ctr is not None:
-                # ABSENT_CTR, summed over the lanes, in a row of
-                # their own before the tail: the same transfer
-                crow = row(jnp.sum(ctr, axis=0))
-                return jnp.concatenate([rows, crow, tail], axis=0)
-            return jnp.concatenate([rows, tail], axis=0)
+            # COUNT_CTR and ABSENT_CTR, summed over the lanes, each in
+            # a row of its own before the tail: the same transfer
+            crows = [row(jnp.sum(c, axis=0)) for c in (cctr, ctr)
+                     if c is not None]
+            return jnp.concatenate([rows, *crows, row(jnp.stack(tail))],
+                                   axis=0)
 
         return pack
 
@@ -1892,7 +1909,8 @@ class CompiledPatternNFA:
             C = max(self.spec.n_caps, 1)
             self._egress_jit = shape_registry().jit(
                 "nfa.egress_pack",
-                {"R": R, "C": C, "absent": self.has_absent},
+                {"R": R, "C": C, "absent": self.has_absent,
+                 "count": self.has_count},
                 self._egress_pack_fn(), static_argnums=8)
         return self._egress_jit
 
@@ -1914,8 +1932,9 @@ class CompiledPatternNFA:
         dl_st = self.carry["slot_state"] if self.has_absent else None
         dl = self.carry.get("deadline") if self.has_absent else None
         ctr = self.carry.get("absent_ctr") if self.has_absent else None
+        cctr = self.carry.get("count_ctr")
         buf = self._egress_jit(mask, caps, ts, enter, seq, dropped,
-                               dl_st, dl, self._egress_cap, ctr)
+                               dl_st, dl, self._egress_cap, ctr, cctr)
         # on-device telemetry rides the SAME slab/transfer as the match
         # buffer — readout costs no extra D2H dispatch
         telem = self.carry.get("telem") if self.spec.telemetry else None
@@ -1933,7 +1952,7 @@ class CompiledPatternNFA:
                 telem.copy_to_host_async()
         return {"buf": buf, "fuse": token, "cap": self._egress_cap,
                 "outs": outs, "dropped": dropped, "dl_st": dl_st, "dl": dl,
-                "ctr": ctr,
+                "ctr": ctr, "cctr": cctr,
                 "dl_base": self.base_ts, "tk": (T, K), "telem": telem}
 
     def egress_retire(self, handle):
@@ -1965,7 +1984,8 @@ class CompiledPatternNFA:
             mask, caps, ts, enter, seq = handle["outs"]
             buf = np.asarray(self._ensure_egress_jit()(
                 mask, caps, ts, enter, seq, handle["dropped"],
-                handle["dl_st"], handle["dl"], cap, handle.get("ctr")))
+                handle["dl_st"], handle["dl"], cap, handle.get("ctr"),
+                handle.get("cctr")))
             count = int(buf[-1, 0])
             self.last_dropped_total = int(buf[-1, 1])
         if self.has_absent:
@@ -1976,7 +1996,18 @@ class CompiledPatternNFA:
             if handle.get("ctr") is not None:
                 self.absent_counts = buf[-2, :len(ABSENT_CTR)] \
                     .astype(np.uint32).astype(np.int64)
+        if handle.get("cctr") is not None:
+            at = -3 if handle.get("ctr") is not None else -2
+            self.count_counts = buf[at, :len(COUNT_CTR)] \
+                .astype(np.uint32).astype(np.int64)
         return buf[:count], handle["tk"]
+
+    def take_count_delta(self) -> np.ndarray:
+        """What COUNT_CTR (summed over the lanes) grew by since the last
+        call, as the retired blocks' egress tails gave it."""
+        delta = self.count_counts - self._count_taken
+        self._count_taken = self.count_counts
+        return delta
 
     def _compact_egress(self, mask, caps, ts, enter, seq):
         """Device-side match compaction: ONE [cap+1, 4+R*C] int32 D2H

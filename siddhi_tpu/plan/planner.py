@@ -220,6 +220,14 @@ def _factored_keys(executor, data, app_name: str):
     return data, kf
 
 
+def _note_pack(app_name: str, events: int, block) -> None:
+    """One dense block packed (``ops/nfa.pack_blocks``): its events and
+    its P x T cells go to the app's lane-occupancy counters.  A
+    statically dead automaton packs nothing."""
+    if block is not None:
+        _ledger().note_pack(app_name, events, block["__valid"].size)
+
+
 def _check_shard_count(shards, snap_shards) -> None:
     """Shard-count mismatch on restore is a routing change: key→shard
     assignment is modular in the shard count, so a snapshot taken at S
@@ -529,6 +537,7 @@ class DevicePatternRuntime:
                 h = sh.engine.dispatch_events(pids, sub_cols, ts_arr[rows],
                                               stream_codes=codes,
                                               pad_t_pow2=True)
+            _note_pack(self.app_name, len(rows), h["block"])
             sh.inflight.append(h)
             sh.events += len(rows)
             sh.dispatches += 1
@@ -565,9 +574,11 @@ class DevicePatternRuntime:
                     eng.grow_slots(eng.spec.n_slots * 2)
                     sh.grows += 1
                 self._emit_columns(pids, ts, cols)
+            self._note_count(eng)
             return
         sh.dropped_seen = max(dropped, sh.dropped_seen)
         self._emit_columns(pids, ts, cols)
+        self._note_count(eng)
 
     def _flush_shard(self, sh) -> None:
         while sh.inflight:
@@ -625,6 +636,7 @@ class DevicePatternRuntime:
             h = self.nfa.dispatch_events(
                 pids, cols, ts_arr, stream_codes=codes, pad_t_pow2=True,
                 factor_of=partial(self._column_factor, data, keys))
+        _note_pack(self.app_name, n, h["block"])
         stamp_submit(h)
         self._inflight.append(h)
         # with depth 0 every chunk retires here (synchronous: matches
@@ -687,12 +699,14 @@ class DevicePatternRuntime:
                     self.nfa.base_ts = pre_base
                     self.nfa.grow_slots(self.nfa.spec.n_slots * 2)
                 self._emit_columns(pids, ts, cols)
+            self._note_count(self.nfa)
             if self.nfa.has_absent:
                 self._note_absent()
                 self._schedule_absent(self.nfa.last_min_deadline)
             return
         self._dropped_seen = max(dropped, self._dropped_seen)
         self._emit_columns(pids, ts, cols, h.get("seq"))
+        self._note_count(self.nfa)
         if self.nfa.has_absent:
             # schedule off the retired chunk's carry — the deadline and
             # the counters rode the egress tail, no extra device read
@@ -759,6 +773,13 @@ class DevicePatternRuntime:
         self.head.process(EventChunk.from_columns(names, ts, out_cols))
 
     # -------------------------------------------------- absent-state timers
+
+    def _note_count(self, eng) -> None:
+        """Hand the ledger what an engine's count counters grew by."""
+        if eng.has_count:
+            delta = eng.take_count_delta()
+            if delta.any():
+                _ledger().note_count(self.app_name, delta)
 
     def _note_absent(self) -> None:
         """Hand the ledger what the engine's absent counters grew by."""
@@ -1093,6 +1114,7 @@ class DeviceWindowedAggRuntime(PipelinedDeviceIngest):
                 ts64 = np.zeros(block["__ts"].shape, np.int64)
                 ts64[lanes, rows] = src
                 block["__ts64"] = ts64
+        _note_pack(self.app_name, n, block)
         with led.span("device"):
             outs = self.cwa.process_block(block)
         token = None
@@ -1146,6 +1168,7 @@ class DeviceWindowedAggRuntime(PipelinedDeviceIngest):
                 ts64 = np.zeros(block["__ts"].shape, np.int64)
                 ts64[lanes, rows] = src
                 block["__ts64"] = ts64
+            _note_pack(self.app_name, n, block)
             with _ledger().span("device"):
                 outs = sh.engine.process_block(block)
             for o in outs:

@@ -117,7 +117,7 @@ def _build_gang(nfas: List[Any], trigger: str = "build"):
             dl = nc.get("deadline") if absent[i] else None
             ctr = nc.get("absent_ctr") if absent[i] else None
             buf = packs[i](mask, cp, ts, enter, seq, nc["dropped"],
-                           dl_st, dl, caps[i], ctr)
+                           dl_st, dl, caps[i], ctr, nc.get("count_ctr"))
             out.append((nc, buf, (mask, cp, ts, enter, seq),
                         nc.get("telem") if telem[i] else None))
         return out
@@ -242,6 +242,7 @@ class TenantBucket:
                      dl_st=nc["slot_state"] if nfa.has_absent else None,
                      dl=nc.get("deadline") if nfa.has_absent else None,
                      ctr=nc.get("absent_ctr") if nfa.has_absent else None,
+                     cctr=nc.get("count_ctr"),
                      dl_base=h["base_ts"], tk=(int(T), int(K)), telem=tele,
                      pre_carry=pc, pre_base=pb, t_issue=t_issue)
             h.pop("xpend", None)

@@ -590,14 +590,16 @@ def test_restore_between_two_sends_keeps_pending_deadlines():
 #: and has not changed since: the step is the program it was.  The second
 #: was re-pinned by PR 35, which replaced the pack's `jnp.nonzero` by a
 #: search over prefix counts (`ops/compact.py`) and the tail's
-#: `.at[].set` by a pad; PR 35 left the first as it was, and a change
+#: `.at[].set` by a pad, and again by PR 36, whose pack gathers the rows'
+#: captures from the slab as it lies (no `[-1, R*C]` row cut first); both
+#: left the first as it was, and a change
 #: that moves the first has changed the step.  Under a jax that is not
 #: listed only the structure is compared (no clock leaf, no counter leaf,
 #: the carry's keys, the registry's key): a change of jax's lowering is no
 #: change of the program.
 PLAIN_SHA = {"0.9.0": (
     "0e20f583a6b2993197da9053d80ee3e152df9548326048a65ca55783adc2f598",
-    "7fbde2b685ec7d6b1fd1dbf17a47ca13c45188a886d85dab703e5f630bc30e69")}
+    "a978b80b3e35cc065ae07ee25769a5aadf22517b0a5540a45a59c9f68ffc4bcf")}
 
 
 def test_a_pattern_without_an_absent_unit_compiles_to_the_parents_program():
