@@ -1,20 +1,31 @@
-"""A column of a block, factored once: its distinct values and, for every
-event, the place of its value among them.
+"""A block's partition keys as ids that outlive the block, and a string
+column of a block factored once.
 
 Everything the keyed device runtimes do with a block's partition key —
 the null-key test, ``str()`` of a non-string key, the key→lane lookup,
 the string dictionary of a pattern that selects or compares the key — is
-a function of the *value*, so it is done once per distinct value and
-gathered through ``inv``; and the factorization itself is the same for
-every query of a partition, so the first one that meets a chunk makes it
-and leaves it on the chunk (``EventChunk.factors``) for the others
-(core/partition.py ``_PartitionExecutor.factor``, and ``column_factor``
-below for an encoded string column that is not the key).  Nothing here
-knows lanes or codes: those stay each runtime's own.
+a function of the *value*.  So a partition interns its keys
+(``KeyInterner``: value → id, dense from 0, only ever appended, for as
+long as the partition lives), a block's keys are one dict probe per
+event (``KeyIds``), and what a runtime knows of a key — its lane, its
+dictionary code — is a gather from a table indexed by id (``IdTable``),
+a cache of the runtime's durable dict.  Only a block's *new* keys are
+type-checked, sorted and appended.  The ids are the same for every query
+of a partition, so the first one that meets a chunk makes them and
+leaves them on the chunk (``EventChunk.factors``) for the others
+(core/partition.py ``_PartitionExecutor.factor``).
+
+A string column that is not the key is factored per block as before
+(``Factor``, ``column_factor``): its distinct values and each event's
+place among them.  Nothing here knows lanes or codes: those stay each
+runtime's own.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Hashable, List, Optional, Tuple
+import threading
+from itertools import repeat
+from operator import itemgetter
+from typing import Any, Callable, Dict, Hashable, List, Optional, Tuple
 
 import numpy as np
 
@@ -24,39 +35,163 @@ import numpy as np
 _TYPED_KINDS = "Siub"
 
 
+#: a null key's place among a block's ids, and a table's "not asked yet"
+NULL = -1
+_MISS = -2
+
+
 class Factor:
     """``uniq`` — the distinct non-null values as strings (the ``str()``
     the per-event path would take of each), sorted, a ``U`` array;
     ``inv`` — per event the index of its value in ``uniq``, -1 where the
     value is null; ``raw_str`` — every value was a ``str`` as it came, so
-    ``uniq`` holds the column's own values and not a rendering of them;
-    ``source`` — the name of the chunk's column the values were (by
-    identity), if they were one.
+    ``uniq`` holds the column's own values and not a rendering of them."""
 
-    A partition executor's factor (``compressed``) drops the null events
-    instead: ``keep`` is then their mask (None when every event has a
-    key) and ``inv`` runs over the kept events only, with no -1."""
-
-    __slots__ = ("uniq", "inv", "keep", "raw_str", "source")
+    __slots__ = ("uniq", "inv", "raw_str")
 
     def __init__(self, uniq: np.ndarray, inv: np.ndarray, raw_str: bool):
         self.uniq = uniq
         self.inv = inv
-        self.keep: Optional[np.ndarray] = None
         self.raw_str = raw_str
-        self.source: Optional[str] = None
 
-    def compressed(self) -> "Factor":
-        keep = self.inv >= 0
+
+class KeyInterner:
+    """value → id for as long as its partition lives: ids dense from 0
+    and only ever appended, ``strings[id]`` the key as a string.  One per
+    partition runtime, shared by the executors of its streams, so a key
+    has one id from whichever stream it comes.
+
+    Only ``str`` values (and None) are ever keys of the dict, so nothing
+    else can find an id: ``True`` and ``1`` never meet ``"1"``'s.  A
+    ``str`` that a ``U`` array would not hold as it is (trailing NULs)
+    is not one either: its block goes the per-distinct way, as any value
+    that is no ``str`` does."""
+
+    def __init__(self):
+        self._id_of: Dict[Any, int] = {None: NULL}
+        self.strings: List[str] = []
+        self._lock = threading.Lock()
+
+    def __len__(self) -> int:
+        return len(self.strings)
+
+    def probe(self, values: list) -> Optional[Tuple[np.ndarray, int]]:
+        """One dict probe per value -> (its id, ``NULL`` for None; how
+        many values were not known).  The unknown ones are the block's
+        new keys: admitted where every one is a ``str`` that is its own
+        key, else None and nothing admitted.  Known values take no lock:
+        an id is never reassigned."""
+        known = self._id_of
+        try:
+            if len(values) > 1:
+                # all the lookups in one C call, twice as fast as ``map``
+                # over a large dict; it gives up at the first unknown key
+                try:
+                    return np.fromiter(itemgetter(*values)(known), np.intp,
+                                       len(values)), 0
+                except KeyError:
+                    pass
+            ids = np.fromiter(map(known.get, values, repeat(_MISS)),
+                              np.intp, len(values))
+        except TypeError:               # an unhashable value
+            return None
+        missed = np.flatnonzero(ids == _MISS)
+        if len(missed):
+            new = [values[i] for i in missed.tolist()]
+            distinct = dict.fromkeys(new)
+            if any(type(v) is not str or v[-1:] == "\0" for v in distinct):
+                return None
+            with self._lock:
+                # in the order of their strings; the strings first, so
+                # whoever finds an id finds its string
+                fresh = sorted(v for v in distinct if v not in self._id_of)
+                n = len(self.strings)
+                self.strings.extend(fresh)
+                self._id_of.update(zip(fresh, range(n, n + len(fresh))))
+            ids[missed] = np.fromiter(map(self._id_of.__getitem__, new),
+                                      np.intp, len(new))
+        return ids, len(missed)
+
+    def intern(self, uniq: np.ndarray) -> np.ndarray:
+        """The ids of a ``Factor``'s distinct strings."""
+        return self.probe(uniq.tolist())[0]
+
+
+class KeyIds:
+    """A block's partition keys: ``ids`` — per event its key's id in
+    ``interner``; ``keep`` — the mask of the events that have a key (None
+    when every event has one), ``ids`` running over those only;
+    ``raw_str`` — every value was a ``str`` as it came, so an id's string
+    is the column's own value and not a rendering of it; ``source`` — the
+    name of the chunk's column the values were (by identity), if they
+    were one; ``hits`` — the events whose id the per-event probe gave
+    (null events among them): neither a new key nor a block that went
+    the per-distinct way."""
+
+    __slots__ = ("interner", "ids", "keep", "raw_str", "source", "hits")
+
+    def __init__(self, interner: KeyInterner, ids: np.ndarray,
+                 raw_str: bool, source: Optional[str], hits: int):
+        self.interner = interner
+        self.keep: Optional[np.ndarray] = None
+        keep = ids >= 0
         if not keep.all():
             self.keep = keep
-            self.inv = self.inv[keep]
-        return self
+            ids = ids[keep]
+        self.ids = ids
+        self.raw_str = raw_str
+        self.source = source
+        self.hits = hits
 
     def keys(self) -> np.ndarray:
-        """The key of every (kept) event, as ``np.asarray`` of the
+        """The key of every kept event, as ``np.asarray`` of the
         per-event path's list would be."""
-        return self.uniq[self.inv]
+        uniq, inv = np.unique(self.ids, return_inverse=True)
+        if not len(uniq):
+            return np.empty(0, "U1")
+        strings = self.interner.strings
+        return np.asarray([strings[i] for i in uniq.tolist()])[
+            inv.reshape(-1)]
+
+
+class IdTable:
+    """What one consumer knows of every key, by the key's id: a cache of
+    the consumer's durable dict string → value (a runtime's ``key_lanes``,
+    an automaton's ``str_encoder``), ``NULL`` where the key has not been
+    asked for.  It grows with the interner and is made anew whenever the
+    interner or the dict is another object (a restore), so nothing of it
+    is persisted.  Values are >= 0."""
+
+    __slots__ = ("vals", "_interner", "_durable")
+
+    def __init__(self, dtype):
+        self.vals = np.empty(0, dtype)
+        self._interner = self._durable = None
+
+    def gather(self, keys: KeyIds, durable: Dict[str, Any],
+               admit: Callable[[str], Any], first_sight: bool = False
+               ) -> np.ndarray:
+        """The value of every event's key.  A key the dict does not hold
+        gets its value from ``admit(string)``: the block's such keys in
+        the order of their strings, or of their first events."""
+        if keys.interner is not self._interner or \
+                durable is not self._durable:
+            self.vals = self.vals[:0]
+            self._interner, self._durable = keys.interner, durable
+        strings = keys.interner.strings
+        have, n = len(self.vals), len(strings)
+        if have < n:
+            self.vals = np.concatenate([self.vals, np.fromiter(
+                map(durable.get, strings[have:n], repeat(NULL)),
+                self.vals.dtype, n - have)])
+        out = self.vals[keys.ids]
+        if len(out) and out.min() < 0:
+            new = keys.ids[out < 0].tolist()
+            for i in (dict.fromkeys(new) if first_sight else
+                      sorted(set(new), key=strings.__getitem__)):
+                self.vals[i] = admit(strings[i])
+            out = self.vals[keys.ids]
+        return out
 
 
 def _sorted(strs: List[str], inv: np.ndarray, raw_str: bool) -> Factor:

@@ -85,17 +85,23 @@ _stage_values = itemgetter(*STAGES)     # the seven accumulators at once
 #: renamed span fails a test (benchmark/tests/test_setup_reader.py holds
 #: every metric file to this list), not a run.  What each one wraps:
 #:
-#:   dispatch.keys    the partition-key executor's factor of the chunk:
-#:                    made by the first query of a partition that meets
-#:                    the chunk, found on it by the others
-#:   dispatch.lanes   key -> lane map (``map_keys_to_lanes``) over the
-#:                    distinct keys, and the gather to events
+#:   dispatch.keys    the partition-key executor's ids of the chunk's
+#:                    keys (core/keyfactor.py): one probe per event of
+#:                    the partition's interner, a block's new keys
+#:                    appended to it; made by the first query of a
+#:                    partition that meets the chunk, found on it by the
+#:                    others
+#:   dispatch.lanes   key -> lane map (``map_keys_to_lanes``): a gather
+#:                    from the runtime's table by key id, and lanes for
+#:                    the keys the runtime has not met
 #:   dispatch.cols    kernel input columns (``_event_cols``)
 #:   dispatch.pack    the windowed-agg runtime's ``pack_blocks`` (it sits
 #:                    under ``dispatch`` there, and stage membership is
 #:                    not this list's to change)
-#:   device.encode    string dictionary encoding (per distinct value,
-#:                    then a gather) / derived lanes
+#:   device.encode    string dictionary encoding (the key's own column:
+#:                    a gather from the automaton's table by key id;
+#:                    another string column: per distinct value, then a
+#:                    gather) / derived lanes
 #:   device.pack      the NFA's dense ``[P, T]`` scatter (``pack_blocks``)
 #:   device.sync      a gang bucket's flush, up to and after the gang call
 #:   device.issue     one registry-jitted call (``RegisteredJit.__call__``,
@@ -162,6 +168,15 @@ ABSENT_COUNTERS = ("absent_armed_total", "absent_fired_total",
 #: executor for a block's factored keys (core/keyfactor.py); of those,
 #: the ones that found it made by an earlier query of the partition
 KEY_FACTOR_COUNTERS = ("key_factor_total", "key_factor_reused_total")
+
+#: the per-app counters of the partitions' key interners
+#: (core/keyfactor.py), in the order of a ``_keyint`` row: events of the
+#: blocks whose keys were interned for a keyed device ingest (once per
+#: block, not once per query); of those, the events whose id the
+#: per-event dict probe gave: neither a key new to the partition nor a
+#: block that went the per-distinct way (typed integers, floats, mixed
+#: objects)
+KEY_INTERN_COUNTERS = ("key_intern_events_total", "key_intern_hits_total")
 
 #: the per-app counters of kleene `<m:n>` units on the device path, in the
 #: order of a ``_count`` row (ops/nfa.COUNT_CTR, counted on the device and
@@ -542,8 +557,10 @@ class LatencyLedger:
         # app -> ABSENT_COUNTERS row.  Kept past drop_app, as the stage
         # accumulators are: a run reads it after its app shut down
         self._absent: Dict[str, list] = {}
-        # app -> KEY_FACTOR_COUNTERS row, kept as ``_absent`` is
+        # app -> KEY_FACTOR_COUNTERS row and app -> KEY_INTERN_COUNTERS
+        # row, kept as ``_absent`` is
         self._keyfac: Dict[str, list] = {}
+        self._keyint: Dict[str, list] = {}
         # app -> COUNT_COUNTERS row and app -> PACK_COUNTERS row, likewise
         self._count: Dict[str, list] = {}
         self._pack: Dict[str, list] = {}
@@ -650,12 +667,14 @@ class LatencyLedger:
         row[cause] += 1
 
     def _add(self, rows: Dict[str, list], app: str, deltas) -> None:
-        row = rows.get(app)
-        if row is None:
-            with self._lock:
-                row = rows.setdefault(app, [0] * len(deltas))
-        for i, d in enumerate(deltas):
-            row[i] += int(d)
+        # under the lock: two queries of an app may ingest on two sender
+        # threads at once, and `row[i] += d` alone loses an update
+        with self._lock:
+            row = rows.get(app)
+            if row is None:
+                row = rows[app] = [0] * len(deltas)
+            for i, d in enumerate(deltas):
+                row[i] += int(d)
 
     def note_absent(self, app: str, deltas) -> None:
         """Add to an app's ABSENT_COUNTERS (a device pattern runtime, as
@@ -675,11 +694,17 @@ class LatencyLedger:
         """One keyed device ingest asked for its block's factored keys."""
         self._add(self._keyfac, app, (1, reused))
 
+    def note_key_intern(self, app: str, events: int, hits: int) -> None:
+        """One block's keys interned: its events, and those of them
+        whose id the per-event probe gave."""
+        self._add(self._keyint, app, (events, hits))
+
     def _counter_rows(self):
         """(counter names, app -> row) of every per-app counter family."""
         return ((RETIRE_COUNTERS, self._retires),
                 (ABSENT_COUNTERS, self._absent),
                 (KEY_FACTOR_COUNTERS, self._keyfac),
+                (KEY_INTERN_COUNTERS, self._keyint),
                 (COUNT_COUNTERS, self._count),
                 (PACK_COUNTERS, self._pack))
 
@@ -847,7 +872,8 @@ class LatencyLedger:
             "stage_spans": dict(self._spans),
         }
         apps = sorted({a for (a, _s) in self._hist}.union(
-            self._absent, self._keyfac, self._count, self._pack)) \
+            self._absent, self._keyfac, self._keyint, self._count,
+            self._pack)) \
             if app is None else [app]
         per_app = {}
         for a in apps:

@@ -22,7 +22,8 @@ from ..query_api import (Partition, Query, RangePartitionType,
 from ..query_api.definition import StreamDefinition
 from ..utils.errors import DefinitionNotExistError, SiddhiAppCreationError
 from .event import EventChunk
-from .keyfactor import Factor, factor_keys, factor_values, memoized
+from .keyfactor import (NULL, KeyIds, KeyInterner, factor_keys,
+                        factor_values, memoized)
 from .query_runtime import QueryRuntime
 from .stateschema import PartitionState, persistent_schema
 from .stream import StreamJunction
@@ -139,7 +140,14 @@ class _PartitionExecutor:
     """Per-event key evaluation (ValuePartitionExecutor /
     RangePartitionExecutor in the reference)."""
 
-    def __init__(self, pt, definition, factory):
+    #: the partition's key interner (core/keyfactor.py), shared with the
+    #: executors of its other streams; an executor that was given none
+    #: makes its own at its first block
+    interner: Optional[KeyInterner] = None
+
+    def __init__(self, pt, definition, factory,
+                 interner: Optional[KeyInterner] = None):
+        self.interner = interner
         scope = Scope()
         scope.add_primary(pt.stream_id, None, definition)
         compiler = factory(scope)
@@ -180,27 +188,42 @@ class _PartitionExecutor:
                     out[i] = key
         return out
 
-    def factor(self, chunk: EventChunk) -> Tuple[Factor, bool]:
-        """``keys(chunk)`` as its distinct keys and each event's place
-        among them (core/keyfactor.py), the events without a key masked
-        out by ``keep``; and whether it was already there.  The first
-        query of a partition that meets a chunk makes it, per distinct
-        value where the values allow and from the per-event list where
-        they do not, and leaves it on the chunk for the partition's other
-        queries: they get the one executor and the one chunk object."""
+    def factor(self, chunk: EventChunk) -> Tuple[KeyIds, bool]:
+        """``keys(chunk)`` as per-event ids of the partition's interner
+        (core/keyfactor.py), the events without a key masked out by
+        ``keep``; and whether it was already there.  The first query of a
+        partition that meets a chunk makes it and leaves it on the chunk
+        for the partition's other queries: they get the one executor and
+        the one chunk object."""
         return memoized(chunk, self, partial(self._factor, chunk))
 
-    def _factor(self, chunk: EventChunk) -> Factor:
-        f = None
+    def _factor(self, chunk: EventChunk) -> KeyIds:
+        """Strings and nulls (an object column, a ``U`` array): one dict
+        probe per event.  Anything else (typed integers, ``{int}``
+        objects, floats, bools, mixed objects) is factored per distinct
+        value where the values allow and from the per-event list where
+        they do not, and the distinct strings are interned."""
+        interner = self.interner
+        if interner is None:
+            interner = self.interner = KeyInterner()
+        arr = probed = None
         if self.value_expr is not None:
             arr = self._values(chunk)
-            f = factor_values(arr)
-            if f is not None:
-                f.source = next((name for name, col in chunk.columns.items()
-                                 if col is arr), None)
-        if f is None:
-            f = factor_keys(self.keys(chunk))
-        return f.compressed()
+            if arr.dtype.kind in "OU":
+                probed = interner.probe(arr.tolist())
+        if probed is not None:
+            ids, missed = probed
+            raw_str, hits = True, len(ids) - missed
+        else:
+            f = None if arr is None else factor_values(arr)
+            if f is None:
+                f, arr = factor_keys(self.keys(chunk)), None
+            ids = np.append(interner.intern(f.uniq), NULL)[f.inv]
+            raw_str, hits = f.raw_str, 0
+        source = None if arr is None else next(
+            (name for name, col in chunk.columns.items() if col is arr),
+            None)
+        return KeyIds(interner, ids, raw_str, source, hits)
 
 
 class _PartitionStreamReceiver:
@@ -266,10 +289,14 @@ class PartitionRuntime:
                                 app_runtime.app_ctx.script_functions,
                                 app_runtime.extension_registry)
 
+        # one interner for all the streams of the partition: a key has
+        # one id from whichever stream it comes
+        self.key_interner = KeyInterner()
         self.executors: Dict[str, _PartitionExecutor] = {}
         for pt in partition.partition_types:
             d = app_runtime.definition_of(pt.stream_id)
-            self.executors[pt.stream_id] = _PartitionExecutor(pt, d, factory)
+            self.executors[pt.stream_id] = _PartitionExecutor(
+                pt, d, factory, self.key_interner)
 
         # streams consumed by partition queries
         self.partitioned_streams: List[str] = []

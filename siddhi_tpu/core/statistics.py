@@ -552,6 +552,12 @@ LEDGER_TYPES = [
     ("siddhi_key_factor_reused_total",
      "counter", "Of those, ingests that found the factor already made by "
      "an earlier query of the partition"),
+    ("siddhi_key_intern_events_total",
+     "counter", "Events of the blocks whose partition keys were interned "
+     "for a keyed device ingest, once per block"),
+    ("siddhi_key_intern_hits_total",
+     "counter", "Of those, events whose key id came from the per-event "
+     "probe: no key new to the partition, no per-distinct block"),
     ("siddhi_count_armed_total",
      "counter", "Device kleene `<m:n>` chains started (a chain's first "
      "event appended)"),

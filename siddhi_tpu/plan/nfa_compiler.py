@@ -44,7 +44,7 @@ from ..query_api.definition import AttrType
 from ..query_api.expression import (And, Compare, CompareOp, Constant, IsNull,
                                     Not, Or, TimeConstant, Variable,
                                     variables_of)
-from ..core.keyfactor import factor_values
+from ..core.keyfactor import IdTable, KeyIds, factor_values
 from ..core.ledger import ledger as _ledger
 from ..core.stateschema import (Carry, ListOf, Scalar, Struct,
                                 persistent_schema)
@@ -1081,6 +1081,9 @@ class CompiledPatternNFA:
         the attrs as LONG code lanes."""
         self.str_encoder: Dict[Any, int] = {}
         self.str_decoder: List[Any] = []
+        # a key's code by its partition's id (core/keyfactor.py): a cache
+        # of str_encoder, made anew when a restore replaces it
+        self._code_of_id = IdTable(np.float32)
         self.encoded_attrs: set = set()
         self.derived: Dict[str, Tuple[str, Any, str]] = {}
         if not str_attrs:
@@ -1236,10 +1239,17 @@ class CompiledPatternNFA:
         sight of a value; ingest-side, host).  Nulls map to the reserved
         code 0, which every rewritten compare guards against — host
         parity: null operands compare false.  ``factor`` is the column
-        factored (core/keyfactor.py): one dictionary probe per distinct
-        value, new values taking their codes in the order of the distinct
-        values, then a gather.  A column that is not one of strings (None
-        for its factor) is encoded event by event."""
+        as core/keyfactor.py holds it.  The partition key's own column
+        comes as its ``KeyIds`` (no null among them): a gather by id, and
+        only values the dictionary has not met are looked at.  Another
+        string column comes factored: one dictionary probe per distinct
+        value, then a gather.  Either way new values take their codes in
+        the order of the block's distinct values.  A column that is not
+        one of strings (None for its factor) is encoded event by
+        event."""
+        if isinstance(factor, KeyIds):
+            return self._code_of_id.gather(factor, self.str_encoder,
+                                           self._encode_str)
         if factor is not None:
             vals = factor.uniq.tolist()
             got = list(map(self.str_encoder.get, vals))

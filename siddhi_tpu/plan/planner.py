@@ -35,7 +35,7 @@ from ..query_api.expression import Variable
 from ..query_api.query import OutputEventsFor
 from ..utils.errors import (SiddhiAppCreationError,
                             SiddhiAppRuntimeException)
-from ..core.keyfactor import Factor, column_factor
+from ..core.keyfactor import IdTable, KeyIds, column_factor
 from ..core.ledger import ABSENT_COUNTERS, ON_FLUSH, ledger as _ledger
 from ..core.stateschema import Keyed, persistent_schema
 from ..parallel.shards import build_shards, resolve_shards, split_rows
@@ -111,23 +111,42 @@ def _record_block(rt_obj, marks, stream: str, batch: int, junction=None,
                     extra=extra, ledger=ledger_row)
 
 
+def _lane_of(key_lanes: Dict[Any, int], key) -> int:
+    """The key's lane, the next free one where it has none yet."""
+    lane = key_lanes.get(key)
+    if lane is None:
+        lane = key_lanes[key] = len(key_lanes)
+    return lane
+
+
 class KeyLanes(dict):
-    """key → lane map with a cached vectorized lookup for steady state.
+    """key → lane map, the durable one that snapshots hold, with two
+    caches beside it for steady state.
 
-    After the key population stops growing (the common regime: every
-    batch revisits known keys), per-batch work drops to one
-    np.searchsorted over the batch's DISTINCT keys — zero dict probes.
-    The cache (sorted key array + parallel lane array) is rebuilt lazily
-    whenever the population size changed; lanes are append-only, so a
-    length check is a complete staleness test."""
+    A block whose keys its partition interned (``KeyIds``) gets its lanes
+    by a gather from ``lane_of_id``, a table by key id (core/keyfactor.py
+    ``IdTable``; a restore makes a new map and so a new table).  A plain
+    key array (the sharded ingests) gets them, after the key population
+    stops growing, by one np.searchsorted over the batch's DISTINCT keys
+    — zero dict probes.  That cache (sorted key array + parallel lane
+    array) is rebuilt lazily whenever the population size changed; lanes
+    are append-only, so a length check is a complete staleness test."""
 
-    __slots__ = ("_vkeys", "_vlanes", "_vn")
+    __slots__ = ("_vkeys", "_vlanes", "_vn", "lane_of_id")
 
     def __init__(self, *a, **kw):
         super().__init__(*a, **kw)
         self._vkeys = None
         self._vlanes = None
         self._vn = -1
+        self.lane_of_id = IdTable(np.int64)
+
+    def of(self, keys: KeyIds) -> np.ndarray:
+        """Every event's lane.  Keys this map has not met are admitted
+        in the order the array path below hands lanes out in: of their
+        strings over 64 events, of first sight up to 64."""
+        return self.lane_of_id.gather(keys, self, partial(_lane_of, self),
+                                      first_sight=len(keys.ids) <= 64)
 
     def lookup(self, uniq: np.ndarray) -> Optional[np.ndarray]:
         """Lanes for ``uniq`` (sorted distinct keys) when EVERY key is
@@ -159,47 +178,19 @@ def map_keys_to_lanes(key_lanes: Dict[Any, int], keys,
                       capacity: int, grow_fn) -> np.ndarray:
     """Assign each key a stable lane index, growing the device slab (via
     grow_fn(new_capacity)) when the key population exceeds capacity.
-    ``keys`` is a block's keys as its partition executor factored them
-    (core/keyfactor.py ``Factor``: nothing per event is left to do but a
-    gather), or a plain sequence, which string AND integer keys let
-    factor here.  Over 64 events either way: one dict probe per DISTINCT
-    key in the batch, lanes handed out in the order of the sorted
-    distinct keys — and zero probes in steady state when key_lanes is a
-    KeyLanes with a warm cache (one searchsorted over the distinct
-    keys).  Up to 64 events, and keys with no vector order: one probe
-    per event, lanes in the order of first sight."""
-    uniq = None
-    if isinstance(keys, Factor):
-        if len(keys.inv) > 64:
-            uniq, inv = keys.uniq, keys.inv
-        else:
-            keys = keys.keys().tolist()
+    ``keys`` is a block's keys as its partition executor interned them
+    (core/keyfactor.py ``KeyIds``, for a ``KeyLanes``: a gather by id,
+    and only keys the map has not met are looked at), or a plain
+    sequence, which string AND integer keys let factor here.  Over 64
+    events either way lanes are handed out in the order of the sorted
+    distinct keys: one dict probe per DISTINCT key of a plain batch, and
+    zero in steady state when key_lanes is a KeyLanes with a warm cache
+    (one searchsorted over the distinct keys).  Up to 64 events, and
+    keys with no vector order: lanes in the order of first sight."""
+    if isinstance(keys, KeyIds):
+        lanes = key_lanes.of(keys)
     else:
-        arr = np.asarray(keys)
-        if arr.dtype.kind in "USiu" and len(keys) > 64:
-            uniq, inv = np.unique(arr, return_inverse=True)
-            inv = inv.reshape(-1)
-    if uniq is not None:
-        lane_of = None
-        if isinstance(key_lanes, KeyLanes):
-            lane_of = key_lanes.lookup(uniq)
-        if lane_of is None:
-            lane_of = np.empty(len(uniq), np.int64)
-            for i, k in enumerate(uniq.tolist()):
-                lane = key_lanes.get(k)
-                if lane is None:
-                    lane = len(key_lanes)
-                    key_lanes[k] = lane
-                lane_of[i] = lane
-        lanes = lane_of[inv]
-    else:
-        lanes = np.empty(len(keys), np.int64)
-        for i, k in enumerate(keys):
-            lane = key_lanes.get(k)
-            if lane is None:
-                lane = len(key_lanes)
-                key_lanes[k] = lane
-            lanes[i] = lane
+        lanes = _lanes_of_array(key_lanes, keys)
     if key_lanes and len(key_lanes) > capacity:
         cap = capacity
         while cap < len(key_lanes):
@@ -208,13 +199,31 @@ def map_keys_to_lanes(key_lanes: Dict[Any, int], keys,
     return lanes
 
 
+def _lanes_of_array(key_lanes: Dict[Any, int], keys) -> np.ndarray:
+    arr = np.asarray(keys)
+    if arr.dtype.kind in "USiu" and len(keys) > 64:
+        uniq, inv = np.unique(arr, return_inverse=True)
+        lane_of = None
+        if isinstance(key_lanes, KeyLanes):
+            lane_of = key_lanes.lookup(uniq)
+        if lane_of is None:
+            lane_of = np.fromiter(map(partial(_lane_of, key_lanes),
+                                      uniq.tolist()), np.int64, len(uniq))
+        return lane_of[inv.reshape(-1)]
+    return np.fromiter(map(partial(_lane_of, key_lanes), keys), np.int64,
+                       len(keys))
+
+
 def _factored_keys(executor, data, app_name: str):
     """A keyed device ingest's first step: the chunk's keys as the
     partition executor factors them (once per chunk, whichever of the
     partition's queries comes first), counted per app, and the chunk
-    without its null-key events.  -> (data, factor)"""
+    without its null-key events.  -> (data, key ids)"""
     kf, reused = executor.factor(data)
-    _ledger().note_key_factor(app_name, reused)
+    led = _ledger()
+    led.note_key_factor(app_name, reused)
+    if not reused:
+        led.note_key_intern(app_name, len(data), kf.hits)
     if kf.keep is not None:
         data = data.mask(kf.keep)
     return data, kf
@@ -453,11 +462,11 @@ class DevicePatternRuntime:
     # ------------------------------------------------------------ ingest
 
     @staticmethod
-    def _column_factor(data, keys: Optional[Factor], name: str
-                       ) -> Optional[Factor]:
-        """The factor of an encoded string column of ``data``: the
-        partition key's own where the column is the one the key was
-        taken from, as it came; else the column's, made once per chunk."""
+    def _column_factor(data, keys: Optional[KeyIds], name: str):
+        """What an encoded string column of ``data`` is encoded from: the
+        partition key's ids where the column is the one the key was
+        taken from, as it came; else the column's factor, made once per
+        chunk."""
         if keys is not None and keys.source == name and keys.raw_str:
             return keys
         return column_factor(data, name)

@@ -9,6 +9,16 @@ at any number of queries, cut and junction; the counters say who made and
 who found a factor; lanes are handed out in the parent's order, which a
 frozen copy of the parent's code holds; snapshots decode the same strings
 whichever order their dictionary was filled in.
+
+Since PR 37 the product is per-event ids of an interner that lives as
+long as the partition (one dict probe per event; `KeyIds`), and a key's
+lane and dictionary code are gathers from tables by id.  So besides: a
+key has one id in whatever form and from whichever stream it comes;
+values that are no strings never find a string's id; new keys mid-stream
+get the parent's lanes and codes, also where the partition already knew
+them from another stream, also across a restore; two threads admit a new
+key once; and the two `key_intern_*` counters say how many events the
+probe answered.
 """
 import functools
 import sys
@@ -19,9 +29,10 @@ import pytest
 
 from siddhi_tpu import ColumnarStreamCallback, SiddhiManager
 from siddhi_tpu.core.event import EventChunk
-from siddhi_tpu.core.keyfactor import (Factor, column_factor, factor_keys,
-                                       factor_values)
-from siddhi_tpu.core.ledger import KEY_FACTOR_COUNTERS, ledger
+from siddhi_tpu.core.keyfactor import (KeyIds, KeyInterner, column_factor,
+                                       factor_keys, factor_values)
+from siddhi_tpu.core.ledger import (KEY_FACTOR_COUNTERS, KEY_INTERN_COUNTERS,
+                                    ledger)
 from siddhi_tpu.plan.planner import KeyLanes, map_keys_to_lanes
 
 KEYS = 48
@@ -73,8 +84,8 @@ class Serving:
             self.rows.append((q, str(c["sym"][j]), int(t),
                               float(c["p1"][j]), float(c["p2"][j])))
 
-    def send(self, cols, ts, cut=None):
-        handler = self.rt.get_input_handler("S")
+    def send(self, cols, ts, cut=None, to="S"):
+        handler = self.rt.get_input_handler(to)
         n = len(ts)
         for i in range(0, n, cut or n):
             sl = slice(i, i + (cut or n))
@@ -90,9 +101,12 @@ class Serving:
         return bool(self.rt.partition_runtimes) and \
             all(pr.device_mode for pr in self.rt.partition_runtimes)
 
-    def counters(self):
+    def counters(self, family=KEY_FACTOR_COUNTERS):
         snap = ledger().snapshot(self.name)["apps"].get(self.name, {})
-        return tuple(snap.get(k, 0) for k in KEY_FACTOR_COUNTERS)
+        return tuple(snap.get(k, 0) for k in family)
+
+    def intern_counters(self):
+        return self.counters(KEY_INTERN_COUNTERS)
 
     def finish(self):
         self.rt.flush()
@@ -193,24 +207,65 @@ def test_one_query_of_a_partition_makes_every_factor_itself():
     assert (asked, reused) == (3, 0)
 
 
-def test_counters_are_on_the_snapshot_and_the_metrics_page():
+@pytest.mark.parametrize("family,note,calls,want", [
+    (KEY_FACTOR_COUNTERS, "note_key_factor",
+     [(False,), (True,), (True,), (True,)], (4, 3)),
+    (KEY_INTERN_COUNTERS, "note_key_intern",
+     [(600, 0), (600, 600), (300, 299)], (1500, 899)),
+], ids=["key_factor", "key_intern"])
+def test_counters_are_on_the_snapshot_and_the_metrics_page(
+        family, note, calls, want):
     from siddhi_tpu.core.ledger import LatencyLedger
     from siddhi_tpu.core.statistics import LEDGER_TYPES
     led = LatencyLedger()
     assert "kf" not in led.snapshot()["apps"]
-    for reused in (False, True, True, True):
-        led.note_key_factor("kf", reused)
+    for args in calls:
+        getattr(led, note)("kf", *args)
     assert "kf" in led.snapshot()["apps"]
     entry = led.snapshot("kf")["apps"]["kf"]
-    assert (entry["key_factor_total"], entry["key_factor_reused_total"]) \
-        == (4, 3)
+    assert tuple(entry[k] for k in family) == want
     text = "\n".join(led.prometheus_lines())
-    assert 'siddhi_key_factor_total{app="kf"} 4' in text
-    assert 'siddhi_key_factor_reused_total{app="kf"} 3' in text
-    assert {f"siddhi_{k}" for k in KEY_FACTOR_COUNTERS} <= \
+    for k, v in zip(family, want):
+        assert f'siddhi_{k}{{app="kf"}} {v}' in text
+    assert {f"siddhi_{k}" for k in family} <= \
         {name for name, _kind, _text in LEDGER_TYPES}
     led.reset()
     assert "kf" not in led.snapshot()["apps"]
+
+
+def test_the_probe_answers_known_keys_and_not_new_ones():
+    """`key_intern_events_total` counts a block's events once, whatever
+    the number of queries; `key_intern_hits_total` all of them on a block
+    of known keys (its null events too) and fewer on a block that brings
+    a new key or whose values are no strings."""
+    s = Serving(app_text(fresh("kf"), "pattern", 3), 3)
+
+    later = iter(range(0, 10 ** 6, 10_000))
+
+    def block(cols_ts, **edits):
+        cols, ts = cols_ts
+        before = np.asarray(s.intern_counters())
+        s.send(dict(cols, **edits), ts + next(later))
+        return tuple(np.asarray(s.intern_counters()) - before)
+
+    assert s.intern_counters() == (0, 0)
+    assert block(stream(41, 500)) == (500, 0)          # every key is new
+    assert block(stream(42, 700)) == (700, 700)
+    cols, ts = stream(43, 400)
+    sym = cols["sym"].copy()
+    sym[::9] = None
+    assert block((cols, ts), sym=sym) == (400, 400)
+    sym = cols["sym"].copy()
+    sym[7] = sym[300] = "a new key"
+    assert block((cols, ts), sym=sym) == (400, 398)
+    assert block((cols, ts), sym=sym) == (400, 400)
+    sym[5] = 17                     # no string: the per-event way
+    assert block((cols, ts), sym=sym) == (400, 0)
+    asked, reused = s.counters()
+    assert (asked, reused) == (6 * 3, 6 * 2)
+    for dev in s.device_runtimes().values():
+        assert len(dev.key_lanes) == KEYS + 2 and "17" in dev.key_lanes
+    s.shutdown()
 
 
 def test_a_pattern_outside_a_partition_asks_for_no_factor():
@@ -452,6 +507,117 @@ def test_lanes_are_handed_out_as_the_parent_did(kind, sizes):
     assert f.raw_str == (kind in ("str", "str+null", "U", "float", "mixed"))
 
 
+def _executor(interner=None):
+    """A partition executor over the column `k`, outside any runtime."""
+    from siddhi_tpu.core.partition import _PartitionExecutor
+    ex = _PartitionExecutor.__new__(_PartitionExecutor)
+    ex.ranges = None
+    ex.interner = interner
+    ex.value_expr = type("E", (), {"fn": staticmethod(
+        lambda ctx: ctx.columns["k"])})()
+    return ex
+
+
+def _ids(ex, vals):
+    f, _reused = ex.factor(EventChunk.from_columns(
+        ["k"], np.arange(len(vals)), {"k": vals}))
+    return f
+
+
+@pytest.mark.parametrize("form", ["shared", "fresh", "U", "np.str_"])
+def test_a_key_has_one_id_in_whatever_form_it_comes(form):
+    """The probe hashes by value: the pool's own `str` objects, `str`
+    objects made anew, a `U` array and `np.str_` values of an object
+    array all find the id the key was given first."""
+    ex = _executor()
+    pool = [f"s{i:02d}" for i in range(90)]
+    base = np.asarray(pool, object)
+    first = _ids(ex, base)      # new keys get their ids in sorted order
+    assert first.ids.tolist() == list(range(90)) and first.hits == 0
+    pick = np.random.default_rng(23).integers(0, 90, 500)
+    if form == "shared":
+        vals = base[pick]
+    elif form == "fresh":
+        vals = np.empty(500, object)
+        vals[:] = [("s%02d" % i).encode().decode() for i in pick]
+        assert vals[0] is not pool[pick[0]]
+    elif form == "U":
+        vals = base[pick].astype("U")
+    else:
+        vals = np.empty(500, object)
+        vals[:] = [np.str_(pool[i]) for i in pick]
+        assert type(vals[0]) is np.str_
+    f = _ids(ex, vals)
+    assert f.ids.tolist() == pick.tolist() and f.hits == 500
+    assert f.keep is None and f.raw_str and f.source == "k"
+    assert f.keys().tolist() == [pool[i] for i in pick]
+    assert len(ex.interner) == 90
+
+
+@pytest.mark.parametrize("kind", ["int", "bool", "object-int", "float",
+                                  "mixed", "S"])
+def test_values_that_are_no_strings_never_find_a_strings_id(kind):
+    """`"1"`, `"True"`, `"1.0"` are known keys; then `1`, `True`, `1.0`
+    arrive.  Each finds the id of its `str()`, which is the key it had a
+    lane for at the parent, and none the id of a string it merely equals
+    or hashes like (`True == 1`); no such block counts a hit."""
+    ex = _executor()
+    known = np.asarray(["1", "True", "1.0", "x", "0", "0.0", "-0.0",
+                        "False"] * 10, object)
+    assert _ids(ex, known).hits == 0 and _ids(ex, known).hits == 80
+    vals = _values(kind, np.random.default_rng(29), 300)
+    f = _ids(ex, vals)
+    want = _parent_keys(vals)
+    assert f.hits == 0
+    assert f.keys().tolist() == [k for k in want if k is not None]
+    strings = ex.interner.strings
+    assert len(set(strings)) == len(strings)
+    assert all(type(k) is str for k in ex.interner._id_of if k is not None)
+    if kind == "mixed":
+        by_value = dict(zip(
+            [repr(v) for v, k in zip(vals, want) if k is not None],
+            f.ids.tolist()))
+        assert by_value["1"] == by_value["'1'"] == strings.index("1")
+        assert by_value["True"] == strings.index("True")
+        assert by_value["1.0"] == strings.index("1.0")
+
+
+def test_a_new_key_sent_by_two_threads_at_once_is_admitted_once():
+    """Two threads probe blocks that bring the same new keys, round after
+    round: every key gets one id, both threads read the same id for it,
+    and an id's string is the key."""
+    interner = KeyInterner()
+    rounds, per_round = 8, 5000
+    start = threading.Barrier(2)
+    seen = ([], [])
+
+    def prober(me):
+        for r in range(rounds):
+            vals = [f"r{r}_k{i}" for i in range(per_round)]
+            if me:
+                vals.reverse()
+            start.wait(timeout=60)
+            ids, _missed = interner.probe(vals)
+            seen[me].append(dict(zip(vals, ids.tolist())))
+
+    threads = [threading.Thread(target=prober, args=(me,)) for me in (0, 1)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    strings = interner.strings
+    assert len(strings) == rounds * per_round == len(set(strings))
+    for mine, theirs in zip(*seen):
+        assert mine == theirs
+        assert all(strings[i] == k for k, i in mine.items())
+
+
 def test_factor_values_leaves_what_it_cannot_order():
     assert factor_values(np.asarray([0.0, -0.0])) is None
     mixed = np.empty(3, object)
@@ -466,7 +632,9 @@ def test_factor_values_leaves_what_it_cannot_order():
     assert not f.raw_str       # "b\0" is not what `uniq` holds
     f = factor_keys([None, None])
     assert len(f.uniq) == 0 and f.inv.tolist() == [-1, -1]
-    assert isinstance(f.compressed(), Factor) and len(f.inv) == 0
+    ids = KeyIds(KeyInterner(), f.inv, f.raw_str, None, 0)
+    assert len(ids.ids) == 0 and ids.keep.tolist() == [False, False]
+    assert ids.keys().tolist() == []
     empty = factor_values(np.empty(0, object))
     assert len(empty.uniq) == 0 and len(empty.inv) == 0
 
@@ -564,6 +732,102 @@ def test_a_parent_written_snapshot_restores_and_is_extended():
             assert len(dev.nfa.str_decoder) > len(old[name])
             assert sorted(dev.nfa.str_decoder) == \
                 sorted(gold["decoders"][name])
+    s.shutdown()
+
+
+# ------------------------- new keys mid-stream, two streams of a partition
+
+def _two_stream_app(name, engine=None):
+    def on(text, sid):
+        return text.replace("S[", sid + "[")
+
+    return ((f"@app:engine('{engine}') " if engine else "") +
+            f"@app:name('{name}') @app:playback\n" +
+            STREAM.replace("stream S", "stream A") +
+            STREAM.replace("stream S", "stream B") +
+            "partition with (sym of A, sym of B) begin\n"
+            "@info(name='q0')\n" + on(PATTERN, "A").format(thr=40.0, q=0) +
+            "@info(name='q1')\n" + on(PATTERN, "B").format(thr=45.0, q=1) +
+            "@info(name='q2')\n" + on(LENGTH, "A").format(thr=50.0, q=2) +
+            "end;\n")
+
+
+#: (stream, events, first and last key): B brings keys before any query
+#: that reads A has met them; the third block is small enough (<= 64) for
+#: lanes in the order of first sight; every block brings new keys
+_BLOCKS = [("B", 300, 0, 40), ("A", 300, 20, 60), ("A", 50, 50, 80),
+           ("B", 300, 30, 100), ("A", 400, 0, 120)]
+
+
+def _two_stream_blocks():
+    names = np.random.default_rng(5).permutation(
+        np.asarray([f"k{i:03d}" for i in range(120)], object))
+    out = []
+    for i, (sid, n, lo, hi) in enumerate(_BLOCKS):
+        cols, ts = stream(50 + i, n, names=names[lo:hi])
+        out.append((sid, cols, ts + 10_000 * i))
+    return out
+
+
+def _parent_codes(decoder, keys):
+    """`encode_column` as PR 36 had it over the key's factor: the block's
+    distinct values in sorted order, the new ones appended."""
+    for v in np.unique(np.asarray(keys)).tolist():
+        if v not in decoder:
+            decoder.append(v)
+
+
+@pytest.mark.parametrize("restore_after", [None, 3],
+                         ids=["straight", "restored"])
+def test_new_keys_mid_stream_get_the_parents_lanes_and_codes(restore_after):
+    """Three queries of one partition over two streams: q0 and q2 read
+    only A, q1 only B.  Keys that B brought are known to the partition's
+    interner, and new to q0 and q2, when A brings them: each runtime
+    still hands its lanes and codes out over its own blocks as the frozen
+    parent does, and the rows are the host's.  Restored half way into an
+    app of its own (another interner, other ids), nothing changes: the
+    tables by id are made again from `key_lanes` and `str_encoder`."""
+    blocks = _two_stream_blocks()
+    host = Serving(_two_stream_app(fresh("kf_host"), engine="host"), 3)
+    for sid, cols, ts in blocks:
+        host.send(cols, ts, to=sid)
+    want_rows = host.finish()
+    lanes = {"A": KeyLanes(), "B": KeyLanes()}
+    codes = {"A": [], "B": []}
+    for sid, cols, _ts in blocks:
+        keys = cols["sym"].tolist()
+        _parent_map_keys_to_lanes(lanes[sid], keys, 8, lambda cap: None)
+        _parent_codes(codes[sid], keys)
+    name = fresh("kf")
+    s = Serving(_two_stream_app(name), 3)
+    assert s.on_device()
+    rows = []
+    for i, (sid, cols, ts) in enumerate(blocks):
+        if i == restore_after:
+            s.rt.flush()
+            snap = s.rt.snapshot()
+            rows += s.rows
+            before = _lanes(s)
+            s.shutdown()
+            s = Serving(_two_stream_app(name), 3)
+            s.rt.restore(snap)
+            assert [list(m.items()) for m in _lanes(s).values()] == \
+                [list(m.items()) for m in before.values()]
+        s.send(cols, ts, to=sid)
+    devs = s.device_runtimes()
+    for q, sid in (("q0", "A"), ("q1", "B"), ("q2", "A")):
+        assert list(devs[q].key_lanes.items()) == list(lanes[sid].items())
+        if hasattr(devs[q], "nfa"):
+            assert devs[q].nfa.str_decoder == codes[sid]
+    interner = s.rt.partition_runtimes[0].key_interner
+    seen = {k for _sid, cols, _ts in blocks[restore_after or 0:]
+            for k in cols["sym"].tolist()}
+    assert sorted(interner.strings) == sorted(seen)
+    assert all(ex.interner is interner for ex in
+               s.rt.partition_runtimes[0].executors.values())
+    s.rt.flush()
+    assert same_rows(sorted(rows + s.rows), want_rows)
+    assert len(want_rows) > 100
     s.shutdown()
 
 
