@@ -205,6 +205,7 @@ _KEYED_ENGINES = {
     "keyed-pattern": "nfa-engine",
     "keyed-window-agg": "wagg-engine",
     "keyed-grouped-agg": "gagg-engine",
+    "keyed-join": "join-engine",
 }
 
 
@@ -379,10 +380,33 @@ def _window_elements(qname: str, handlers, engine: str,
     return out
 
 
+def _keyed_join_planned(q: Query, app: Optional[SiddhiApp]) -> bool:
+    """Does the keyed device join runtime take this join?  The planner's
+    own question (plan/join_compiler.plan_keyed_join, which touches no
+    jax), over the app's definitions; a side whose stream is defined
+    only by another query's output is not known here and reads no."""
+    from ..plan.join_compiler import plan_keyed_join
+    from ..utils.errors import SiddhiAppCreationError
+
+    def kind_of(sid):
+        for kind, defs in (("table", app.table_definitions),
+                           ("named window", app.window_definitions),
+                           ("aggregation", app.aggregation_definitions)):
+            if sid in defs:
+                return kind, None
+        return "stream", app.stream_definitions[sid]
+    try:
+        plan_keyed_join(q.input_stream, q, kind_of)
+    except (SiddhiAppCreationError, KeyError):
+        return False
+    return True
+
+
 def _query_elements(q: Query, qname: str, engine: str,
                     device_kinds: Tuple[str, ...],
                     in_partition: bool,
-                    attr_types: Optional[dict] = None) -> List[ElementSchema]:
+                    attr_types: Optional[dict] = None,
+                    app: Optional[SiddhiApp] = None) -> List[ElementSchema]:
     ins = q.input_stream
     els: List[ElementSchema] = []
 
@@ -406,6 +430,13 @@ def _query_elements(q: Query, qname: str, engine: str,
 
     if isinstance(ins, JoinInputStream):
         els.append(ElementSchema(f"{qname}:selector", "selector", "host"))
+        if engine != "host" and not in_partition and app is not None \
+                and _keyed_join_planned(q, app):
+            # window state on the device rings; the select stays host
+            els.append(ElementSchema(
+                f"{qname}:state", "keyed-join", "device",
+                engine=_KEYED_ENGINES["keyed-join"]))
+            return els
         i = 0
         for side in (ins.left, ins.right):
             handlers = getattr(side, "handlers", None) or []
@@ -536,7 +567,8 @@ def extract_app_schema(app: Union[str, SiddhiApp],
             qname = el.name or f"query_{qcount}"
             els.extend(_query_elements(el, qname, engine, device_kinds,
                                        in_partition=False,
-                                       attr_types=_attr_types_for(el)))
+                                       attr_types=_attr_types_for(el),
+                                       app=app))
         elif isinstance(el, Partition):
             pname = f"partition_{qcount}"
             p = ElementSchema(f"partition:{pname}", "partition", "fixed",

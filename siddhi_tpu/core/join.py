@@ -31,7 +31,8 @@ from ..utils.errors import SiddhiAppCreationError
 from ..query_api.expression import expr_children
 from .event import CURRENT, EXPIRED, TIMER, EventChunk
 from .processor import Processor
-from .window import WindowProcessor, create_window_processor
+from .window import (TimeWindowProcessor, WindowProcessor,
+                     create_window_processor)
 
 
 class _Collector(Processor):
@@ -93,17 +94,23 @@ class JoinSide:
                 raise SiddhiAppCreationError(
                     "stream functions on join sides are not supported yet")
 
-    def apply_filters(self, chunk: EventChunk) -> EventChunk:
+    def passing(self, chunk: EventChunk) -> np.ndarray:
+        """Mask of the chunk's events that pass this side's filters
+        (each filter sees the survivors of the ones before it; TIMER
+        rows pass)."""
+        keep = np.ones(len(chunk), bool)
         for f in self.filters:
-            n = len(chunk)
-            if n == 0:
+            idx = np.flatnonzero(keep)
+            if not len(idx):
                 break
-            ctx = EvalCtx(chunk.columns, chunk.timestamps, n)
-            m = np.asarray(f.fn(ctx), bool)
-            if m.ndim == 0:
-                m = np.full(n, bool(m))
-            chunk = chunk.mask(m | (chunk.types == TIMER))
-        return chunk
+            sub = chunk.take(idx)
+            m = np.asarray(f.fn(EvalCtx(sub.columns, sub.timestamps,
+                                        len(idx))), bool)
+            keep[idx] = np.broadcast_to(m, idx.shape) | (sub.types == TIMER)
+        return keep
+
+    def apply_filters(self, chunk: EventChunk) -> EventChunk:
+        return chunk.mask(self.passing(chunk)) if self.filters else chunk
 
     def buffer_chunk(self) -> Optional[EventChunk]:
         """Opposite-side probe target (reference FindableProcessor.find)."""
@@ -123,8 +130,86 @@ class _JoinReceiver:
         self.side = side
 
     def receive_chunk(self, chunk: EventChunk):
+        self.runtime.note_events(len(chunk))
         self.runtime.on_arrival(self.side, chunk)
 
+
+class _SelfJoinReceiver:
+    """The one receiver of a stream joined with itself.  Upstream's
+    junction hands each event to the left receiver and then to the
+    right one, so a later event of a chunk finds the earlier ones in the
+    other side's window.  Two receivers that each take the whole chunk
+    lose that, so this one cuts the chunk into runs in which every left
+    event precedes every right one (an event on both sides ends a run's
+    left part), and hands each run to the left side, then to the right:
+    within a run that is the per-event order, and `send_batch` gives the
+    rows that per-event sends give."""
+
+    def __init__(self, runtime: "JoinRuntime"):
+        self.runtime = runtime
+
+    def receive_chunk(self, chunk: EventChunk):
+        rt = self.runtime
+        rt.note_events(len(chunk))
+        if len(chunk) < 2 or (chunk.types != CURRENT).any():
+            rt.on_arrival(rt.left, chunk)
+            rt.on_arrival(rt.right, chunk)
+            return
+        with rt.qr.lock:
+            on = [rt.left.passing(chunk), rt.right.passing(chunk)]
+            # a run ends before the first left event behind a right one
+            lefts = np.flatnonzero(on[0])
+            at = np.searchsorted(lefts, np.flatnonzero(on[1]), side="right")
+            cuts = np.unique(lefts[at[at < len(lefts)]])
+            for a, b in zip(np.append(0, cuts),
+                            np.append(cuts, len(chunk))):
+                for side, m in zip((rt.left, rt.right), on):
+                    if m[a:b].any():
+                        rt.on_arrival(side, chunk.slice(a, b).mask(m[a:b]),
+                                      filtered=True)
+
+
+
+def joined_scope(sides):
+    """The scope of a join's condition and select, and the definition of
+    its flattened rows: both sides qualified by alias (and stream name),
+    an unqualified name the first side's that defines it
+    (plan/join_compiler.joined_names is the one table of who reads what,
+    for this scope and for the keyed device runtime's plan).  A column
+    is read from ``ctx.qualified[(side.ref, 0)]``."""
+    from ..plan.join_compiler import joined_names
+    scope = Scope()
+    union_attrs: List[Attribute] = []
+    for (qual, name), side in joined_names(sides).items():
+        attr = next(a for a in side.definition.attributes if a.name == name)
+
+        def g(ctx, _r=side.ref, _a=name):
+            return ctx.qualified[(_r, 0)][_a]
+        scope.add(qual, name, attr.type, g)
+        if qual is None:
+            union_attrs.append(attr)
+    return scope, StreamDefinition("__join", union_attrs)
+
+
+def joined_chunk(sides, union_def, cols_of, ts: np.ndarray,
+                 emit_type: int) -> EventChunk:
+    """Joined rows as the selector reads them: ``cols_of[i]`` are the
+    columns of ``sides[i]``, one row per matched pair."""
+    qualified = {}
+    for s, cols in zip(sides, cols_of):
+        qualified[(s.ref, 0)] = cols
+        if s.stream_id != s.ref:
+            qualified[(s.stream_id, 0)] = cols
+    # flattened union columns (left side wins collisions iff it defined
+    # the union attr first)
+    flat: Dict[str, np.ndarray] = {}
+    for a in union_def.attribute_names:
+        for s, cols in zip(sides, cols_of):
+            if a in cols:
+                flat[a] = cols[a]
+                break
+    return EventChunk(union_def.attribute_names, ts,
+                      np.full(len(ts), emit_type, np.int8), flat, qualified)
 
 
 class JoinRuntime:
@@ -147,22 +232,7 @@ class JoinRuntime:
         self.join_type = jis.join_type
         self.trigger = jis.trigger
 
-        # joined scope: both sides qualified + unique attrs unqualified
-        scope = Scope()
-        union_attrs: List[Attribute] = []
-        seen: Dict[str, str] = {}
-        for side in (self.left, self.right):
-            for a in side.definition.attributes:
-                def g(ctx, _r=side.ref, _a=a.name):
-                    return ctx.qualified[(_r, 0)][_a]
-                scope.add(side.ref, a.name, a.type, g)
-                if side.stream_id != side.ref:
-                    scope.add(side.stream_id, a.name, a.type, g)
-                if a.name not in seen:
-                    seen[a.name] = side.ref
-                    union_attrs.append(a)
-                    scope.add(None, a.name, a.type, g)
-        self.union_def = StreamDefinition("__join", union_attrs)
+        scope, self.union_def = joined_scope((self.left, self.right))
 
         self.on: Optional[CompiledExpr] = None
         if jis.on is not None:
@@ -245,8 +315,19 @@ class JoinRuntime:
         # a named-window side subscribes to the shared window itself — its
         # published CURRENT/EXPIRED events trigger the join exactly like
         # the reference's Window.java feeding downstream JoinProcessors
+        junctions = [None if side.is_table or side.is_aggregation or
+                     side.is_named_window else
+                     app.junction_of(s.stream_id, s.is_inner, s.is_fault)
+                     for side, s in ((self.left, jis.left),
+                                     (self.right, jis.right))]
+        self.self_join = junctions[0] is not None and \
+            junctions[0] is junctions[1]
+        if self.self_join:
+            recv = _SelfJoinReceiver(self)
+            junctions[0].subscribe(recv)
+            qr.receivers[f"join:{jis.left.stream_id}"] = recv
         for side, s in ((self.left, jis.left), (self.right, jis.right)):
-            if side.is_table or side.is_aggregation:
+            if side.is_table or side.is_aggregation or self.self_join:
                 continue
             recv = _JoinReceiver(self, side)
             if side.is_named_window:
@@ -506,10 +587,17 @@ class JoinRuntime:
 
     # ------------------------------------------------------------ event flow
 
-    def on_arrival(self, side: JoinSide, chunk: EventChunk):
+    def note_events(self, n: int) -> None:
+        """`n` events reached this join, on the host path."""
+        from .ledger import ledger
+        ledger().note_join_events(self.qr.app_runtime.name, n, 0)
+
+    def on_arrival(self, side: JoinSide, chunk: EventChunk,
+                   filtered: bool = False):
         with self.qr.lock:
             opposite = self.right if side.side == "left" else self.left
-            chunk = side.apply_filters(chunk)
+            if not filtered:
+                chunk = side.apply_filters(chunk)
             if chunk.is_empty:
                 return
             data = chunk.only(CURRENT)
@@ -637,6 +725,14 @@ class JoinRuntime:
             else:
                 mask = np.ones(n * m, bool)
             sel_l, sel_r = li[mask], rj[mask]
+        if emit_type == CURRENT and \
+                type(opposite.window) is TimeWindowProcessor:
+            # an entry is gone at `ts + window <= now` of the probing
+            # event itself: the window's timer runs behind a send, and a
+            # chunk's later events are later than its first
+            seen = buf.timestamps[sel_r] + opposite.window.window_ms > \
+                data.timestamps[sel_l]
+            sel_l, sel_r = sel_l[seen], sel_r[seen]
         if outer_this:
             matched = np.zeros(n, bool)
             matched[sel_l] = True
@@ -653,34 +749,20 @@ class JoinRuntime:
               buf: Optional[EventChunk], sel_l: np.ndarray,
               sel_r: np.ndarray, emit_type: int):
         k = len(sel_l)
-        qualified = {}
-        flat: Dict[str, np.ndarray] = {}
-
-        def null_col(length):
-            return np.full(length, None, object)
-
+        cols_of = {}
         for s, c, idx in ((side, data, sel_l), (opposite, buf, sel_r)):
             cols = {}
             for a in s.definition.attribute_names:
                 if c is None:
-                    cols[a] = null_col(k)
+                    cols[a] = np.full(k, None, object)
                 else:
                     vals = c.columns[a][np.maximum(idx, 0)]
                     if (idx < 0).any():
                         vals = vals.astype(object)
                         vals[idx < 0] = None
                     cols[a] = vals
-            qualified[(s.ref, 0)] = cols
-            if s.stream_id != s.ref:
-                qualified[(s.stream_id, 0)] = cols
-        # flattened union columns (left side wins collisions iff it defined
-        # the union attr first)
-        for a in self.union_def.attribute_names:
-            for s in (self.left, self.right):
-                if a in s.definition.attribute_names:
-                    flat[a] = qualified[(s.ref, 0)][a]
-                    break
-        ts = data.timestamps[sel_l]
-        out = EventChunk(self.union_def.attribute_names, ts,
-                         np.full(k, emit_type, np.int8), flat, qualified)
-        self.head.process(out)
+            cols_of[s.side] = cols
+        self.head.process(joined_chunk(
+            (self.left, self.right), self.union_def,
+            (cols_of["left"], cols_of["right"]), data.timestamps[sel_l],
+            emit_type))

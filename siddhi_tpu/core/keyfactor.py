@@ -154,6 +154,30 @@ class KeyIds:
             inv.reshape(-1)]
 
 
+def intern_values(interner: KeyInterner, arr: Optional[np.ndarray],
+                  source: Optional[str],
+                  per_event: Callable[[], List[Any]]) -> KeyIds:
+    """A block's key values as ids of ``interner``.  Strings and nulls
+    (an object column, a ``U`` array): one dict probe per event.
+    Anything else (typed integers, ``{int}`` objects, floats, bools,
+    mixed objects) is factored per distinct value where the values allow
+    and from ``per_event()``, the per-event list of key strings, where
+    they do not (or where ``arr`` is None), and the distinct strings are
+    interned.  ``source``: the name of the chunk's column that ``arr``
+    is, if it is one."""
+    probed = None
+    if arr is not None and arr.dtype.kind in "OU":
+        probed = interner.probe(arr.tolist())
+    if probed is not None:
+        ids, missed = probed
+        return KeyIds(interner, ids, True, source, len(ids) - missed)
+    f = None if arr is None else factor_values(arr)
+    if f is None:
+        f, source = factor_keys(per_event()), None
+    ids = np.append(interner.intern(f.uniq), NULL)[f.inv]
+    return KeyIds(interner, ids, f.raw_str, source, 0)
+
+
 class IdTable:
     """What one consumer knows of every key, by the key's id: a cache of
     the consumer's durable dict string → value (a runtime's ``key_lanes``,

@@ -194,6 +194,21 @@ COUNT_COUNTERS = ("count_armed_total", "count_appended_total",
 #: power of two): their ratio is how full the blocks are
 PACK_COUNTERS = ("pack_events_total", "pack_cells_total")
 
+#: the per-app counters of joins, in the order of a ``_join`` row.  The
+#: first five are ops/keyed_join.JOIN_CTR, counted on the device by the
+#: keyed join step and read off the egress tail as the count unit's
+#: are: probing events that met a windowed side; those of them that
+#: found a row; rows; events that entered a window ring; entries
+#: expired (at the next event of their key).  Then: ring doublings (a
+#: lane's ring was full of live entries: the block is replayed, nothing
+#: is dropped); events that reached a join query, on either path; of
+#: those, the ones that reached a keyed device join runtime (which
+#: places in its blocks the ones that pass a side's filter)
+JOIN_COUNTERS = ("join_probes_total", "join_probe_hits_total",
+                 "join_rows_total", "join_inserted_total",
+                 "join_expired_total", "join_ring_grown_total",
+                 "join_events_total", "join_device_events_total")
+
 
 # os.environ.get pays ~0.9 us per call (key encode + value decode);
 # the ledger asks "am I on?" ~10x per ingest block, so that alone would
@@ -564,6 +579,8 @@ class LatencyLedger:
         # app -> COUNT_COUNTERS row and app -> PACK_COUNTERS row, likewise
         self._count: Dict[str, list] = {}
         self._pack: Dict[str, list] = {}
+        # app -> JOIN_COUNTERS row, likewise
+        self._join: Dict[str, list] = {}
         # app -> [device launches, ingest blocks]: the runtimes hand the
         # launch delta of every ingest block to ``note_block``.  Kept as
         # ``_absent`` is
@@ -690,6 +707,16 @@ class LatencyLedger:
         """One dense block packed: its events and its P x T cells."""
         self._add(self._pack, app, (events, cells))
 
+    def note_join(self, app: str, deltas, grown: int = 0) -> None:
+        """Add a retired block's JOIN_CTR deltas and ring doublings to
+        an app's JOIN_COUNTERS (the keyed device join runtime)."""
+        self._add(self._join, app, (*deltas, grown, 0, 0))
+
+    def note_join_events(self, app: str, events: int, device: int) -> None:
+        """``events`` reached a join query; ``device`` of them a keyed
+        device join runtime's."""
+        self._add(self._join, app, (0, 0, 0, 0, 0, 0, events, device))
+
     def note_key_factor(self, app: str, reused: bool) -> None:
         """One keyed device ingest asked for its block's factored keys."""
         self._add(self._keyfac, app, (1, reused))
@@ -706,7 +733,8 @@ class LatencyLedger:
                 (KEY_FACTOR_COUNTERS, self._keyfac),
                 (KEY_INTERN_COUNTERS, self._keyint),
                 (COUNT_COUNTERS, self._count),
-                (PACK_COUNTERS, self._pack))
+                (PACK_COUNTERS, self._pack),
+                (JOIN_COUNTERS, self._join))
 
     # ------------------------------------------------------ block fold
 
@@ -873,7 +901,7 @@ class LatencyLedger:
         }
         apps = sorted({a for (a, _s) in self._hist}.union(
             self._absent, self._keyfac, self._keyint, self._count,
-            self._pack)) \
+            self._pack, self._join)) \
             if app is None else [app]
         per_app = {}
         for a in apps:

@@ -22,8 +22,7 @@ from ..query_api import (Partition, Query, RangePartitionType,
 from ..query_api.definition import StreamDefinition
 from ..utils.errors import DefinitionNotExistError, SiddhiAppCreationError
 from .event import EventChunk
-from .keyfactor import (NULL, KeyIds, KeyInterner, factor_keys,
-                        factor_values, memoized)
+from .keyfactor import KeyIds, KeyInterner, intern_values, memoized
 from .query_runtime import QueryRuntime
 from .stateschema import PartitionState, persistent_schema
 from .stream import StreamJunction
@@ -198,32 +197,18 @@ class _PartitionExecutor:
         return memoized(chunk, self, partial(self._factor, chunk))
 
     def _factor(self, chunk: EventChunk) -> KeyIds:
-        """Strings and nulls (an object column, a ``U`` array): one dict
-        probe per event.  Anything else (typed integers, ``{int}``
-        objects, floats, bools, mixed objects) is factored per distinct
-        value where the values allow and from the per-event list where
-        they do not, and the distinct strings are interned."""
-        interner = self.interner
-        if interner is None:
-            interner = self.interner = KeyInterner()
-        arr = probed = None
+        """The chunk's key values through ``intern_values``
+        (core/keyfactor.py); a range partition has no value array and
+        goes by the per-event list of its range names."""
+        if self.interner is None:
+            self.interner = KeyInterner()
+        arr = source = None
         if self.value_expr is not None:
             arr = self._values(chunk)
-            if arr.dtype.kind in "OU":
-                probed = interner.probe(arr.tolist())
-        if probed is not None:
-            ids, missed = probed
-            raw_str, hits = True, len(ids) - missed
-        else:
-            f = None if arr is None else factor_values(arr)
-            if f is None:
-                f, arr = factor_keys(self.keys(chunk)), None
-            ids = np.append(interner.intern(f.uniq), NULL)[f.inv]
-            raw_str, hits = f.raw_str, 0
-        source = None if arr is None else next(
-            (name for name, col in chunk.columns.items() if col is arr),
-            None)
-        return KeyIds(interner, ids, raw_str, source, hits)
+            source = next((name for name, col in chunk.columns.items()
+                           if col is arr), None)
+        return intern_values(self.interner, arr, source,
+                             partial(self.keys, chunk))
 
 
 class _PartitionStreamReceiver:

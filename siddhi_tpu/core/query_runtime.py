@@ -218,15 +218,30 @@ class QueryRuntime:
             self.backend_reason = reason
             self._build_single(q.input_stream, factory)
         elif isinstance(q.input_stream, JoinInputStream):
+            # a keyed window join runs on the ring step, its window
+            # state on the device (plan/planner.py DeviceKeyedJoinRuntime)
+            dev, reason = None, "device keyed join: inside a partition"
+            if self.partition_key is None and \
+                    self._device_key_executors is None and \
+                    getattr(app, "app", None) is not None:
+                from ..plan.planner import plan_join_runtime
+                dev, reason = plan_join_runtime(self, q.input_stream,
+                                                factory)
+            if dev is not None:
+                self.device_runtime = dev
+                self.backend = "device"
+                return
             from .join import JoinRuntime
             self.join_runtime = JoinRuntime(self, q.input_stream, factory)
-            # the on-condition probe — the join's per-event hot loop — may
-            # have compiled to the device; buffers/windows stay host
+            # every other join: the on-condition probe — the join's
+            # per-event hot loop — may have compiled to the device;
+            # buffers/windows stay host
+            self.backend_reason = reason
             if self.join_runtime.device_probe is not None:
                 self.backend = "device"
             else:
-                self.backend_reason = \
-                    self.join_runtime.device_probe_reason
+                self.backend_reason += \
+                    "; " + self.join_runtime.device_probe_reason
         elif isinstance(q.input_stream, StateInputStream):
             if self._device_key_executors is not None:
                 # keyed (partition) mode: device or raise — the caller

@@ -576,6 +576,25 @@ LEDGER_TYPES = [
     ("siddhi_pack_cells_total",
      "counter", "P x T cells of those blocks (lanes times the depth of "
      "the fullest key, rounded up to a power of two)"),
+    ("siddhi_join_probes_total",
+     "counter", "Events of a keyed device join that probed the other "
+     "side's window ring"),
+    ("siddhi_join_probe_hits_total",
+     "counter", "Of those, events that found at least one row"),
+    ("siddhi_join_rows_total",
+     "counter", "Rows a keyed device join matched"),
+    ("siddhi_join_inserted_total",
+     "counter", "Events that entered a keyed device join's window ring"),
+    ("siddhi_join_expired_total",
+     "counter", "Ring entries of a keyed device join expired by event "
+     "time (at the next event of their key)"),
+    ("siddhi_join_ring_grown_total",
+     "counter", "Doublings of a keyed device join's slot ring (a lane was "
+     "full of live entries; the block is replayed, nothing dropped)"),
+    ("siddhi_join_events_total",
+     "counter", "Events that reached a join query, on either path"),
+    ("siddhi_join_device_events_total",
+     "counter", "Of those, events that reached a keyed device join runtime"),
     ("siddhi_app_dispatches_per_block",
      "gauge", "Device dispatches per ingest block (running average)"),
     ("siddhi_ledger_stage_latency_ms",

@@ -35,10 +35,12 @@ from ..query_api.expression import Variable
 from ..query_api.query import OutputEventsFor
 from ..utils.errors import (SiddhiAppCreationError,
                             SiddhiAppRuntimeException)
-from ..core.keyfactor import IdTable, KeyIds, column_factor
+from ..core.keyfactor import (IdTable, KeyIds, KeyInterner, column_factor,
+                              intern_values)
 from ..core.ledger import ABSENT_COUNTERS, ON_FLUSH, ledger as _ledger
 from ..core.stateschema import Keyed, persistent_schema
 from ..parallel.shards import build_shards, resolve_shards, split_rows
+from .join_compiler import CompiledKeyedJoin, plan_keyed_join
 from .nfa_compiler import CompiledPatternNFA
 from .pipeline import (PipelinedDeviceIngest, note_retire,
                        retire_after_submit, settle_inflight, stamp_submit)
@@ -1281,6 +1283,311 @@ class DeviceWindowedAggRuntime(PipelinedDeviceIngest):
 
 
 @persistent_schema(
+    "keyed-join", version=1, schema=Keyed("join"),
+    doc="per-key window rings of a keyed join; one slab (a join is no "
+        "partition's, so it is never sharded)")
+class DeviceKeyedJoinRuntime(PipelinedDeviceIngest):
+    """Keyed inner window join on the ring step (ops/keyed_join.py): the
+    join key's values become lanes of one slab, each windowed side a
+    ring per lane, and an arriving event probes its own lane only — the
+    device replacement for the reference's per-event ``find()`` over the
+    opposite window (JoinProcessor.java:36-122), which core/join.py
+    evaluates as an ``[n, m]`` mask over the whole window.  The select
+    stays the host selector's, over the matched rows.
+
+    Both sides of a stream joined with itself arrive in one chunk and
+    are stepped in one block, in arrival order: tick order within a lane
+    is arrival order, and a lane holds one key."""
+
+    backend = "device"
+    shards = None
+
+    def __init__(self, query_runtime, jis, factory):
+        from ..core.join import joined_scope
+        from ..core.query_runtime import ProcessStreamReceiver
+        from .expr_compiler import Scope
+        from .pipeline import egress_fuser_for
+
+        qr = query_runtime
+        app = qr.app_runtime
+
+        def kind_of(stream_id):
+            for kind, has in (("table", app.has_table),
+                              ("named window", app.has_named_window)):
+                if has(stream_id):
+                    return kind, None
+            if stream_id in app.aggregations:
+                return "aggregation", None
+            return "stream", app.definition_of(stream_id)
+
+        self.plan = plan = plan_keyed_join(jis, qr.query, kind_of)
+        self.qr = qr
+        self.app_name = app.name
+        self.join = CompiledKeyedJoin(plan, initial_lanes(app.app),
+                                      DEFAULT_SLOTS)
+        self.key_lanes: Dict[Any, int] = KeyLanes()
+        self.interner = KeyInterner()
+        # per input stream: the sides its events may be on
+        self._present = {
+            sid: tuple(s.stream_id == sid for s in plan.sides)
+            for sid in dict.fromkeys(s.stream_id for s in plan.sides)}
+        # trace every stream's step before the output tail is wired, so
+        # a condition jnp cannot express rejects while the fall-back to
+        # core/join.py is still clean
+        try:
+            for here in self._present.values():
+                self.join.trace(here)
+        except (SiddhiAppCreationError, JaxRuntimeError):
+            raise
+        except Exception as e:
+            raise SiddhiAppCreationError(
+                f"device keyed join: step not traceable ({e})") from e
+
+        self._filters = []
+        for side in plan.sides:
+            scope = Scope()
+            scope.add_primary(side.stream_id, side.ref, side.definition)
+            compiler = factory(scope)
+            self._filters.append([compiler.compile(e)
+                                  for e in side.filters])
+        scope, self.union_def = joined_scope(plan.sides)
+        qr._finish_chain([], scope, self.union_def, factory)
+        self.head = qr._chain_head([])
+        for sid in self._present:
+            recv = ProcessStreamReceiver(
+                _DeviceIngress(self, 0, sid), qr.lock,
+                app.latency_tracker_for(qr.name), qr.name, app.app_ctx)
+            app.junction_of(sid).subscribe(recv)
+            qr.receivers[sid] = recv
+        self._init_pipeline(app, self._present)
+        self._fuser = egress_fuser_for(app)
+
+    # ------------------------------------------------------------ ingest
+
+    def _grow(self, cap: int) -> None:
+        # lane growth re-shapes the carry: retire in-flight work first,
+        # so a replay never starts from a narrower one
+        self.flush()
+        self.join.grow(cap)
+
+    def _sides_of(self, data, present) -> np.ndarray:
+        """Per event the sides whose filters it passes, as the step's
+        bits (ops/keyed_join.LEFT | RIGHT)."""
+        from .expr_compiler import EvalCtx
+        n = len(data)
+        ctx = EvalCtx(data.columns, data.timestamps, n)
+        bits = np.zeros(n, np.int32)
+        for i in (0, 1):
+            if not present[i]:
+                continue
+            ok = np.ones(n, bool)
+            for f in self._filters[i]:
+                ok &= np.broadcast_to(np.asarray(f.fn(ctx), bool), ok.shape)
+            bits |= ok.astype(np.int32) << i
+        return bits
+
+    def ingest(self, stream_code: int, stream_id: str, chunk) -> None:
+        from ..core.event import CURRENT
+        from .join_compiler import attr_planes
+        data = chunk.only(CURRENT)
+        if data.is_empty:
+            return
+        marks = shape_registry().marks()
+        led = _ledger()
+        arrived = len(data)
+        present = self._present[stream_id]
+        with led.span("dispatch", "cols"):
+            bits = self._sides_of(data, present)
+            keep = bits != 0
+            if not keep.all():
+                data, bits = data.mask(keep), bits[keep]
+        with led.span("dispatch", "keys"):
+            key = self.plan.sides[present.index(True)].key
+            col = data.columns[key]
+            keys = intern_values(
+                self.interner, np.asarray(col), key,
+                lambda: [None if v is None else str(v)
+                         for v in col.tolist()])
+            led.note_key_factor(self.app_name, False)
+            led.note_key_intern(self.app_name, len(data), keys.hits)
+            if keys.keep is not None:       # a null key meets nothing
+                data, bits = data.mask(keys.keep), bits[keys.keep]
+        n = len(data)
+        led.note_join_events(self.app_name, arrived, arrived)
+        if n == 0:
+            return
+        with led.span("dispatch", "lanes"):
+            lanes = map_keys_to_lanes(self.key_lanes, keys,
+                                      self.join.n_lanes, self._grow)
+        with led.span("dispatch", "cols"):
+            offs = self.join.offsets(np.asarray(data.timestamps, np.int64),
+                                     self.flush)
+            # per plane: the events that have a value in it, the values
+            planes = {}
+            for attr, typ in self.join.event_attrs(present):
+                at, vals = self.join.planes_of(attr, typ,
+                                               data.columns[attr], bits)
+                for (name, kind), v in zip(attr_planes(attr, typ), vals):
+                    planes[f"{kind}:{name}"] = (at, v)
+        self._dispatch(data, lanes, offs, bits, planes, present)
+        _record_block(self, marks, stream_id, n)
+
+    def _dispatch(self, data, lanes, offs, bits, planes, present) -> None:
+        """Pack the placed events into one dense block, step it and put
+        it in flight."""
+        from ..ops.nfa import pack_blocks
+        led = _ledger()
+        with led.span("device"):
+            with led.span("device", "pack"):
+                # the float planes ride the scatter of the block itself
+                floats = {}
+                for name, (at, v) in planes.items():
+                    if name[0] == "f":
+                        floats[name] = np.zeros(len(lanes), np.float32)
+                        floats[name][at] = v
+                packed, prow = pack_blocks(
+                    lanes, floats, offs, bits, self.join.n_lanes,
+                    pad_t_pow2=True, return_rows=True)
+                block = {"ts": packed["__ts"], "side": packed["__stream"]}
+                for name, (at, v) in planes.items():
+                    if name[0] == "f":
+                        block[name] = packed[name]
+                    else:
+                        block[name] = np.zeros(packed["__ts"].shape,
+                                               np.int32)
+                        block[name][lanes[at], prow[at]] = v
+            _note_pack(self.app_name, len(lanes), packed)
+            work = {"data": data, "lanes": lanes, "prow": prow,
+                    "block": block, "present": present,
+                    "pre": self.join.carry}
+            self._step(work)
+            if self._fuser is not None:
+                # the rows and the tail ride the app's per-block slab
+                work["fuse"] = self._fuser.register(self, work["outs"])
+            else:
+                for o in work["outs"]:
+                    o.copy_to_host_async()
+        self._submit(work)
+
+    def _step(self, work) -> None:
+        """Launch the step over ``work``'s block from the engine's carry
+        and leave the un-read result on it."""
+        rows, tail, cap = self.join.process_block(work["block"],
+                                                  work["present"])
+        T = work["block"]["ts"].shape[1]
+        work.update(outs=[rows, tail], cap=cap, fuse=None,
+                    shape=(T, self.join.n_slots, self.join.n_lanes))
+
+    # ------------------------------------------------------------ retire
+
+    def _retire(self, work) -> None:
+        with _ledger().span("device", "retire"):
+            if work["fuse"] is not None:
+                rows, tail = work["fuse"].fetch()
+            else:
+                with _ledger().span("egress_d2h"):
+                    rows, tail = (np.asarray(o) for o in work["outs"])
+                self.join.step_for(work["present"]).entry.d2h_bytes += \
+                    rows.nbytes + tail.nbytes
+        if not (tail[1] or tail[0] > work["cap"]):
+            self._deliver(work, rows, tail)
+            return
+        # a lane's ring was full of live entries, or the block's rows
+        # outgrew the egress buffer: this block and every later one in
+        # flight ran on from a result that is not whole.  Go back to the
+        # carry this block started from, widen, and replay them in order
+        pending = [work, *self._inflight]
+        self._inflight.clear()
+        for w in pending:
+            while tail is None or tail[1] or tail[0] > w["cap"]:
+                if tail is not None:
+                    self.join.carry = w["pre"]
+                    grown = self.join.widen(tail, w["shape"][0])
+                    _ledger().note_join(self.app_name, (0,) * 5, grown)
+                w["pre"] = self.join.carry
+                self._step(w)
+                rows, tail = (np.asarray(o) for o in w["outs"])
+            self._deliver(w, rows, tail)
+            tail = None
+
+    def _deliver(self, work, rows: np.ndarray, tail: np.ndarray) -> None:
+        """Hand the ledger the counters the tail carries, decode the
+        block's rows, put them in arrival order and emit them."""
+        from ..core.event import CURRENT, dtype_for
+        from ..core.join import joined_chunk
+        delta = self.join.count_delta(tail)
+        if delta.any():
+            _ledger().note_join(self.app_name, delta)
+        count = int(tail[0])
+        if count == 0:
+            return
+        data, plan = work["data"], self.plan
+        T, _K, P = work["shape"]
+        side, lane, tick, seq, entry = self.join.decode(
+            rows[:count], work["shape"], work["present"])
+        event_at = np.zeros(P * T, np.int32)
+        event_at[work["lanes"] * T + work["prow"]] = np.arange(len(data))
+        ev = event_at[lane * T + tick]
+        # by probing event; a left event's rows before its right ones;
+        # the matched entries in their arrival order
+        order = np.lexsort((seq, side, ev))
+        side, ev = side[order], ev[order]
+        cols_of = []
+        for i, s in enumerate(plan.sides):
+            probing = side == i
+            other = plan.sides[1 - i]
+            cols = {}
+            for a in s.definition.attributes:
+                dt = dtype_for(a.type)
+                col = None
+                if not probing.all():       # rows this side's ring gave
+                    if a.name in entry.get(i, ()):
+                        col = entry[i][a.name][order]
+                    elif a.name == s.key:   # equal to the probing key
+                        col = data.columns[other.key][ev]
+                        if dt is not object:
+                            col = col.astype(dt)
+                    else:                   # read by nobody
+                        col = np.zeros(count, dt) if dt is not object \
+                            else np.full(count, None, object)
+                if probing.any():
+                    mine = data.columns[a.name][ev]
+                    if col is None:
+                        col = mine
+                    else:
+                        if col.dtype != object:     # strings stay objects
+                            col = col.astype(mine.dtype)
+                        col[probing] = mine[probing]
+                cols[a.name] = col
+            cols_of.append(cols)
+        with _ledger().span(None, "match.scatter", block=work.get("seq"),
+                            app=self.app_name):
+            self.head.process(joined_chunk(
+                plan.sides, self.union_def, cols_of,
+                np.asarray(data.timestamps)[ev], CURRENT))
+
+    # --------------------------------------------------------- lifecycle
+
+    def start(self) -> None:
+        pass
+
+    def shutdown(self) -> None:
+        self.flush()
+
+    def current_state(self) -> dict:
+        with self.qr.lock:
+            self.flush()
+            return {"join": self.join.current_state(),
+                    "key_lanes": dict(self.key_lanes)}
+
+    def restore_state(self, state: dict) -> None:
+        with self.qr.lock:
+            self.flush()
+            self.join.restore_state(state["join"])
+            self.key_lanes = KeyLanes(state["key_lanes"])
+
+
+@persistent_schema(
     "keyed-grouped-agg", version=1, schema=Keyed("cga"))
 class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
     """Aggregation query on the grouped/running device kernel
@@ -1892,6 +2199,19 @@ def plan_state_runtime(query_runtime, sis: StateInputStream, factory):
     would wire an unpartitioned runtime.)"""
     return _plan(query_runtime,
                  lambda: DevicePatternRuntime(query_runtime, sis, factory))
+
+
+def plan_join_runtime(query_runtime, jis, factory):
+    """The keyed device runtime for a join it takes, else the reason
+    core/join.py keeps the query.  No engine mode raises here: the host
+    join runtime's mask probe is a device path of its own, and 'device'
+    mode is held to that one as before."""
+    if engine_mode(query_runtime.app_runtime.app) == "host":
+        return None, "device keyed join: engine mode 'host'"
+    try:
+        return DeviceKeyedJoinRuntime(query_runtime, jis, factory), None
+    except SiddhiAppCreationError as e:
+        return None, str(e)
 
 
 def plan_single_runtime(query_runtime, sis, factory):

@@ -57,6 +57,12 @@ KIND_APPS = {
         "R#window.length(8) as b on a.sym == b.sym "
         "select a.sym as sym, a.price as price, b.qty as qty "
         "insert into Out;", {}),
+    "join.keyed_step": (
+        "define stream R (sym string, qty long);\n"
+        "@info(name='q') from S#window.time(10 sec) as a join "
+        "R#window.time(10 sec) as b on a.sym == b.sym "
+        "select a.sym as sym, a.price as price, b.qty as qty "
+        "insert into Out;", {}),
 }
 
 
