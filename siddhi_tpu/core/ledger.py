@@ -194,6 +194,13 @@ COUNT_COUNTERS = ("count_armed_total", "count_appended_total",
 #: power of two): their ratio is how full the blocks are
 PACK_COUNTERS = ("pack_events_total", "pack_cells_total")
 
+#: the per-app counters of those blocks' planes, in the order of a
+#: ``_planes`` row: ``[P, T]`` planes placed in blocks handed to a step,
+#: per query and chunk; of those, the planes that another query of the
+#: partition had already made of the chunk (``ops/nfa.SharedPlanes``:
+#: scattered once, and uploaded once by the gang, plan/xtenant.py)
+PLANE_COUNTERS = ("pack_planes_total", "pack_planes_shared_total")
+
 #: the per-app counters of joins, in the order of a ``_join`` row.  The
 #: first five are ops/keyed_join.JOIN_CTR, counted on the device by the
 #: keyed join step and read off the egress tail as the count unit's
@@ -579,6 +586,8 @@ class LatencyLedger:
         # app -> COUNT_COUNTERS row and app -> PACK_COUNTERS row, likewise
         self._count: Dict[str, list] = {}
         self._pack: Dict[str, list] = {}
+        # app -> PLANE_COUNTERS row, likewise
+        self._planes: Dict[str, list] = {}
         # app -> JOIN_COUNTERS row, likewise
         self._join: Dict[str, list] = {}
         # app -> [device launches, ingest blocks]: the runtimes hand the
@@ -707,6 +716,11 @@ class LatencyLedger:
         """One dense block packed: its events and its P x T cells."""
         self._add(self._pack, app, (events, cells))
 
+    def note_planes(self, app: str, planes: int, shared: int) -> None:
+        """One dense block's planes, and those of them that were made
+        already."""
+        self._add(self._planes, app, (planes, shared))
+
     def note_join(self, app: str, deltas, grown: int = 0) -> None:
         """Add a retired block's JOIN_CTR deltas and ring doublings to
         an app's JOIN_COUNTERS (the keyed device join runtime)."""
@@ -734,6 +748,7 @@ class LatencyLedger:
                 (KEY_INTERN_COUNTERS, self._keyint),
                 (COUNT_COUNTERS, self._count),
                 (PACK_COUNTERS, self._pack),
+                (PLANE_COUNTERS, self._planes),
                 (JOIN_COUNTERS, self._join))
 
     # ------------------------------------------------------ block fold
@@ -901,7 +916,7 @@ class LatencyLedger:
         }
         apps = sorted({a for (a, _s) in self._hist}.union(
             self._absent, self._keyfac, self._keyint, self._count,
-            self._pack, self._join)) \
+            self._pack, self._planes, self._join)) \
             if app is None else [app]
         per_app = {}
         for a in apps:

@@ -576,6 +576,11 @@ LEDGER_TYPES = [
     ("siddhi_pack_cells_total",
      "counter", "P x T cells of those blocks (lanes times the depth of "
      "the fullest key, rounded up to a power of two)"),
+    ("siddhi_pack_planes_total",
+     "counter", "[P, T] planes of those blocks, per query and chunk"),
+    ("siddhi_pack_planes_shared_total",
+     "counter", "Of those, planes another query of the partition had "
+     "already made of the chunk: scattered once, uploaded once"),
     ("siddhi_join_probes_total",
      "counter", "Events of a keyed device join that probed the other "
      "side's window ring"),

@@ -43,6 +43,7 @@ is asserted in tests/test_tpu_nfa.py and tests/test_planner.py.
 from __future__ import annotations
 
 import os
+from functools import partial
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -1429,6 +1430,84 @@ def make_bank_carry(spec: NfaSpec, n_patterns: int,
             for k, v in c.items()}
 
 
+def _same(a: np.ndarray, b: np.ndarray) -> bool:
+    """``b`` is the array ``a``, or holds what ``a`` holds."""
+    return a is b or (a.dtype == b.dtype and a.shape == b.shape
+                      and np.array_equal(a, b))
+
+
+class _Layout:
+    """Where each event of a flat batch lies in its dense ``[P, T]``
+    block, and, where they are kept, the planes scattered there so far:
+    ``planes[name]`` lists (what the plane was scattered from, the
+    plane)."""
+
+    __slots__ = ("lanes", "pad_t_pow2", "shape", "row", "flat", "planes")
+
+    def __init__(self, partition_ids: np.ndarray, n_partitions: int,
+                 pad_t_pow2: bool, keep: bool):
+        from ..native_ext import assign_rows
+        self.lanes = partition_ids
+        self.pad_t_pow2 = pad_t_pow2
+        pids = np.ascontiguousarray(partition_ids, np.int32)
+        self.row, _counts, T = assign_rows(pids, n_partitions)
+        if pad_t_pow2:
+            T = 1 << (T - 1).bit_length()
+        self.shape = (n_partitions, T)
+        # each event's cell of the block laid flat: one index array for
+        # every plane, half the cost of indexing lane and row apart
+        self.flat = pids.astype(np.intp) * T + self.row
+        self.planes: Optional[Dict] = {} if keep else None
+
+    def plane(self, name, source: np.ndarray, dtype, values: Callable):
+        """The plane ``name`` of ``values(source)``, one per event -> (the
+        plane, whether it was there: made from ``source`` or from an
+        array equal to it).  A kept plane is read-only: the next caller
+        with an equal source gets the same object."""
+        made = () if self.planes is None else \
+            self.planes.setdefault(name, [])
+        for src, plane in made:
+            if _same(src, source):
+                return plane, True
+        plane = np.zeros(self.shape, dtype)
+        plane.reshape(-1)[self.flat] = values(source)
+        if self.planes is not None:
+            plane.flags.writeable = False
+            made.append((source, plane))
+        return plane, False
+
+
+def _f32(col: np.ndarray) -> np.ndarray:
+    return np.asarray(col, np.float32)
+
+
+def _ts_offsets(base_ts: int, timestamps: np.ndarray) -> np.ndarray:
+    return (np.asarray(timestamps, np.int64) - base_ts).astype(np.int32)
+
+
+def _present(_lanes: np.ndarray) -> bool:
+    return True
+
+
+def _pack(lay: _Layout, columns: Dict[str, np.ndarray],
+          timestamps: np.ndarray, stream_codes: np.ndarray,
+          base_ts: int) -> Tuple[Dict[str, np.ndarray], int]:
+    """The block of one flat batch in ``lay`` -> (block, how many of its
+    planes were there already)."""
+    planes = [(name, ("col", name), col, np.float32, _f32)
+              for name, col in columns.items()]
+    planes += [("__ts", ("__ts", base_ts), timestamps, np.int32,
+                partial(_ts_offsets, base_ts)),
+               ("__stream", "__stream", stream_codes, np.int32, np.asarray),
+               ("__valid", "__valid", lay.lanes, bool, _present)]
+    block: Dict[str, np.ndarray] = {}
+    found = 0
+    for key, name, source, dtype, values in planes:
+        block[key], was = lay.plane(name, source, dtype, values)
+        found += was
+    return block, found
+
+
 def pack_blocks(partition_ids: np.ndarray, columns: Dict[str, np.ndarray],
                 timestamps: np.ndarray, stream_codes: np.ndarray,
                 n_partitions: int, base_ts: int = 0,
@@ -1439,32 +1518,63 @@ def pack_blocks(partition_ids: np.ndarray, columns: Dict[str, np.ndarray],
     shapes).  return_rows additionally yields each input event's row index
     within its lane (for per-event output decode).
 
+    The block is the caller's own: every plane made here, writeable.
+    Several callers that pack one batch (the pattern queries of a
+    partition) go through the batch's :class:`SharedPlanes` instead.
+
     This is the columnar replacement for the reference's per-key junction
     routing (partition/PartitionStreamReceiver.java:83-153)."""
-    from ..native_ext import assign_rows
-    n = len(partition_ids)
-    partition_ids = np.ascontiguousarray(partition_ids, np.int32)
-    row, _counts, T = assign_rows(partition_ids, n_partitions)
-    if pad_t_pow2:
-        T = 1 << (T - 1).bit_length()
-    block: Dict[str, np.ndarray] = {}
-    for name, col in columns.items():
-        out = np.zeros((n_partitions, T), np.float32)
-        out[partition_ids, row] = col.astype(np.float32)
-        block[name] = out
-    ts = np.zeros((n_partitions, T), np.int32)
-    ts[partition_ids, row] = (np.asarray(timestamps, np.int64) -
-                              base_ts).astype(np.int32)
-    block["__ts"] = ts
-    sc = np.zeros((n_partitions, T), np.int32)
-    sc[partition_ids, row] = stream_codes
-    block["__stream"] = sc
-    valid = np.zeros((n_partitions, T), bool)
-    valid[partition_ids, row] = True
-    block["__valid"] = valid
+    lay = _Layout(np.asarray(partition_ids), n_partitions, pad_t_pow2,
+                  keep=False)
+    block, _found = _pack(lay, columns, np.asarray(timestamps),
+                          np.asarray(stream_codes), base_ts)
     if return_rows:
-        return block, row
+        return block, lay.row
     return block
+
+
+class SharedPlanes:
+    """The dense planes made of one flat event batch, kept with the
+    batch (``EventChunk.factors``, core/keyfactor.py) for whoever packs
+    it again: the pattern queries of a partition receive the same chunk,
+    and their blocks are equal plane for plane as long as they saw the
+    same chunks in the same order.  That is observed, never assumed.
+    :meth:`pack` is ``pack_blocks`` with a memory: the row assignment and
+    ``T`` are made once per lane array (and ``n_partitions``,
+    ``pad_t_pow2``), a plane once per input, and a later caller whose
+    input is the same object or compares equal (the lanes and dictionary
+    codes of a query come from its own tables: a gather per query, equal
+    until a restore, a growth or a later start makes them differ) gets
+    the plane that is there; ``__ts`` is one per ``base_ts``.  A caller
+    whose input differs makes its own plane, of that input only: a query
+    that reads one more column adds that plane, lanes that differ give a
+    block of the caller's own.  Kept planes are read-only, and every
+    caller gets a dict of its own over them."""
+
+    __slots__ = ("_layouts",)
+
+    def __init__(self):
+        self._layouts: List[_Layout] = []
+
+    def pack(self, partition_ids: np.ndarray,
+             columns: Dict[str, np.ndarray], timestamps: np.ndarray,
+             stream_codes: np.ndarray, n_partitions: int,
+             base_ts: int = 0, pad_t_pow2: bool = False
+             ) -> Tuple[Dict[str, np.ndarray], int]:
+        """-> (the block, how many of its planes an earlier caller had
+        made)."""
+        partition_ids = np.asarray(partition_ids)
+        for lay in self._layouts:
+            if lay.shape[0] == n_partitions and \
+                    lay.pad_t_pow2 == pad_t_pow2 and \
+                    _same(lay.lanes, partition_ids):
+                break
+        else:
+            lay = _Layout(partition_ids, n_partitions, pad_t_pow2,
+                          keep=True)
+            self._layouts.append(lay)
+        return _pack(lay, columns, np.asarray(timestamps),
+                     np.asarray(stream_codes), base_ts)
 
 
 def make_timer_block(n_partitions: int, ts_offset: int,

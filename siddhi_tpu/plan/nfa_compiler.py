@@ -33,9 +33,8 @@ import numpy as np
 from ..compiler import SiddhiCompiler
 from ..ops.compact import compact_indices
 from ..ops.nfa import (ABSENT_CTR, CLOCK_KEY, COUNT_CTR, COUNT_INF, NfaSpec,
-                       UnitSpec, build_block_step,
-                       make_carry, make_timer_block, pack_blocks,
-                       resolve_batch_b)
+                       SharedPlanes, UnitSpec, build_block_step,
+                       make_carry, make_timer_block, resolve_batch_b)
 from ..query_api import (AbsentStreamStateElement, CountStateElement,
                          EveryStateElement, Filter, LogicalOp,
                          LogicalStateElement, NextStateElement, Query,
@@ -2200,12 +2199,20 @@ class CompiledPatternNFA:
                         stream_names: Optional[np.ndarray] = None,
                         stream_codes: Optional[np.ndarray] = None,
                         pad_t_pow2: bool = False,
-                        factor_of=None) -> dict:
+                        factor_of=None,
+                        shared: Optional[SharedPlanes] = None) -> dict:
         """Pack + dispatch one flat event batch and start its egress D2H
         transfer without blocking; returns a handle for retire_events.
         ``factor_of(name)`` gives an encoded string column's factor
         (core/keyfactor.py) where the caller holds the chunk it may
         already be on; None for a column that has to go event by event.
+        ``shared`` holds the planes other automata have made of this
+        batch (ops/nfa.SharedPlanes, kept on the caller's chunk): the
+        block takes those that were made from what this automaton would
+        scatter too (its lanes, its codes, its ``base_ts``) and makes the
+        rest; the handle's ``planes`` is (planes of the block, those of
+        them that were there).  The block itself is this handle's own
+        dict and complete, whoever made its arrays.
         The pipelined engine path (plan/planner.py) keeps a few handles in
         flight so the egress read of chunk N overlaps chunk
         N+1's dispatch; the handle carries everything needed to replay the
@@ -2255,10 +2262,10 @@ class CompiledPatternNFA:
                             factor_values(np.asarray(c), strings_only=True))
                 cols[a] = np.asarray(c)
         with led.span("device", "pack"):
-            block = pack_blocks(np.asarray(partition_ids), cols,
-                                np.asarray(timestamps), codes,
-                                self.n_partitions, base_ts=self.base_ts,
-                                pad_t_pow2=pad_t_pow2)
+            block, found = (shared or SharedPlanes()).pack(
+                partition_ids, cols, timestamps, codes, self.n_partitions,
+                base_ts=self.base_ts, pad_t_pow2=pad_t_pow2)
+        planes = (len(block), found)    # the clock below is no [P, T] plane
         if ts_range is not None:
             self._stamp_clock(block, ts_range[1])
         if bucket is not None:
@@ -2266,12 +2273,14 @@ class CompiledPatternNFA:
             # block into the tenant's bucket — the gang step runs it
             # with every co-tenant's pending block as ONE device launch;
             # any read of the handle forces the flush
-            return bucket.submit(self, block, ts_range)
-        pre_carry, pre_base = self.carry, self.base_ts
-        outs = self.process_block(block)
-        h = self.egress_dispatch(outs)
-        h.update(block=block, ts_range=ts_range, pre_carry=pre_carry,
-                 pre_base=pre_base, base_ts=self.base_ts)
+            h = bucket.submit(self, block, ts_range)
+        else:
+            pre_carry, pre_base = self.carry, self.base_ts
+            outs = self.process_block(block)
+            h = self.egress_dispatch(outs)
+            h.update(block=block, ts_range=ts_range, pre_carry=pre_carry,
+                     pre_base=pre_base, base_ts=self.base_ts)
+        h["planes"] = planes
         return h
 
     def replay_block(self, h: dict) -> dict:

@@ -36,9 +36,10 @@ from ..query_api.query import OutputEventsFor
 from ..utils.errors import (SiddhiAppCreationError,
                             SiddhiAppRuntimeException)
 from ..core.keyfactor import (IdTable, KeyIds, KeyInterner, column_factor,
-                              intern_values)
+                              intern_values, memoized)
 from ..core.ledger import ABSENT_COUNTERS, ON_FLUSH, ledger as _ledger
 from ..core.stateschema import Keyed, persistent_schema
+from ..ops.nfa import SharedPlanes
 from ..parallel.shards import build_shards, resolve_shards, split_rows
 from .join_compiler import CompiledKeyedJoin, plan_keyed_join
 from .nfa_compiler import CompiledPatternNFA
@@ -227,16 +228,23 @@ def _factored_keys(executor, data, app_name: str):
     if not reused:
         led.note_key_intern(app_name, len(data), kf.hits)
     if kf.keep is not None:
-        data = data.mask(kf.keep)
+        # one chunk of the keyed events for all the partition's queries:
+        # what they factor and pack of it is shared as the chunk's is
+        data = memoized(data, ("keyed", executor),
+                        partial(data.mask, kf.keep))[0]
     return data, kf
 
 
-def _note_pack(app_name: str, events: int, block) -> None:
+def _note_pack(app_name: str, events: int, block, planes=None) -> None:
     """One dense block packed (``ops/nfa.pack_blocks``): its events and
-    its P x T cells go to the app's lane-occupancy counters.  A
-    statically dead automaton packs nothing."""
+    its P x T cells go to the app's lane-occupancy counters, and its
+    planes with those of them that another query of the partition had
+    made already (``planes``, a pattern handle's; a block packed in one
+    piece shares none) to its plane counters.  A statically dead
+    automaton packs nothing."""
     if block is not None:
         _ledger().note_pack(app_name, events, block["__valid"].size)
+        _ledger().note_planes(app_name, *(planes or (len(block), 0)))
 
 
 def _check_shard_count(shards, snap_shards) -> None:
@@ -548,7 +556,7 @@ class DevicePatternRuntime:
                 h = sh.engine.dispatch_events(pids, sub_cols, ts_arr[rows],
                                               stream_codes=codes,
                                               pad_t_pow2=True)
-            _note_pack(self.app_name, len(rows), h["block"])
+            _note_pack(self.app_name, len(rows), h["block"], h.get("planes"))
             sh.inflight.append(h)
             sh.events += len(rows)
             sh.dispatches += 1
@@ -646,8 +654,9 @@ class DevicePatternRuntime:
         with led.span("device"):
             h = self.nfa.dispatch_events(
                 pids, cols, ts_arr, stream_codes=codes, pad_t_pow2=True,
-                factor_of=partial(self._column_factor, data, keys))
-        _note_pack(self.app_name, n, h["block"])
+                factor_of=partial(self._column_factor, data, keys),
+                shared=memoized(data, "planes", SharedPlanes)[0])
+        _note_pack(self.app_name, n, h["block"], h.get("planes"))
         stamp_submit(h)
         self._inflight.append(h)
         # with depth 0 every chunk retires here (synchronous: matches

@@ -30,13 +30,28 @@ the legacy path pays.  With depth ≥ 1 (all-@Async junctions or
 ``@app:pipeline('D')``) blocks from different tenants accumulate and a
 repeat submission by any tenant — or any read — flushes the gang.
 
+The gang's arguments are the tenants' carries, one each, and the
+distinct arrays among their pending blocks, each once
+(``_distinct_planes``, by identity): ``gang(carries, planes)`` puts
+tenant i's block together from ``planes`` inside the trace.  Which
+tenant reads which argument is static, so it is part of the gang's
+cache key beside the tenants' signatures, and the number of plane
+arguments is a dim of its registry entry.  The pattern queries of one
+partition hold one block of a chunk (ops/nfa.SharedPlanes: equal inputs
+give the same read-only arrays), so four of them upload six planes where
+they uploaded 24; tenants of different apps, or queries whose lanes or
+codes differ, hold arrays of their own and get the program they always
+got.  Each tenant's handle keeps its own complete block (a dict of its
+own), whoever else reads the same arrays.
+
 Grow-and-replay stays correct at bucket granularity: tenant sub-steps
-inside the gang are mutually independent (separate carries, separate
-blocks), so one tenant's slot overflow never corrupts co-tenants.  The
-planner rewinds ONLY the overflowing tenant to its pre-gang carry
-(handles carry per-tenant snapshots, the gang never donates), grows its
-ring and replays through its individual step; the slot growth re-keys
-it into a new bucket while co-tenants' gang results stand.
+inside the gang are mutually independent (separate carries; blocks are
+only ever read), so one tenant's slot overflow never corrupts
+co-tenants.  The planner rewinds ONLY the overflowing tenant to its
+pre-gang carry (handles carry per-tenant snapshots, the gang never
+donates), grows its ring and replays through its individual step; the
+slot growth re-keys it into a new bucket while co-tenants' gang results
+stand.
 
 ``SIDDHI_TPU_XTENANT=0`` kills the whole layer (per-app dispatch, the
 pre-round-14 behavior); ``SIDDHI_TPU_XTENANT_BUCKET`` bounds tenants
@@ -95,12 +110,34 @@ def _gang_sig(nfa) -> Tuple:
             int(getattr(nfa, "_egress_cap", 1024)))
 
 
-def _build_gang(nfas: List[Any], trigger: str = "build"):
+def _distinct_planes(blocks: List[Dict]) -> Tuple[List[Any], Tuple]:
+    """The distinct arrays among the tenants' blocks, by identity, and
+    per tenant which of them each of its planes is:
+    ((name, index), ...) in the order of the names."""
+    planes: List[Any] = []
+    index: Dict[int, int] = {}
+    reads = []
+    for block in blocks:
+        mine = []
+        for name in sorted(block):
+            arr = block[name]
+            at = index.get(id(arr))
+            if at is None:
+                at = index[id(arr)] = len(planes)
+                planes.append(arr)
+            mine.append((name, at))
+        reads.append(tuple(mine))
+    return planes, tuple(reads)
+
+
+def _build_gang(nfas: List[Any], reads: Tuple, trigger: str = "build"):
     """ONE jitted function stepping every tenant's block against its own
     carry and packing its egress — a single XLA executable, a single
     device launch per bucket flush.  Tenants' condition programs are
     heterogeneous (different closures), so this is a trace-time unroll,
-    not a vmap; the bucket cap bounds the unroll width."""
+    not a vmap; the bucket cap bounds the unroll width.  ``reads`` is
+    static (``_distinct_planes``): tenant i's block is put together from
+    the gang's plane arguments inside the trace."""
     from ..ops.nfa import build_block_step
     from .shapes import shape_registry
     steps = [build_block_step(n.spec) for n in nfas]
@@ -109,10 +146,11 @@ def _build_gang(nfas: List[Any], trigger: str = "build"):
     absent = [n.has_absent for n in nfas]
     telem = [bool(n.spec.telemetry) for n in nfas]
 
-    def gang(carries, blocks):
+    def gang(carries, planes):
         out = []
         for i in range(len(steps)):
-            nc, (mask, cp, ts, enter, seq) = steps[i](carries[i], blocks[i])
+            block = {name: planes[at] for name, at in reads[i]}
+            nc, (mask, cp, ts, enter, seq) = steps[i](carries[i], block)
             dl_st = nc["slot_state"] if absent[i] else None
             dl = nc.get("deadline") if absent[i] else None
             ctr = nc.get("absent_ctr") if absent[i] else None
@@ -123,14 +161,16 @@ def _build_gang(nfas: List[Any], trigger: str = "build"):
         return out
 
     # shape-class dims: the bucket's shared shape key (every co-ganged
-    # tenant matches it — see _shape_key) plus the gang's unroll width
-    # and per-tenant egress caps, which are baked into the executable
+    # tenant matches it — see _shape_key) plus the gang's unroll width,
+    # per-tenant egress caps and the number of its plane arguments,
+    # which are baked into the executable
     n0 = nfas[0]
     dims = {"S": len(n0.spec.units), "K": n0.spec.n_slots,
             "P": n0.n_partitions, "B": max(n0.batch_b, 1),
             "R": max(n0.spec.n_rows, 1), "C": max(n0.spec.n_caps, 1),
             "telem": bool(n0.spec.telemetry), "n": len(nfas),
-            "caps": tuple(caps)}
+            "caps": tuple(caps),
+            "planes": 1 + max(at for r in reads for _name, at in r)}
     return shape_registry().jit("nfa.xstep", dims, gang,
                                 trigger=trigger), caps
 
@@ -209,20 +249,25 @@ class TenantBucket:
         self.pending = []
         self._pending_ids = set()
         nfas = [e[0] for e in entries]
-        sig = tuple(_gang_sig(n) for n in nfas)
+        # an array that several tenants' blocks hold (the queries of one
+        # partition: ops/nfa.SharedPlanes) is one argument, uploaded once
+        planes, reads = _distinct_planes([e[1] for e in entries])
+        sig = (tuple(_gang_sig(n) for n in nfas), reads)
         cached = self._gangs.get(sig)
         if cached is None:
-            # a second gang build on a live bucket means membership or a
-            # tenant's shape re-keyed — that is a rebucket, not a build
+            # a second gang build on a live bucket means membership, a
+            # tenant's shape or what its tenants share re-keyed — that
+            # is a rebucket, not a build
             cached = self._gangs[sig] = _build_gang(
-                nfas, trigger="build" if not self._gangs else "rebucket")
+                nfas, reads,
+                trigger="build" if not self._gangs else "rebucket")
         gang, caps = cached
         # per-tenant pre-gang snapshots: the gang never donates, so the
         # planner's grow-and-replay can rewind ONE tenant without
         # re-stepping (or corrupting) its co-tenants
         pres = [(n.carry, n.base_ts) for n in nfas]
         t_issue = time.perf_counter_ns()
-        out = gang([n.carry for n in nfas], [e[1] for e in entries])
+        out = gang([n.carry for n in nfas], planes)
         gang.note_ticks(max(e[1]["__ts"].shape[-1] for e in entries))
         self.flush_total += 1
         for (nfa, block, h), (nc, buf, outs, tele), (pc, pb), cap in \

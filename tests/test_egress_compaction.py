@@ -199,12 +199,13 @@ def test_pack_lowers_without_a_scatter(plain):
 def test_a_one_tenant_gang_lowers_without_a_scatter(config):
     """The gang as `plan/xtenant._build_gang` builds it (the registry's
     jit of `gang` over one tenant's step and pack)."""
-    from siddhi_tpu.plan.xtenant import _build_gang
+    from siddhi_tpu.plan.xtenant import _build_gang, _distinct_planes
     nfa = _nfa(config)
-    gang, caps = _build_gang([nfa], trigger="test")
+    planes, reads = _distinct_planes([_block(nfa, 16, 8)])
+    gang, caps = _build_gang([nfa], reads, trigger="test")
     assert caps == [1024]
     carry = make_carry(nfa.spec, 16)
-    text = gang.lower([carry], [_block(nfa, 16, 8)]).as_text()
+    text = gang.lower([carry], planes).as_text()
     assert "scatter" not in text
 
 

@@ -270,7 +270,8 @@ def test_the_probe_answers_known_keys_and_not_new_ones():
 
 def test_a_pattern_outside_a_partition_asks_for_no_factor():
     """P = 1: one lane, no key, no ask; its string column is still
-    factored once per chunk for the two queries that encode it."""
+    factored once per chunk for the two queries that encode it, and
+    their block's planes are kept beside it (ops/nfa.SharedPlanes)."""
     name = fresh("kf")
     text = (f"@app:name('{name}') @app:playback\n" + STREAM +
             "".join(f"@info(name='q{q}')\n" + PATTERN.format(thr=90, q=q)
@@ -282,7 +283,7 @@ def test_a_pattern_outside_a_partition_asks_for_no_factor():
     s.rt.flush()
     assert s.counters() == (0, 0)
     assert len(s.rows) > 0
-    assert [set(c.factors) for c in seen] == [{("col", "sym")}]
+    assert [set(c.factors) for c in seen] == [{("col", "sym"), "planes"}]
     s.rt.shutdown()
 
 

@@ -261,6 +261,48 @@ def test_h2d_bytes_counts_numpy_leaves_and_not_device_arrays():
     assert shape_registry().kernels()["test.h2d"]["h2d_bytes"] == 192
 
 
+def test_a_gang_of_equal_tenants_uploads_one_block(monkeypatch):
+    """Four pattern queries over one stream hold one block of a chunk
+    (ops/nfa.SharedPlanes), and the gang passes each distinct array
+    once: ``h2d_bytes`` of `nfa.xstep` for a gang call of the four is
+    one block's bytes, and a second flush of the same tenants sharing
+    the same planes compiles nothing."""
+    from siddhi_tpu.plan import xtenant
+    monkeypatch.setenv("SIDDHI_TPU_MESH", "off")
+    flushes = []
+    step = xtenant.TenantBucket._gang_step
+
+    def spy(self, entries):
+        flushes.append([block for _nfa, block, _h in entries])
+        return step(self, entries)
+    monkeypatch.setattr(xtenant.TenantBucket, "_gang_step", spy)
+    reg = shape_registry()
+    body = "".join(
+        PATTERN.replace("'q'", f"'q{q}'").replace("vol == 0",
+                                                  f"vol == 0 and price > {q}")
+        .replace("insert into Out", f"insert into Out{q}") for q in range(4))
+    rt = SiddhiManager().create_siddhi_app_runtime(
+        "@app:playback\n@Async(buffer.size='64', batch.size.max='4096')\n"
+        + S + body)
+    rows = []
+    for q in range(4):
+        rt.add_callback(f"Out{q}", StreamCallback(rows.extend))
+    rt.start()
+    books = []
+    for i in range(3):
+        _send(rt, i)
+        rt.flush()
+        books.append(dict(reg.kernels()["nfa.xstep"]))
+    rt.shutdown()
+    assert rows and [len(f) for f in flushes] == [4, 4, 4]
+    for blocks, before, after in zip(flushes[1:], books, books[1:]):
+        one = sum(plane.nbytes for plane in blocks[0].values())
+        assert after["calls"] - before["calls"] == 1
+        assert after["h2d_bytes"] - before["h2d_bytes"] == one
+        assert sum(p.nbytes for b in blocks for p in b.values()) == 4 * one
+        assert after["compiles"] == before["compiles"]
+
+
 def test_retire_books_the_bytes_it_read_back(monkeypatch):
     """A fused slab's read is booked once, under the kind of the eager
     concat that made it (``other``); unfused, the same buffers' bytes
