@@ -14,8 +14,6 @@ directly (strings are dictionary-encoded before shipping to device).
 """
 from __future__ import annotations
 
-import time
-
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -303,9 +301,7 @@ class LazyEvents:
 
     def materialize(self) -> List[Event]:
         if self._events is None:
-            t0 = time.perf_counter_ns()
             self._events = self.chunk.to_events()
-            _RIM.rim_ns += time.perf_counter_ns() - t0
         return self._events
 
     def __len__(self) -> int:

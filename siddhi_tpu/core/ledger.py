@@ -1,9 +1,8 @@
 """Per-chunk latency ledger, event-time lag watermarks and the SLO engine.
 
 One end-to-end latency number carries no stage attribution.  This module
-generalizes the ``rim_ns`` discipline — one always-on counter,
-kill-switchable — into a stage-bucketed wall-clock ledger over the whole
-ingest→publish path:
+keeps an always-on, kill-switchable, stage-bucketed wall-clock ledger
+over the whole ingest→publish path:
 
   ingress     input-handler admit (validate/encode, before junction.send)
   queue       @Async buffer wait (enqueue → worker dequeue; 0 when sync)
@@ -34,10 +33,15 @@ name and no stage credits no stage and only annotates
 keeps its elapsed time under its key).  When ``tracing='true'`` the same
 spans feed the operator's Chrome-trace exporter (core/tracing.py).
 The waits of a block in flight (:data:`WAITS`) are histograms only: they
-credit no stage.  Per-block deltas are folded into per-app/per-stage HDR
-histograms (PR 1 machinery) and a ``ledger`` waterfall row on each flight
-ring record — same global-accumulator-delta convention as the ring's
-existing rim/kernel ms split.
+credit no stage.  A recorded span (a profiler session or the exporter is
+on) of one of :data:`ONCPU_KEYS` also reads the thread's CPU clock just
+outside its two wall stamps: its exclusive CPU time, by the same rules
+as its wall time, goes beside its exclusive wall time to a pair of
+accumulators per key (:meth:`LatencyLedger.oncpu_seconds`).  Nobody
+recording, no span reads that clock.  Per-block deltas are folded into
+per-app/per-stage HDR histograms (PR 1 machinery) and a ``ledger``
+waterfall row on each flight ring record — same global-accumulator-delta
+convention as the ring's existing rim/kernel ms split.
 
 On top of the ledger:
 
@@ -50,8 +54,8 @@ On top of the ledger:
     breaching window's waterfall.
 
 Always-on with a ``SIDDHI_TPU_LEDGER=0`` kill switch; the env is re-read
-per call, so it can be toggled per block.  Like ``RimStats`` it is not
-gated on ``@app:statistics``.
+per call, so it can be toggled per block.  It is not gated on
+``@app:statistics``.
 """
 from __future__ import annotations
 
@@ -140,6 +144,16 @@ ANNOTATIONS = ("queue.idle", "deliver", "ingest.chunk", "egress_d2h.seal",
 #: issued at once; a gang tenant waits for its bucket's flush);
 #: ``wait.inflight`` submit -> the start of its retire
 WAITS = ("wait.defer", "wait.inflight")
+
+#: the declared keys whose recorded spans also read the thread's CPU
+#: clock, and keep their exclusive wall and CPU ns in a pair of
+#: accumulators (:meth:`LatencyLedger.oncpu_seconds`): a block's packing
+#: on the host and its launch.  The stage and keyed spans inside them
+#: read it too, to keep the pairs exclusive; no other span does unless
+#: the operator's exporter is on.  A read is a system call, which a
+#: sandboxed kernel can make cost microseconds: hence so few keys
+ONCPU_KEYS = ("dispatch.keys", "dispatch.lanes", "dispatch.cols",
+              "device.encode", "device.pack", "device.sync", "device.issue")
 
 #: the per-app retire counters, in the order of a ``_retires`` row.  The
 #: first two: was the result there when the retire began.  The last
@@ -362,6 +376,8 @@ class _SloState:
 
 
 _pcns = time.perf_counter_ns
+# the thread's CPU clock: read only by a recorded span
+_tns = time.thread_time_ns
 
 # jax.profiler.TraceAnnotation, bound on the first span after jax was
 # imported: this module stays importable (and the analyzer's CLI stays
@@ -425,7 +441,6 @@ def _sink_event(name: str, cat: str, t0: int, dur: Optional[int],
 # what span() hands out for an annotation nobody is recording
 _NO_SPAN = contextlib.nullcontext()
 
-
 class _Span:
     """Nest-aware span.  With a stage: on exit its EXCLUSIVE time
     (elapsed minus enclosed stage spans on this thread) is credited to
@@ -443,16 +458,25 @@ class _Span:
 
     The hot path runs cold-cache right next to device dispatches, where
     every attribute chase costs real time — a span's state is one plain
-    list ``[child_ns, named_ns, block, app, rec, t0, annotation]`` on a
-    thread-local stack, so the span object itself holds nothing of one
-    execution: the ledger hands out one object per (stage, name) over
-    and over, and makes a new one only where it is given a ``block``.
-    ``block`` and ``app`` default to the enclosing frame's.  Only the
-    outermost span asks the kill switch and ``rec`` (is a profiler
-    session or the operator's exporter recording?); those inside take
-    its answer."""
+    list ``[child_ns, named_ns, block, app, rec, t0, annotation,
+    child_cpu, named_cpu, c0]`` on a thread-local stack, so the span
+    object itself holds nothing of one execution: the ledger hands out
+    one object per (stage, name) over and over, and makes a new one only
+    where it is given a ``block``.  ``block`` and ``app`` default to the
+    enclosing frame's.  Only the outermost span asks the kill switch and
+    ``rec`` (is a profiler session or the operator's exporter
+    recording?); those inside take its answer.
 
-    __slots__ = ("ledger", "stage", "key", "annot", "block", "app")
+    A recorded span of :data:`ONCPU_KEYS` reads the thread's CPU clock
+    (``c0``) and sets ``rec`` to 2 for the spans inside it, whose stage
+    and keyed spans then read it too; with the exporter on every span
+    reads it.  The last three slots keep the CPU time exclusive by the
+    rules of the first two.  The reads lie just outside the wall stamps,
+    and what they and the span's books take goes to the parent's child
+    slot: no stage or key is charged for the second clock."""
+
+    __slots__ = ("ledger", "stage", "key", "annot", "block", "app",
+                 "clock")
 
     def __init__(self, ledger: "LatencyLedger", stage: Optional[str],
                  key: Optional[str], annot: str,
@@ -463,6 +487,10 @@ class _Span:
         self.annot = annot
         self.block = block
         self.app = app
+        # 2: a key with a pair; 1: a stage or a key, which reads the CPU
+        # clock inside a span that does; 0: an annotation
+        self.clock = 2 if key in ONCPU_KEYS else \
+            int(stage is not None or key is not None)
 
     def __enter__(self):
         tls = self.ledger._tls
@@ -471,21 +499,32 @@ class _Span:
         if st:
             top = st[-1]
             rec = top[4]
-            frame = [0, 0, top[2], top[3], rec, 0, None] if block is None \
-                else [0, 0, block, self.app, rec, 0, None]
+            frame = [0, 0, top[2], top[3], rec, 0, None, 0, 0, None] \
+                if block is None else \
+                [0, 0, block, self.app, rec, 0, None, 0, 0, None]
         else:
             if not ledger_enabled():
                 return self
             if st is None:
                 st = tls.stack = []
             rec = _SINK.enabled or _ta_session()
-            frame = [0, 0, block, self.app, rec, 0, None]
+            frame = [0, 0, block, self.app, rec, 0, None, 0, 0, None]
         st.append(frame)
-        if rec and _ta_session():
-            block = frame[2]
-            ta = frame[6] = _TA(self.annot) if block is None else \
-                _TA(self.annot, block=block)
-            ta.__enter__()
+        if rec:
+            if _ta_session():
+                block = frame[2]
+                ta = frame[6] = _TA(self.annot) if block is None else \
+                    _TA(self.annot, block=block)
+                ta.__enter__()
+            clock = self.clock
+            if clock == 2 or clock and rec == 2 or _SINK.enabled:
+                w = _pcns()
+                frame[4] = 2
+                frame[9] = _tns()
+                t0 = frame[5] = _pcns()
+                if len(st) > 1:
+                    st[-2][0] += t0 - w
+                return self
         frame[5] = _pcns()
         return self
 
@@ -494,7 +533,11 @@ class _Span:
         if not st:
             return False            # the ledger was off at the enter
         frame = st.pop()
-        elapsed = _pcns() - frame[5]
+        t1 = _pcns()
+        elapsed = t1 - frame[5]
+        c0 = frame[9]
+        if c0 is not None:
+            cpu = _tns() - c0
         led = self.ledger
         stage = self.stage
         key = self.key
@@ -515,6 +558,12 @@ class _Span:
                     named.append((app, key, ns))
                     if len(named) >= led._FOLD_NAMED_EVERY:
                         led._fold_named()
+            if c0 is not None:
+                if st:
+                    st[-1][7] += cpu
+                if self.clock == 2:
+                    led._rec_ns[key] += ns
+                    led._cpu_ns[key] += cpu - frame[7] - frame[8]
         else:
             # what the stage spans inside it took is their own stages'
             ns = elapsed - frame[0]
@@ -522,16 +571,25 @@ class _Span:
                 top = st[-1]
                 top[0] += frame[0]
                 top[1] += frame[1] if key is None else ns
+                if frame[4] == 2:
+                    top[7] += frame[7]
+                    top[8] += frame[8] if key is None else cpu - frame[7]
             if key is not None and ns > 0:
                 led._ns[key] += ns
+            if self.clock == 2 and c0 is not None:
+                led._rec_ns[key] += ns
+                led._cpu_ns[key] += cpu - frame[7]
         if frame[4]:
+            if c0 is not None and st:
+                st[-1][0] += _pcns() - t1
             if frame[6] is not None:
                 frame[6].__exit__(None, None, None)
             if _SINK.enabled:
-                block = frame[2]
+                args = {} if c0 is None else {"cpu_us": cpu / 1e3}
+                if frame[2] is not None:
+                    args["block"] = frame[2]
                 _sink_event(self.annot[7:], stage or "engine", frame[5],
-                            elapsed,
-                            None if block is None else {"block": block})
+                            elapsed, args or None)
         return False
 
 
@@ -561,6 +619,10 @@ class LatencyLedger:
         # the seven stages and, beside them, the declared sub-spans
         self._ns: Dict[str, int] = {s: 0 for s in STAGES + SPAN_NAMES}
         self._spans: Dict[str, int] = {s: 0 for s in STAGES}
+        # the exclusive wall ns and CPU ns of ONCPU_KEYS' recorded spans
+        # alone, so that their ratio compares like with like
+        self._rec_ns: Dict[str, int] = dict.fromkeys(ONCPU_KEYS, 0)
+        self._cpu_ns: Dict[str, int] = dict.fromkeys(ONCPU_KEYS, 0)
         self._lock = threading.Lock()
         self._tls = threading.local()
         # stage or (stage, name) -> the span object of every execution of
@@ -756,6 +818,13 @@ class LatencyLedger:
     def stage_ns(self) -> Dict[str, int]:
         return dict(self._ns)
 
+    def oncpu_seconds(self) -> Dict[str, Dict[str, float]]:
+        """Key of :data:`ONCPU_KEYS` -> ``{"wall": s, "cpu": s}``: the
+        exclusive wall and CPU time of its recorded spans (only while a
+        profiler session or the exporter records; 0 and 0 otherwise)."""
+        return {k: {"wall": self._rec_ns[k] / 1e9,
+                    "cpu": self._cpu_ns[k] / 1e9} for k in self._rec_ns}
+
     def _hist_for(self, app: str, stage: str) -> Histogram:
         h = self._hist.get((app, stage))
         if h is None:
@@ -912,6 +981,7 @@ class LatencyLedger:
             "enabled": ledger_enabled(),
             "stage_seconds": {s: self._ns[s] / 1e9 for s in STAGES},
             "span_seconds": {s: self._ns[s] / 1e9 for s in SPAN_NAMES},
+            "oncpu_seconds": self.oncpu_seconds(),
             "stage_spans": dict(self._spans),
         }
         apps = sorted({a for (a, _s) in self._hist}.union(
@@ -994,6 +1064,8 @@ class LatencyLedger:
         with self._lock:
             for s in self._ns:
                 self._ns[s] = 0
+            for s in ONCPU_KEYS:
+                self._rec_ns[s] = self._cpu_ns[s] = 0
             for s in STAGES:
                 self._spans[s] = 0
             self._hist.clear()

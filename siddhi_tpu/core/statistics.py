@@ -502,9 +502,6 @@ _TYPES = [
 RIM_TYPES = [
     ("siddhi_events_materialized_total",
      "counter", "Per-event Event objects built from columnar chunks"),
-    ("siddhi_host_rim_seconds_total",
-     "counter", "Host-rim wall time (ingress conversion + egress "
-     "delivery)"),
 ]
 
 #: Always-on per-stage latency ledger + lag watermarks + SLO engine

@@ -30,14 +30,12 @@ from .event import CURRENT, EXPIRED, Event, EventChunk, LazyEvents
 from .ledger import ledger as _ledger, ledger_enabled
 from .hotpath import hot_path
 from .lockwitness import maybe_wrap
-from .profiling import rim_stats
 from .threads import engine_thread_name
 
 log = logging.getLogger(__name__)
 
 FAULT_PREFIX = "!"
 
-_RIM = rim_stats()
 _LED = _ledger()
 
 # the junction worker's waits on its queue: with nothing in flight, how
@@ -830,10 +828,9 @@ class InputHandler:
     def _send_chunk(self, chunk: EventChunk, t0: int) -> None:
         """Shared chunk core: observe the clock, deliver, advance
         playback.  ``t0`` is the caller's entry stamp — everything up to
-        delivery is host-rim time (RimStats)."""
+        delivery is the ledger's ``ingress`` stage."""
         n = len(chunk)
         if n == 0:
-            _RIM.rim_ns += time.perf_counter_ns() - t0
             return
         qt = self.quota
         if qt is not None:
@@ -846,7 +843,6 @@ class InputHandler:
             if take < n:
                 self._quota_shed(n - take)
                 if take == 0:
-                    _RIM.rim_ns += time.perf_counter_ns() - t0
                     return
                 chunk = chunk.mask(np.arange(n) < take)
                 n = take
@@ -854,9 +850,8 @@ class InputHandler:
                 qt.breach = False     # budget recovered: episode closed
         mx = int(chunk.timestamps.max())
         self.app_ctx.timestamp_generator.observe_event_time(mx)
-        now = time.perf_counter_ns()
-        _RIM.rim_ns += now - t0
         if ledger_enabled():
+            now = time.perf_counter_ns()
             # ingress stage (validate/encode up to delivery) + the
             # event-time lag watermark: max admitted timestamp vs the
             # playback clock when replaying history, else the wall clock
