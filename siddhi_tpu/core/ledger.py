@@ -224,11 +224,15 @@ PLANE_COUNTERS = ("pack_planes_total", "pack_planes_shared_total")
 #: lane's ring was full of live entries: the block is replayed, nothing
 #: is dropped); events that reached a join query, on either path; of
 #: those, the ones that reached a keyed device join runtime (which
-#: places in its blocks the ones that pass a side's filter)
+#: places in its blocks the ones that pass a side's filter); last, the
+#: build side's events uploaded as compact rows, once per group of int
+#: planes and block, and the rows of those uploads, padding included
+#: (their ratio is how full the uploads are)
 JOIN_COUNTERS = ("join_probes_total", "join_probe_hits_total",
                  "join_rows_total", "join_inserted_total",
                  "join_expired_total", "join_ring_grown_total",
-                 "join_events_total", "join_device_events_total")
+                 "join_events_total", "join_device_events_total",
+                 "join_build_rows_total", "join_build_slots_total")
 
 
 # os.environ.get pays ~0.9 us per call (key encode + value decode);
@@ -786,12 +790,17 @@ class LatencyLedger:
     def note_join(self, app: str, deltas, grown: int = 0) -> None:
         """Add a retired block's JOIN_CTR deltas and ring doublings to
         an app's JOIN_COUNTERS (the keyed device join runtime)."""
-        self._add(self._join, app, (*deltas, grown, 0, 0))
+        self._add(self._join, app, (*deltas, grown, 0, 0, 0, 0))
 
     def note_join_events(self, app: str, events: int, device: int) -> None:
         """``events`` reached a join query; ``device`` of them a keyed
         device join runtime's."""
-        self._add(self._join, app, (0, 0, 0, 0, 0, 0, events, device))
+        self._add(self._join, app, (0,) * 6 + (events, device, 0, 0))
+
+    def note_join_build(self, app: str, rows: int, slots: int) -> None:
+        """One group of a block's build side uploaded as compact rows:
+        the events it holds, and its rows, padding included."""
+        self._add(self._join, app, (0,) * 8 + (rows, slots))
 
     def note_key_factor(self, app: str, reused: bool) -> None:
         """One keyed device ingest asked for its block's factored keys."""

@@ -597,6 +597,11 @@ LEDGER_TYPES = [
      "counter", "Events that reached a join query, on either path"),
     ("siddhi_join_device_events_total",
      "counter", "Of those, events that reached a keyed device join runtime"),
+    ("siddhi_join_build_rows_total",
+     "counter", "Build-side events of a keyed device join uploaded as "
+     "compact rows, once per group of int planes and block"),
+    ("siddhi_join_build_slots_total",
+     "counter", "Rows of those uploads, padding included"),
     ("siddhi_app_dispatches_per_block",
      "gauge", "Device dispatches per ingest block (running average)"),
     ("siddhi_ledger_stage_latency_ms",

@@ -8,7 +8,11 @@ at its arrival (``seq``, which is both the slot, ``seq % K``, and the
 arrival order of the rows).  A block is the dense ``[P, T]`` scatter of
 ``ops/nfa.pack_blocks``; its ``__stream`` plane holds the sides an event
 belongs to (``LEFT | RIGHT`` bits: the sides of a self-join meet in one
-chunk).  The step scans the ``T`` ticks; at each tick every lane
+chunk).  The int planes of the columns the step reads off an event (a
+ring's 64-bit halves and string codes) come up as compact rows, only
+for the events that carry them, and are scattered into their dense
+planes on the device before the scan.  The step scans the ``T`` ticks;
+at each tick every lane
 
   (a) expires the entries of both rings with ``ts + window <= now`` of
       the arriving event (upstream's TimeWindowProcessor; a lane is only
@@ -116,16 +120,22 @@ def _bits(a):
         jax.lax.bitcast_convert_type(a, jnp.int32)
 
 
-def build_step(spec: JoinSpec, present: Tuple[bool, bool]):
+def build_step(spec: JoinSpec, present: Tuple[bool, bool],
+               groups: Tuple[Tuple[str, ...], ...] = ()):
     """-> ``step(carry, block, cap)`` -> ``(carry, rows, tail)`` for the
     blocks of one input stream, which hold events of the sides
-    ``present``.  ``block``: ``ts`` and ``side`` ``[P, T]`` int32 and
-    per column ``f:<name>`` / ``i:<name>``; ``rows``: ``[cap, 2 + C]``
-    int32, per match its flat index over ``[D, T, K, P]`` (``D`` the
-    probing directions of ``directions(spec, present)``, in their
-    order), the matched entry's ``seq`` and its planes' bits; ``tail``
-    int32: the true row count, the lanes whose ring overflowed, then
-    ``JOIN_CTR`` summed over the lanes."""
+    ``present``.  ``block``: ``ts`` and ``side`` ``[P, T]`` int32, per
+    float column ``f:<name>`` ``[P, T]``, and ``rows``: per group of
+    ``groups`` (the names of its int planes) a pair ``(idx, vals)``:
+    ``idx`` ``[R]`` int32 the flat cell ``tick * P + lane`` of each row
+    (``P * T`` and beyond: padding, dropped), ``vals`` ``[n, R]`` int32
+    the group's planes.  A cell holds one event, so the scatter is
+    exact.  ``rows`` (the result): ``[cap, 2 + C]`` int32, per match its
+    flat index over ``[D, T, K, P]`` (``D`` the probing directions of
+    ``directions(spec, present)``, in their order), the matched entry's
+    ``seq`` and its planes' bits; ``tail`` int32: the true row count,
+    the lanes whose ring overflowed, then ``JOIN_CTR`` summed over the
+    lanes."""
     dirs = directions(spec, present)
     width = max([len(spec.rings[1 - s].planes) for s in dirs], default=0)
 
@@ -185,7 +195,12 @@ def build_step(spec: JoinSpec, present: Tuple[bool, bool]):
         return {"ring": tuple(rings), "ctr": ctr}, (ys, grew)
 
     def keyed_join_step(carry, block, cap):
-        xs = {k: v.T for k, v in block.items()}       # lanes minor
+        P, T = block["ts"].shape
+        xs = {k: v.T for k, v in block.items() if k != "rows"}  # lanes minor
+        for names, (idx, vals) in zip(groups, block["rows"]):
+            dense = jnp.zeros((len(names), T * P), jnp.int32).at[:, idx] \
+                .set(vals, mode="drop").reshape(len(names), T, P)
+            xs.update({f"i:{name}": dense[j] for j, name in enumerate(names)})
         carry, (ys, grew) = jax.lax.scan(tick, carry, xs)
         tail = [jnp.zeros((), jnp.int32),
                 jnp.sum(jnp.any(grew, axis=0), dtype=jnp.int32)]

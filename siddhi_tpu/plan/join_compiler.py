@@ -43,6 +43,9 @@ _KEY_TYPES = ({AttrType.STRING}, {AttrType.INT, AttrType.LONG})
 MAX_WINDOW_MS = 1 << 30
 #: rows a block of depth T may deliver before the egress buffer doubles
 ROWS_PER_TICK = 2048
+#: a block's build side goes up as ``P * T / ROW_SHARE`` compact rows,
+#: doubled until they hold the events that carry its values
+ROW_SHARE = 64
 
 
 def attr_planes(attr: str, typ: AttrType) -> Tuple[Tuple[str, str], ...]:
@@ -261,6 +264,27 @@ def halves(col: np.ndarray, typ: AttrType) -> List[np.ndarray]:
             (bits & 0xFFFFFFFF).astype(np.uint32).view(np.int32)]
 
 
+def compact_rows(lanes: np.ndarray, ticks: np.ndarray, vals: np.ndarray,
+                 P: int, T: int) -> Tuple[np.ndarray, np.ndarray]:
+    """One group of a ``[P, T]`` block's int planes as the step takes it:
+    ``(idx [R], vals [n, R])`` int32, per row the flat cell ``tick * P +
+    lane`` of the event at ``lanes``, ``ticks`` and its values, then
+    padding at ``P * T``, which the step drops.  ``R`` is ``P * T /
+    ROW_SHARE``, doubled until it holds the rows and at most ``P * T``:
+    a function of the block's shape unless its build side is that
+    full."""
+    n_cells = P * T
+    R = max(n_cells // ROW_SHARE, 1)
+    while R < len(lanes):
+        R *= 2
+    R = min(R, n_cells)
+    idx = np.full(R, n_cells, np.int32)
+    idx[:len(lanes)] = ticks * P + lanes
+    out = np.zeros((len(vals), R), np.int32)
+    out[:, :len(lanes)] = vals
+    return idx, out
+
+
 def _whole(lanes: List[np.ndarray], typ: AttrType) -> np.ndarray:
     """The inverse of `halves`, from int32 bit lanes."""
     if typ == AttrType.FLOAT:
@@ -342,18 +366,54 @@ class CompiledKeyedJoin:
             (a, self.plan.sides[i].types[a]) for i in (0, 1) if present[i]
             for a in self.plan.event_attrs[i]))
 
-    def planes_of(self, attr: str, typ: AttrType, col: np.ndarray,
-                  bits: np.ndarray) -> Tuple[np.ndarray, List[np.ndarray]]:
-        """-> (``at``, planes): the events of a block whose ``attr`` the
-        step reads (``bits``: per event the sides it is on) and the
-        column's planes (`attr_planes`) over those events.  In q20 they
-        are the auctions, one placed event in eighty."""
-        at = np.flatnonzero(bits & self._read_sides[attr])
-        vals = np.asarray(col)[at]
+    def row_groups(self, present: Tuple[bool, bool]):
+        """The columns the step reads off the events of a block that
+        holds the sides ``present`` and that ride int planes, grouped by
+        the events that carry them: per group ``(side bits, ((attribute,
+        type), ...))``.  In q20 one group: the auction's nine columns."""
+        mask = present[0] | present[1] << 1
+        groups: Dict[int, list] = {}
+        for attr, typ in self.event_attrs(present):
+            if typ != AttrType.FLOAT:
+                groups.setdefault(self._read_sides[attr] & mask, []) \
+                    .append((attr, typ))
+        return tuple((bits, tuple(cols)) for bits, cols in groups.items())
+
+    def _group_planes(self, present: Tuple[bool, bool]):
+        """The names of the int planes of each of ``row_groups``."""
+        return tuple(tuple(name for attr, typ in cols
+                           for name, _k in attr_planes(attr, typ))
+                     for _bits, cols in self.row_groups(present))
+
+    def event_planes(self, present: Tuple[bool, bool],
+                     columns: Dict[str, np.ndarray], bits: np.ndarray):
+        """The values the step reads off the events of a block (``bits``:
+        per event the sides it is on), of the events that carry them
+        only -> (``{"f:<name>": (at, float32 values)}`` per float
+        column, ``(at, [n, len(at)] int32)`` per group of
+        ``row_groups(present)``), ``at`` those events' places.  In q20
+        they are the auctions, one placed event in eighty."""
+        floats = {}
+        for attr, typ in self.event_attrs(present):
+            if typ == AttrType.FLOAT:
+                at = np.flatnonzero(bits & self._read_sides[attr])
+                floats[f"f:{attr}"] = (
+                    at, np.asarray(columns[attr], np.float32)[at])
+        groups = []
+        for side_bits, cols in self.row_groups(present):
+            at = np.flatnonzero(bits & side_bits)
+            groups.append((at, np.stack([
+                p for attr, typ in cols
+                for p in self._int_planes(np.asarray(columns[attr])[at],
+                                          typ)])))
+        return floats, groups
+
+    def _int_planes(self, vals: np.ndarray, typ: AttrType
+                    ) -> List[np.ndarray]:
         if typ != AttrType.STRING:
-            return at, halves(vals, typ)
-        return at, [np.fromiter(map(self._encode_str, vals.tolist()),
-                                np.int32, len(at))]
+            return halves(vals, typ)
+        return [np.fromiter(map(self._encode_str, vals.tolist()),
+                            np.int32, len(vals))]
 
     def _encode_str(self, v) -> int:
         if v is None:
@@ -379,10 +439,15 @@ class CompiledKeyedJoin:
         block = {"ts": jax.ShapeDtypeStruct(shape, np.int32),
                  "side": jax.ShapeDtypeStruct(shape, np.int32)}
         for attr, typ in self.event_attrs(present):
-            for name, kind in attr_planes(attr, typ):
-                block[f"{kind}:{name}"] = jax.ShapeDtypeStruct(
-                    shape, np.float32 if kind == "f" else np.int32)
-        fn = build_step(self.spec, present)
+            if typ == AttrType.FLOAT:
+                block[f"f:{attr}"] = jax.ShapeDtypeStruct(shape, np.float32)
+        groups = self._group_planes(present)
+        none = np.empty(0, np.int32)
+        block["rows"] = tuple(
+            compact_rows(none, none, np.empty((len(names), 0), np.int32),
+                         self.n_lanes, 1)
+            for names in groups)
+        fn = build_step(self.spec, present, groups)
         jax.eval_shape(lambda c, b: fn(c, b, 64), self.carry, block)
 
     def step_for(self, present: Tuple[bool, bool]):
@@ -399,7 +464,8 @@ class CompiledKeyedJoin:
                                   if s.window_ms is not None),
                  "planes": sum(len(s.planes) for s in self.plan.sides),
                  "residual": self.plan.residual is not None},
-                build_step(self.spec, present), static_argnums=2)
+                build_step(self.spec, present, self._group_planes(present)),
+                static_argnums=2)
             self._book()
         return step
 

@@ -41,7 +41,7 @@ from ..core.ledger import ABSENT_COUNTERS, ON_FLUSH, ledger as _ledger
 from ..core.stateschema import Keyed, persistent_schema
 from ..ops.nfa import SharedPlanes
 from ..parallel.shards import build_shards, resolve_shards, split_rows
-from .join_compiler import CompiledKeyedJoin, plan_keyed_join
+from .join_compiler import CompiledKeyedJoin, compact_rows, plan_keyed_join
 from .nfa_compiler import CompiledPatternNFA
 from .pipeline import (PipelinedDeviceIngest, note_retire,
                        retire_after_submit, settle_inflight, stamp_submit)
@@ -1397,7 +1397,6 @@ class DeviceKeyedJoinRuntime(PipelinedDeviceIngest):
 
     def ingest(self, stream_code: int, stream_id: str, chunk) -> None:
         from ..core.event import CURRENT
-        from .join_compiler import attr_planes
         data = chunk.only(CURRENT)
         if data.is_empty:
             return
@@ -1431,40 +1430,37 @@ class DeviceKeyedJoinRuntime(PipelinedDeviceIngest):
         with led.span("dispatch", "cols"):
             offs = self.join.offsets(np.asarray(data.timestamps, np.int64),
                                      self.flush)
-            # per plane: the events that have a value in it, the values
-            planes = {}
-            for attr, typ in self.join.event_attrs(present):
-                at, vals = self.join.planes_of(attr, typ,
-                                               data.columns[attr], bits)
-                for (name, kind), v in zip(attr_planes(attr, typ), vals):
-                    planes[f"{kind}:{name}"] = (at, v)
-        self._dispatch(data, lanes, offs, bits, planes, present)
+            # the events that carry a value the step reads, the values
+            floats, groups = self.join.event_planes(present, data.columns,
+                                                    bits)
+        self._dispatch(data, lanes, offs, bits, floats, groups, present)
         _record_block(self, marks, stream_id, n)
 
-    def _dispatch(self, data, lanes, offs, bits, planes, present) -> None:
-        """Pack the placed events into one dense block, step it and put
-        it in flight."""
+    def _dispatch(self, data, lanes, offs, bits, floats, groups,
+                  present) -> None:
+        """Pack the placed events into one block, step it and put it in
+        flight: the float planes ride the dense scatter of the block
+        itself, each group of int planes goes up as compact rows of the
+        events that carry it (the step scatters them on the device)."""
         from ..ops.nfa import pack_blocks
         led = _ledger()
         with led.span("device"):
             with led.span("device", "pack"):
-                # the float planes ride the scatter of the block itself
-                floats = {}
-                for name, (at, v) in planes.items():
-                    if name[0] == "f":
-                        floats[name] = np.zeros(len(lanes), np.float32)
-                        floats[name][at] = v
+                dense = {}
+                for name, (at, v) in floats.items():
+                    dense[name] = np.zeros(len(lanes), np.float32)
+                    dense[name][at] = v
                 packed, prow = pack_blocks(
-                    lanes, floats, offs, bits, self.join.n_lanes,
+                    lanes, dense, offs, bits, self.join.n_lanes,
                     pad_t_pow2=True, return_rows=True)
-                block = {"ts": packed["__ts"], "side": packed["__stream"]}
-                for name, (at, v) in planes.items():
-                    if name[0] == "f":
-                        block[name] = packed[name]
-                    else:
-                        block[name] = np.zeros(packed["__ts"].shape,
-                                               np.int32)
-                        block[name][lanes[at], prow[at]] = v
+                P, T = packed["__ts"].shape
+                block = {"ts": packed["__ts"], "side": packed["__stream"],
+                         **{name: packed[name] for name in floats}}
+                block["rows"] = tuple(
+                    compact_rows(lanes[at], prow[at], vals, P, T)
+                    for at, vals in groups)
+            for (at, _v), (idx, _r) in zip(groups, block["rows"]):
+                led.note_join_build(self.app_name, len(at), len(idx))
             _note_pack(self.app_name, len(lanes), packed)
             work = {"data": data, "lanes": lanes, "prow": prow,
                     "block": block, "present": present,

@@ -120,35 +120,50 @@ def counters(app):
 Q20 = ("@app:name('{name}') @app:playback\n" + STREAM + """
 @info(name='q')
 from S[kind >= 4] as b
-  join S[kind >= 1 and kind <= 3 and price >= 40.0]#window.time({w} sec) as a
+  join S[{build}]#window.time({w} sec) as a
   on b.sym == a.sym{residual}
-select b.sym as auction, b.price as bid, a.price as reserve
+select b.sym as auction, b.price as bid, a.price as reserve,
+       a.kind as akind
 insert into Out;
 """)
 
+#: the auction side's filter, as SiddhiQL and as the reference's `where`:
+#: q20's, which few events pass (the block's compact rows are a few of
+#: its cells), and one that every event passes (the rows outgrow
+#: P x T / 64 and double)
+BUILDS = {"sparse": ("kind >= 1 and kind <= 3 and price >= 40.0",
+                     [["kind", ">=", 1], ["kind", "<=", 3],
+                      ["price", ">=", 40.0]]),
+          "full": ("kind >= 0", [["kind", ">=", 0]])}
 
-def q20_args(window_ms=2000, residual=()):
+
+def q20(name, w=2, residual="", build="sparse"):
+    return Q20.format(name=name, w=w, residual=residual,
+                      build=BUILDS[build][0])
+
+
+def q20_args(window_ms=2000, residual=(), build="sparse"):
     return {"key": "sym",
             "left": {"where": [["kind", ">=", 4]], "window_ms": None},
-            "right": {"where": [["kind", ">=", 1], ["kind", "<=", 3],
-                                ["price", ">=", 40.0]],
-                      "window_ms": window_ms},
+            "right": {"where": BUILDS[build][1], "window_ms": window_ms},
             "trigger": "all", "residual": list(residual),
             "out": {"auction": ["left", "sym"], "bid": ["left", "price"],
-                    "reserve": ["right", "price"]}}
+                    "reserve": ["right", "price"], "akind": ["right", "kind"]}}
 
 
+@pytest.mark.parametrize("build", sorted(BUILDS))
 @pytest.mark.parametrize("chunk", [1, 7, 64, 1000])
 @pytest.mark.parametrize("seed", [1, 2147483999])
-def test_q20_device_host_and_reference_agree(seed, chunk):
+def test_q20_device_host_and_reference_agree(seed, chunk, build):
     cols, ts = events(seed, n=400, kinds=12)
     tally = {}
-    want = REF.run_loop(cols, ts, q20_args(), tally)
+    args = q20_args(build=build)
+    want = REF.run_loop(cols, ts, args, tally)
     assert len(want["__ts"]) > 20
-    same_rows(REF.run(cols, ts, q20_args()), want)
-    name = f"q20_{seed}_{chunk}"
+    same_rows(REF.run(cols, ts, args), want)
+    name = f"q20_{seed}_{chunk}_{build}"
     before = counters(name)
-    app = Q20.format(name=name, w=2, residual="")
+    app = q20(name, build=build)
     dev, sv = serve_one_stream(app, cols, ts, chunk)
     assert sv.qr.backend == "device" and sv.qr.backend_reason is None
     assert type(sv.qr.device_runtime).__name__ == "DeviceKeyedJoinRuntime"
@@ -164,7 +179,16 @@ def test_q20_device_host_and_reference_agree(seed, chunk):
         assert got[f"join_{k}_total"] == tally[k], (k, got, tally)
     assert got["join_events_total"] == len(ts)
     assert got["join_device_events_total"] == len(ts)
-    assert got["join_ring_grown_total"] == 0
+    # q20's auctions never fill a ring; with every event on the build
+    # side a key can hold more than 8 live entries, and its ring doubles
+    assert 8 << got["join_ring_grown_total"] == \
+        sv.qr.device_runtime.join.n_slots
+    if build == "sparse":
+        assert got["join_ring_grown_total"] == 0
+    # every auction went up once as a compact row, into uploads of at
+    # least its block's P x T / 64 rows
+    assert got["join_build_rows_total"] == tally["inserted"]
+    assert got["join_build_slots_total"] >= tally["inserted"]
     assert counters(name + "h")["join_events_total"] == len(ts)
     assert counters(name + "h")["join_device_events_total"] == 0
 
@@ -260,7 +284,8 @@ def test_self_join_rows_do_not_depend_on_the_cut(mode):
         @info(name='q')
         from S[kind >= 3] as b join S[kind < 3]#window.time(1 sec) as a
           on b.sym == a.sym
-        select b.sym as auction, b.price as bid, a.price as reserve
+        select b.sym as auction, b.price as bid, a.price as reserve,
+               a.kind as akind
         insert into Out;""", mode)
     for chunk in range(1, len(ts) + 1):
         got, _ = serve_one_stream(app, cols, ts, chunk)
@@ -292,29 +317,50 @@ def test_expiry_boundary_and_equal_milliseconds(mode, chunk):
     # t+w-1 both are live, at t+w only the second, at t+w+1 none
     assert want["bid"].tolist() == [11, 12, 12, 13, 14]
     assert want["reserve"].tolist() == [50, 50, 60, 60, 60]
-    app = engine(Q20.format(name=f"edge_{mode}_{chunk}", w=2, residual=""),
-                 mode)
+    app = engine(q20(f"edge_{mode}_{chunk}"), mode)
     got, _ = serve_one_stream(app, cols, ts, chunk)
     same_rows(got, want, keyed=("auction",))
 
 
 # ---------------------------------------------------- (e) ring growth
 
+def _spy_blocks(join):
+    """Wrap ``join.process_block``: -> (every block it was given, each
+    once, and its number of calls)."""
+    blocks, calls, step = [], [0], join.process_block
+
+    def spy(block, present):
+        calls[0] += 1
+        if not any(b is block for b in blocks):
+            blocks.append(block)
+        return step(block, present)
+    join.process_block = spy
+    return blocks, calls
+
+
+@pytest.mark.parametrize("build", sorted(BUILDS))
 @pytest.mark.parametrize("chunk", [1, 40])
-def test_a_full_ring_doubles_and_loses_nothing(chunk):
+def test_a_full_ring_doubles_and_loses_nothing(chunk, build):
     """20 live auctions of one key: the 8-slot ring doubles twice, the
-    block that filled it is replayed, and the rows are the reference's."""
+    block that filled it is replayed, and the rows are the reference's.
+    The build side's counters count each block's compact rows once,
+    the replayed block too."""
     kinds = [1] * 20 + [5] * 3 + [1] * 2 + [5]
     cols, ts = _planted(kinds, np.arange(len(kinds)) + 50.0,
                         7_000_000 + np.arange(len(kinds)) * 10)
     tally = {}
-    want = REF.run_loop(cols, ts, q20_args(), tally)
-    assert len(want["__ts"]) == 3 * 20 + 22
-    name = f"grow_{chunk}"
+    want = REF.run_loop(cols, ts, q20_args(build=build), tally)
+    # with every event on the build side a bid also enters the ring
+    assert len(want["__ts"]) == {"sparse": 3 * 20 + 22,
+                                 "full": 20 + 21 + 22 + 25}[build]
+    name = f"grow_{chunk}_{build}"
     before = counters(name)
-    got, sv = serve_one_stream(Q20.format(name=name, w=2, residual=""),
-                               cols, ts, chunk)
-    same_rows(got, want, keyed=("auction",))
+    sv = Serving(q20(name, build=build))
+    blocks, calls = _spy_blocks(sv.qr.device_runtime.join)
+    for sl in cut(len(ts), chunk):
+        sv.send("S", {k: (NAMES[v[sl]] if k == "sym" else v[sl])
+                      for k, v in cols.items()}, ts[sl])
+    same_rows(sv.close(), want, keyed=("auction",))
     assert sv.qr.device_runtime.join.n_slots == 32
     after = counters(name)
     assert after["join_ring_grown_total"] - before["join_ring_grown_total"] \
@@ -322,8 +368,27 @@ def test_a_full_ring_doubles_and_loses_nothing(chunk):
     for k in REF.TALLY:     # a replayed block is counted once
         assert after[f"join_{k}_total"] - before[f"join_{k}_total"] == \
             tally[k], k
+    assert calls[0] > len(blocks)                   # a block was replayed
+    rows = slots = 0
+    for block in blocks:
+        P, T = block["ts"].shape
+        (idx, _vals), = block["rows"]
+        rows += int((idx < P * T).sum())
+        slots += len(idx)
+    assert rows == tally["inserted"]
+    assert after["join_build_rows_total"] - \
+        before["join_build_rows_total"] == rows
+    assert after["join_build_slots_total"] - \
+        before["join_build_slots_total"] == slots
+    # kept per app on /metrics as on snapshot(), declared with the others
+    from siddhi_tpu.core.statistics import LEDGER_TYPES
+    text = "\n".join(ledger().prometheus_lines())
+    for k in ("join_build_rows_total", "join_build_slots_total"):
+        assert f'siddhi_{k}{{app="{name}"}} {after[k]}' in text
+    assert {f"siddhi_{k}" for k in JOIN_COUNTERS} <= \
+        {n for n, _kind, _text in LEDGER_TYPES}
     host, _ = serve_one_stream(
-        engine(Q20.format(name=name + "h", w=2, residual=""), "host"),
+        engine(q20(name + "h", build=build), "host"),
         cols, ts, chunk)
     same_rows(host, want, keyed=("auction",))
 
@@ -337,7 +402,7 @@ def test_a_deep_block_is_stepped_at_its_own_depth():
                         8_000_000 + np.arange(len(kinds)) * 10)
     cols["sym"][::7] = 1                 # a second key in between
     want = REF.run_loop(cols, ts, q20_args())
-    sv = Serving(Q20.format(name="deep", w=2, residual=""))
+    sv = Serving(q20("deep"))
     join = sv.qr.device_runtime.join
     depths, step = [], join.process_block
     join.process_block = lambda block, present: (
@@ -358,8 +423,7 @@ def test_residual_comparison(chunk):
     loose = REF.run_loop(cols, ts, q20_args())
     assert 10 < len(want["__ts"]) < len(loose["__ts"])
     same_rows(REF.run(cols, ts, args), want)
-    app = Q20.format(name=f"res_{chunk}", w=2,
-                     residual=" and b.price > a.price")
+    app = q20(f"res_{chunk}", residual=" and b.price > a.price")
     dev, sv = serve_one_stream(app, cols, ts, chunk)
     assert type(sv.qr.device_runtime).__name__ == "DeviceKeyedJoinRuntime"
     same_rows(dev, want, keyed=("auction",))
@@ -369,10 +433,11 @@ def test_residual_comparison(chunk):
 
 # ------------------------------------------------ (g) persist, restore
 
-def test_snapshot_half_way_restores_the_rings():
+@pytest.mark.parametrize("build", sorted(BUILDS))
+def test_snapshot_half_way_restores_the_rings(build):
     cols, ts = events(13, n=300, kinds=12)
-    want = REF.run_loop(cols, ts, q20_args())
-    app = Q20.format(name="snap", w=2, residual="")
+    want = REF.run_loop(cols, ts, q20_args(build=build))
+    app = q20(f"snap_{build}", build=build)
     half = 150
 
     def feed(sv, sl):
@@ -533,6 +598,74 @@ def test_snapshot_carries_the_rings_strings():
         assert (np.concatenate([head[k], tail[k]]) == want[k]).all(), k
     # rows of the second half name strings only the snapshot held
     assert set(tail["atag"]) & set(tag[:half])
+
+
+# ----------------------------------- (k) the build side's compact rows
+
+def _step_layouts():
+    """-> {layout: (spec, groups, the side bits whose events carry each
+    group)}: q20's one group (the ring side's 64-bit halves and a string
+    code, beside a float), and two windowed sides whose events carry a
+    group each, compared by a residual over both."""
+    from siddhi_tpu.ops.keyed_join import LEFT, RIGHT, JoinSpec, Ring
+    return {
+        "one group": (
+            JoinSpec(rings=(None, Ring(300, (("a#0", "i"), ("a#1", "i"),
+                                             ("s", "i"), ("x", "f")))),
+                     triggers=(True, True), residual=None),
+            (("a#0", "a#1", "s"),), (RIGHT,)),
+        "two groups": (
+            JoinSpec(rings=(Ring(500, (("a", "i"), ("x", "f"))),
+                            Ring(300, (("b", "i"), ("c", "i")))),
+                     triggers=(True, True),
+                     residual=lambda lv, rv: (lv["a"] & 3) != (rv["b"] & 3)),
+            (("a",), ("b", "c")), (LEFT, RIGHT))}
+
+
+@pytest.mark.parametrize("T", [4, 8, 16])
+@pytest.mark.parametrize("layout", ["one group", "two groups"])
+def test_compact_rows_step_equals_dense_planes(layout, T):
+    """The step given a block's int planes as compact rows (in any order,
+    padding rows behind them) returns the carry, rows and tail that it
+    returns given the same planes dense, bit for bit, block after
+    block."""
+    import jax
+
+    from siddhi_tpu.ops.keyed_join import build_step, make_carry
+    from siddhi_tpu.plan.join_compiler import compact_rows
+    spec, groups, carriers = _step_layouts()[layout]
+    P, cap = 32, 4096
+    dense_step = jax.jit(build_step(spec, (True, True)), static_argnums=2)
+    rows_step = jax.jit(build_step(spec, (True, True), groups),
+                        static_argnums=2)
+    rng = np.random.default_rng(4200 + T)
+    carry = {"dense": make_carry(spec, P, 8), "rows": make_carry(spec, P, 8)}
+    for b in range(3):
+        side = rng.choice(4, (P, T), p=[0.4, 0.25, 0.25, 0.1]) \
+            .astype(np.int32)
+        ts = np.broadcast_to(b * T * 40 + np.arange(T, dtype=np.int32) * 40,
+                             (P, T)).copy()
+        x = rng.uniform(0, 100, (P, T)).astype(np.float32)
+        dense = {"ts": ts, "side": side, "f:x": x, "rows": ()}
+        compact = {"ts": ts, "side": side, "f:x": x, "rows": ()}
+        for names, bit in zip(groups, carriers):
+            lane, tick = np.nonzero(side & bit)
+            vals = rng.integers(-2**31, 2**31, (len(names), len(lane)),
+                                dtype=np.int64).astype(np.int32)
+            for name, v in zip(names, vals):
+                dense[f"i:{name}"] = np.zeros((P, T), np.int32)
+                dense[f"i:{name}"][lane, tick] = v
+            order = rng.permutation(len(lane))
+            idx, up = compact_rows(lane[order], tick[order], vals[:, order],
+                                   P, T)
+            assert (idx == P * T).any() and len(idx) >= P * T // 64
+            compact["rows"] += ((idx, up),)
+        carry["dense"], *want = dense_step(carry["dense"], dense, cap)
+        carry["rows"], *got = rows_step(carry["rows"], compact, cap)
+        for a, b_ in zip(jax.tree_util.tree_leaves(want + [carry["dense"]]),
+                         jax.tree_util.tree_leaves(got + [carry["rows"]])):
+            assert np.array_equal(np.asarray(a), np.asarray(b_))
+    assert int(want[1][0]) > 0          # the blocks matched something
 
 
 # ------------------------------------- (i) what stays on core/join.py
