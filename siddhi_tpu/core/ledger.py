@@ -234,6 +234,15 @@ JOIN_COUNTERS = ("join_probes_total", "join_probe_hits_total",
                  "join_events_total", "join_device_events_total",
                  "join_build_rows_total", "join_build_slots_total")
 
+#: the per-app counters of the grouped-aggregation device runtime, in the
+#: order of a ``_gagg`` row: events stepped (placed in a block); of
+#: those, the events stepped on group lanes (one lane per group tuple,
+#: plan/gagg_compiler.py ``group_lanes``); the ``[P, T]`` cells of the
+#: blocks stepped (lanes times the block's depth): events over cells is
+#: how full the blocks are
+GAGG_COUNTERS = ("gagg_events_total", "gagg_lane_events_total",
+                 "gagg_cells_total")
+
 
 # os.environ.get pays ~0.9 us per call (key encode + value decode);
 # the ledger asks "am I on?" ~10x per ingest block, so that alone would
@@ -656,6 +665,8 @@ class LatencyLedger:
         self._planes: Dict[str, list] = {}
         # app -> JOIN_COUNTERS row, likewise
         self._join: Dict[str, list] = {}
+        # app -> GAGG_COUNTERS row, likewise
+        self._gagg: Dict[str, list] = {}
         # app -> [device launches, ingest blocks]: the runtimes hand the
         # launch delta of every ingest block to ``note_block``.  Kept as
         # ``_absent`` is
@@ -802,6 +813,12 @@ class LatencyLedger:
         the events it holds, and its rows, padding included."""
         self._add(self._join, app, (0,) * 8 + (rows, slots))
 
+    def note_gagg(self, app: str, events: int, lane_events: int,
+                  cells: int) -> None:
+        """One block stepped by a grouped-aggregation device runtime: its
+        events, those of them on group lanes, its cells."""
+        self._add(self._gagg, app, (events, lane_events, cells))
+
     def note_key_factor(self, app: str, reused: bool) -> None:
         """One keyed device ingest asked for its block's factored keys."""
         self._add(self._keyfac, app, (1, reused))
@@ -820,7 +837,8 @@ class LatencyLedger:
                 (COUNT_COUNTERS, self._count),
                 (PACK_COUNTERS, self._pack),
                 (PLANE_COUNTERS, self._planes),
-                (JOIN_COUNTERS, self._join))
+                (JOIN_COUNTERS, self._join),
+                (GAGG_COUNTERS, self._gagg))
 
     # ------------------------------------------------------ block fold
 
@@ -995,7 +1013,7 @@ class LatencyLedger:
         }
         apps = sorted({a for (a, _s) in self._hist}.union(
             self._absent, self._keyfac, self._keyint, self._count,
-            self._pack, self._planes, self._join)) \
+            self._pack, self._planes, self._join, self._gagg)) \
             if app is None else [app]
         per_app = {}
         for a in apps:

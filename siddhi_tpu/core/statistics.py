@@ -602,6 +602,14 @@ LEDGER_TYPES = [
      "compact rows, once per group of int planes and block"),
     ("siddhi_join_build_slots_total",
      "counter", "Rows of those uploads, padding included"),
+    ("siddhi_gagg_events_total",
+     "counter", "Events stepped by the grouped-aggregation device runtime"),
+    ("siddhi_gagg_lane_events_total",
+     "counter", "Of those, events stepped on group lanes (one lane per "
+     "group tuple)"),
+    ("siddhi_gagg_cells_total",
+     "counter", "[P, T] cells of the grouped-aggregation blocks stepped "
+     "(lanes times the block's depth)"),
     ("siddhi_app_dispatches_per_block",
      "gauge", "Device dispatches per ingest block (running average)"),
     ("siddhi_ledger_stage_latency_ms",

@@ -202,6 +202,128 @@ def build_grouped_step(window: int, want_minmax: bool, want_forever: bool,
     return step
 
 
+#: the step's per-event outputs, in the order of its 13-tuple: a row
+#: gather names a plane by its place here
+PLANES = ("fsum_hi", "fsum_lo", "isum_hi", "isum_lo", "cnt",
+          "w_min_f", "w_max_f", "w_min_i", "w_max_i",
+          "all_min_f", "all_max_f", "all_min_i", "all_max_i")
+CNT = PLANES.index("cnt")
+
+
+def _columns(x, cols, axis):
+    """``x``'s value columns ``cols`` (static) along ``axis``; a count
+    plane (``cols`` None) as it is."""
+    if cols is None or tuple(cols) == tuple(range(x.shape[axis])):
+        return x
+    return jnp.stack([jnp.take(x, j, axis=axis) for j in cols], axis=axis)
+
+
+def build_row_gather(step, gather):
+    """The step and its row egress in one call: ``step``'s 13 planes
+    ``[P, T(, V)]`` read at each accepted event's ``(lane, row)`` into
+    ``[n]`` rows (``[n, k]`` for the ``k`` value columns a plane is read
+    at), only the ``(plane, columns)`` of ``gather``.  Anything the step
+    returns past its 13 planes (the NUMGUARD sentinel) follows the rows.
+    -> fn(carry, *step inputs, lanes [n], rows [n])."""
+    def gagg_rows_step(carry, *args):
+        *inputs, lanes, rows = args
+        carry, outs = step(carry, *inputs)
+        got = tuple(_columns(outs[p][lanes, rows], cols, 1)
+                    for p, cols in gather)
+        return carry, got + tuple(outs[13:])
+
+    return gagg_rows_step
+
+
+class LaneGroupCarry(NamedTuple):
+    """Running aggregates of one group per lane; the lanes are minor."""
+    fsum_hi: jnp.ndarray    # [VF, P] f32 two-float hi
+    fsum_lo: jnp.ndarray    # [VF, P] f32 two-float lo
+    isum_hi: jnp.ndarray    # [VI, P] i32 split hi
+    isum_lo: jnp.ndarray    # [VI, P] i32 split lo
+    gcnt: jnp.ndarray       # [P] i32
+    fmin_f: jnp.ndarray     # [VF, P] f32 add-only min
+    fmax_f: jnp.ndarray     # [VF, P] f32 add-only max
+    fmin_i: jnp.ndarray     # [VI, P] i32 add-only min
+    fmax_i: jnp.ndarray     # [VI, P] i32 add-only max
+
+
+def make_lane_group_carry(n_lanes: int, n_float: int,
+                          n_int: int) -> LaneGroupCarry:
+    P, VF, VI = n_lanes, n_float, n_int
+    return LaneGroupCarry(
+        fsum_hi=jnp.zeros((VF, P), jnp.float32),
+        fsum_lo=jnp.zeros((VF, P), jnp.float32),
+        isum_hi=jnp.zeros((VI, P), jnp.int32),
+        isum_lo=jnp.zeros((VI, P), jnp.int32),
+        gcnt=jnp.zeros((P,), jnp.int32),
+        fmin_f=jnp.full((VF, P), jnp.inf, jnp.float32),
+        fmax_f=jnp.full((VF, P), -jnp.inf, jnp.float32),
+        fmin_i=jnp.full((VI, P), I32_MAX, jnp.int32),
+        fmax_i=jnp.full((VI, P), I32_MIN, jnp.int32))
+
+
+def build_lane_group_step(want_minmax: bool, gather,
+                          numguard: bool = False):
+    """Running (no-window) aggregates with one group per lane: an event
+    updates its own lane alone, so the lanes step side by side and a
+    block is ``T`` ticks of elementwise work over ``[V, P]`` slabs.
+
+    fn(carry, vals_f [n, VF], vals_i [n, VI], at [n] i32, depth) ->
+    (carry, rows): the block's accepted events come up as compact rows,
+    ``at`` = ``row * P + lane`` their cell in the ``[depth, P]`` block
+    (``depth * P`` or beyond: padding, dropped), and are scattered on the
+    device; ``rows`` are the outputs of ``gather`` (``(plane, columns)``
+    of the 13-tuple build_grouped_step documents) at each event's cell,
+    ``[n]`` or ``[n, k]``, then the NUMGUARD sentinel where armed.
+    ``depth`` is static."""
+    def gagg_lane_step(carry: LaneGroupCarry, vals_f, vals_i, at, depth):
+        P = carry.gcnt.shape[0]
+        cells = depth * P
+
+        def dense(rows):
+            # [n, V] rows -> [depth, V, P] planes, zero where no event
+            V = rows.shape[1]
+            d = jnp.zeros((V, cells), rows.dtype).at[:, at].set(
+                rows.T, mode="drop")
+            return d.reshape(V, depth, P).transpose(1, 0, 2)
+
+        ok = jnp.zeros((cells,), bool).at[at].set(
+            True, mode="drop").reshape(depth, P)
+
+        def tick(c: LaneGroupCarry, xs):
+            xf, xi, m = xs
+            fhi, flo = _pair_add(c.fsum_hi, c.fsum_lo, xf, m[None, :])
+            ihi = c.isum_hi + jnp.where(m, xi >> 16, 0)
+            ilo = c.isum_lo + jnp.where(m, xi & (_SPLIT - 1), 0)
+            gc = c.gcnt + m.astype(jnp.int32)
+            mnf, mxf, mni, mxi = c.fmin_f, c.fmax_f, c.fmin_i, c.fmax_i
+            if want_minmax:
+                mnf = jnp.where(m, jnp.minimum(mnf, xf), mnf)
+                mxf = jnp.where(m, jnp.maximum(mxf, xf), mxf)
+                mni = jnp.where(m, jnp.minimum(mni, xi), mni)
+                mxi = jnp.where(m, jnp.maximum(mxi, xi), mxi)
+            nc = LaneGroupCarry(fhi, flo, ihi, ilo, gc, mnf, mxf, mni, mxi)
+            planes = (fhi, flo, ihi, ilo, gc, mnf, mxf, mni, mxi,
+                      mnf, mxf, mni, mxi)
+            return nc, tuple(_columns(planes[p], cols, 0)
+                             for p, cols in gather)
+
+        carry, outs = jax.lax.scan(
+            tick, carry, (dense(vals_f), dense(vals_i), ok))
+        cell = jnp.minimum(at, cells - 1)
+        t, lane = cell // P, cell % P
+        # [depth, k, P] read at (t, :, lane) -> [n, k]
+        rows = tuple(o[t, lane] if o.ndim == 2 else o[t, :, lane]
+                     for o in outs)
+        if numguard:
+            rows = rows + (sentinel_plane(carry.fsum_hi, carry.isum_hi,
+                                          carry.isum_lo, carry.gcnt),)
+        return carry, rows
+
+    return gagg_lane_step
+
+
 def sentinel_plane(fsum_hi, isum_hi, isum_lo, gcnt) -> jnp.ndarray:
     """[3] int32 NUMGUARD flags folded from accumulator planes a step
     already produced: [int sums past 90% of 2^31, count lanes past 90%

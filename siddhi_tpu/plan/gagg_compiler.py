@@ -41,21 +41,49 @@ from ..query_api.definition import AttrType
 from ..query_api.expression import AttributeFunction, Constant, Variable
 from ..utils.errors import (SiddhiAppCreationError,
                             SiddhiAppRuntimeException)
-from ..ops.grouped_agg import (INT_EXACT_MAX, INT_GROUP_MAX, TS_EMPTY,
+from ..ops.grouped_agg import (CNT, INT_EXACT_MAX, INT_GROUP_MAX, TS_EMPTY,
                                GroupedTimeCarry, build_grouped_step,
-                               build_grouped_time_step, make_grouped_carry,
-                               make_grouped_time_carry,
-                               reassemble_int_sums)
+                               build_grouped_time_step,
+                               build_lane_group_step, build_row_gather,
+                               make_grouped_carry, make_grouped_time_carry,
+                               make_lane_group_carry, reassemble_int_sums)
+from ..core.ledger import ledger as _ledger
 from .expr_compiler import EvalCtx, ExprCompiler, Scope
 
 _AGGS = {"sum", "count", "avg", "min", "max", "minforever", "maxforever",
          "stddev"}
 _INT_TYPES = (AttrType.INT, AttrType.LONG)
 _NUM_TYPES = _INT_TYPES + (AttrType.FLOAT, AttrType.DOUBLE)
+#: group attribute types whose distinct values a block factors exactly as
+#: the host's tuple keys tell them apart (a float's -0.0 and NaN do not)
+_LANE_KEY_TYPES = (AttrType.STRING, AttrType.INT, AttrType.LONG,
+                   AttrType.BOOL)
 
 G_START = 8          # initial per-lane group capacity (doubles on demand)
 MAX_WINDOW = (1 << 15) - 1   # hi/lo int sums stay exact below this
 TIME_CAPACITY_START = 64     # time-window ring start (grow-and-replay)
+#: a block's rows go out as at least ``P * T / ROW_SHARE`` (the accepted
+#: events rounded up to a power of two where more), so that the egress
+#: buffer, like the block, takes its shape from the depth
+ROW_SHARE = 8
+
+
+def _const_choice(ast) -> bool:
+    """``ifThenElse(<cond>, c1, c2)`` with two integer constants: a
+    computed integer lane whose values are the two constants alone."""
+    if not (isinstance(ast, AttributeFunction) and
+            (ast.namespace or "") == "" and
+            ast.name.lower() == "ifthenelse" and len(ast.args) == 3):
+        return False
+    return all(isinstance(c, Constant) and type(c.value) is int and
+               abs(c.value) < INT_EXACT_MAX for c in ast.args[1:])
+
+
+def rows_pad(n: int, n_lanes: int, depth: int) -> int:
+    """The rows a block of ``n`` events over ``[n_lanes, depth]`` cells
+    sends out (ROW_SHARE)."""
+    return max(1 << max(0, (n - 1).bit_length()),
+               n_lanes * depth // ROW_SHARE)
 
 
 def _reject(msg: str):
@@ -118,7 +146,7 @@ class CompiledGroupedAgg:
     """One aggregation query over [lane, group, value] device state."""
 
     def __init__(self, app, query: Query, n_lanes: int = 1,
-                 keyed: bool = False):
+                 keyed: bool = False, definition=None):
         s = query.input_stream
         assert isinstance(s, SingleInputStream)
         wh = s.window_handler
@@ -156,7 +184,8 @@ class CompiledGroupedAgg:
         # set by the pipelined runtime: retires in-flight work before a
         # timestamp rebase mutates the ring (plan/pipeline.py)
         self.flush_hook = None
-        definition = app.stream_definitions.get(s.stream_id)
+        if definition is None:
+            definition = app.stream_definitions.get(s.stream_id)
         if definition is None:
             _reject(f"no stream '{s.stream_id}'")
         self.stream_id = s.stream_id
@@ -200,10 +229,11 @@ class CompiledGroupedAgg:
                 _reject(f"aggregate argument type {ce.type} not numeric")
             int_mode = ce.type in _INT_TYPES
             attr = ast.attribute if isinstance(ast, Variable) else None
-            if int_mode and attr is None:
+            if int_mode and attr is None and not _const_choice(ast):
                 _reject("INT/LONG aggregate arguments must be plain "
-                        "attributes (computed integer expressions cannot "
-                        "be exactness-checked)")
+                        "attributes or ifThenElse of two integer constants "
+                        "(other computed integer expressions cannot be "
+                        "exactness-checked)")
             if int_mode:
                 v = _Value(ast, ce, True, self._n_int, attr)
                 self._n_int += 1
@@ -296,8 +326,22 @@ class CompiledGroupedAgg:
             kind in ("sum", "avg") and isinstance(ref, _Value) and
             ref.int_mode for (_n, kind, ref) in self.outputs)
 
+        # group lanes: an unpartitioned group-by whose window keeps each
+        # group's events apart (none, time, externalTime) steps one lane
+        # per group tuple, G = 1; the caller maps group tuples to lanes.
+        # A length window evicts across groups and keeps one lane
+        self.group_lanes = (not keyed and bool(self.group_attrs) and
+                            (self.window == 0 or
+                             self.window_kind == "time") and
+                            all(attr_types[a] in _LANE_KEY_TYPES
+                                for a in self.group_attrs))
+        # running aggregates on group lanes take the lane-minor step,
+        # whose events come up and go out as rows
+        self.lane_rows = self.group_lanes and self.window == 0 and \
+            self.selection is None
+        self._gather, self._row_at = self._gather_spec()
         self.n_lanes = n_lanes
-        self.n_groups = G_START
+        self.n_groups = 1 if self.group_lanes else G_START
         self.gid_map: Dict[Tuple, int] = {}      # (lane, key tuple) → gid
         self._lane_gids: Dict[int, int] = {}     # lane → next local gid
         # numeric sentinels (core/numguard.py, SIDDHI_TPU_NUMGUARD):
@@ -312,20 +356,59 @@ class CompiledGroupedAgg:
 
     # ------------------------------------------------------------ shapes
 
+    def _gather_spec(self):
+        """What decode reads of an accepted event's outputs: per plane of
+        the step's 13-tuple (ops/grouped_agg.PLANES) the value columns,
+        in plane order, the count plane always (the exact-sum bound and
+        avg read it); and plane -> (its place among the rows, value
+        column -> its place in the plane's rows)."""
+        planes: Dict[int, set] = {CNT: set()}
+        # (float plane(s), int plane(s)) an aggregate reads
+        reads = {"sum": ((0, 1), (2, 3)), "avg": ((0, 1), (2, 3)),
+                 "stddev": ((0, 1), ()), "min": ((5,), (7,)),
+                 "max": ((6,), (8,)), "minforever": ((9,), (11,)),
+                 "maxforever": ((10,), (12,))}
+        for (_name, kind, ref) in self.outputs:
+            if kind not in reads:
+                continue
+            for v in (ref if kind == "stddev" else (ref,)):
+                for p in reads[kind][v.int_mode]:
+                    planes.setdefault(p, set()).add(v.vidx)
+        spec = tuple((p, None if p == CNT else tuple(sorted(planes[p])))
+                     for p in sorted(planes))
+        return spec, {p: (i, {j: k for k, j in enumerate(cols or ())})
+                      for i, (p, cols) in enumerate(spec)}
+
     def _build_step(self):
         from .shapes import shape_registry
         # shape-class dims exclude lanes/groups: those grow under the
         # same jit (a plain retrace), only these facts change the program
-        if self.window_kind == "time":
+        rows = None if self.selection is not None else self._gather
+        if self.lane_rows:
+            # running sums of group lanes (min/max and their forever
+            # forms are one lane here); donated unless exact int sums
+            # are wanted (their bound rewinds to the pre-carry)
+            donate = () if self._int_sum_needed else (0,)
+            minmax = self.want_minmax or self.want_forever
+            self._step = shape_registry().jit(
+                "gagg.step",
+                {"kind": "lanes", "vf": self._n_float, "vi": self._n_int,
+                 "minmax": minmax, "donate": bool(donate),
+                 "numguard": self._numguard},
+                build_lane_group_step(minmax, rows,
+                                      numguard=self._numguard),
+                static_argnums=(4,), donate_argnums=donate)
+        elif self.window_kind == "time":
             # no donation: decode's GaggOverflow rewind replays from the
             # chunk's pre-carry, which must survive the step
+            step = build_grouped_time_step(
+                self.window_ms, self.window, self.want_forever)
             self._step = shape_registry().jit(
                 "gagg.time.step",
                 {"win_ms": self.window_ms, "win": self.window,
                  "vf": self._n_float, "vi": self._n_int,
                  "forever": self.want_forever},
-                build_grouped_time_step(
-                    self.window_ms, self.window, self.want_forever))
+                step if rows is None else build_row_gather(step, rows))
         else:
             # length/running carries donate (XLA aliases the [P, G, V]
             # slabs in place) UNLESS exact int sums are wanted — their
@@ -334,15 +417,16 @@ class CompiledGroupedAgg:
             # NUMGUARD (core/numguard.py): the sentinel flag appends a
             # 14th output, a different compiled program — so it is part
             # of the shape-class key, like every program-changing fact
+            step = build_grouped_step(
+                self.window, self.want_minmax, self.want_forever,
+                numguard=self._numguard)
             self._step = shape_registry().jit(
                 "gagg.step",
                 {"kind": self.window_kind, "win": self.window,
                  "vf": self._n_float, "vi": self._n_int,
                  "minmax": self.want_minmax, "forever": self.want_forever,
                  "donate": bool(donate), "numguard": self._numguard},
-                build_grouped_step(
-                    self.window, self.want_minmax, self.want_forever,
-                    numguard=self._numguard),
+                step if rows is None else build_row_gather(step, rows),
                 donate_argnums=donate)
         if getattr(self, "selection", None) is not None:
             from ..ops.select import build_select_step
@@ -354,6 +438,9 @@ class CompiledGroupedAgg:
 
     def _make_carry(self, n_lanes: int, n_groups: Optional[int] = None):
         g = self.n_groups if n_groups is None else n_groups
+        if self.lane_rows:
+            return make_lane_group_carry(n_lanes, self._n_float,
+                                         self._n_int)
         if self.window_kind == "time":
             return make_grouped_time_carry(n_lanes, self.window, g,
                                            self._n_float, self._n_int)
@@ -364,8 +451,9 @@ class CompiledGroupedAgg:
         if n_lanes <= self.n_lanes:
             return
         fresh = self._make_carry(n_lanes - self.n_lanes)
+        axis = -1 if self.lane_rows else 0      # the lane-minor carry
         self.carry = type(self.carry)(
-            *[jnp.concatenate([a, b], axis=0)
+            *[jnp.concatenate([a, b], axis=axis)
               for a, b in zip(self.carry, fresh)])
         self.n_lanes = n_lanes
 
@@ -421,31 +509,28 @@ class CompiledGroupedAgg:
         if new_capacity <= self.window:
             return
         old = self.carry
-        P = self.n_lanes
         rts = np.asarray(old.ring_ts)
-        rf = np.asarray(old.ring_f)
-        ri = np.asarray(old.ring_i)
-        rg = np.asarray(old.ring_gid)
+        P, W = rts.shape
         W2 = new_capacity
-        nf = np.zeros((P, W2) + rf.shape[2:], np.float32)
-        ni = np.zeros((P, W2) + ri.shape[2:], np.int32)
-        ng = np.full((P, W2), -1, np.int32)
-        nts = np.full((P, W2), TS_EMPTY, np.int32)
-        cnt = np.zeros(P, np.int32)
+        # empty slots (TS_EMPTY, the int32 minimum) sort first: a lane's
+        # cnt live entries are the last cnt of its ordered slots
         order = np.argsort(rts, axis=1, kind="stable")
-        keep = np.take_along_axis(rts, order, 1) != TS_EMPTY
-        for p in range(P):                  # host-side, grow-time only
-            sel = order[p][keep[p]]
-            k = len(sel)
-            nf[p, :k] = rf[p, sel]
-            ni[p, :k] = ri[p, sel]
-            ng[p, :k] = rg[p, sel]
-            nts[p, :k] = rts[p, sel]
-            cnt[p] = k
+        cnt = (rts != TS_EMPTY).sum(axis=1)
+        i = np.arange(W2)[None, :]
+        live = i < cnt[:, None]
+        src = np.take_along_axis(
+            order, np.minimum(W - cnt[:, None] + i, W - 1), axis=1)
+        lane = np.arange(P)[:, None]
+
+        def moved(a, empty):
+            a = np.asarray(a)[lane, src]
+            keep = live if a.ndim == 2 else live[..., None]
+            return jnp.asarray(np.where(keep, a, empty).astype(a.dtype))
         self.window = W2
         self.carry = GroupedTimeCarry(
-            ring_f=jnp.asarray(nf), ring_i=jnp.asarray(ni),
-            ring_gid=jnp.asarray(ng), ring_ts=jnp.asarray(nts),
+            ring_f=moved(old.ring_f, 0), ring_i=moved(old.ring_i, 0),
+            ring_gid=moved(old.ring_gid, -1),
+            ring_ts=moved(old.ring_ts, TS_EMPTY),
             pos=jnp.asarray(cnt % W2, jnp.int32),
             cnt=jnp.asarray(cnt, jnp.int32),
             overflow=jnp.zeros((P,), bool),
@@ -508,14 +593,11 @@ class CompiledGroupedAgg:
 
     # ------------------------------------------------------------ execute
 
-    def dispatch(self, lanes: np.ndarray, data) -> Optional[Dict[str, Any]]:
-        """data: EventChunk of CURRENT events, lanes: per-event lane
-        index.  Host-side encode + ONE kernel dispatch; returns a work
-        dict whose un-read device handles `decode` consumes later
-        (pipelined ingest), or None when no event passes the filters.
-        Data errors that are host-detectable (2^31 integer lanes) raise
-        HERE, before any carry mutation."""
-        from ..native_ext import assign_rows
+    def encode(self, data) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """The filters and the value lanes of a chunk, on the host ->
+        (accepted mask, vals_f [n, VF] f32, vals_i [n, VI] i32).  Data
+        errors that are host-detectable (2^31 integer lanes) raise HERE,
+        before any carry mutation."""
         n = len(data)
         ctx = EvalCtx(data.columns, data.timestamps, n)
         ok = np.ones(n, bool)
@@ -539,31 +621,61 @@ class CompiledGroupedAgg:
                 vals_i[:, v.vidx] = iv.astype(np.int32)
             else:
                 vals_f[:, v.vidx] = np.asarray(col, np.float32)
+        return ok, vals_f, vals_i
+
+    def dispatch(self, lanes: np.ndarray, data, encoded=None
+                 ) -> Optional[Dict[str, Any]]:
+        """data: EventChunk of CURRENT events, lanes: per-event lane
+        index; ``encoded``: what ``encode(data)`` gave, where the caller
+        ran it.  Host-side encode + ONE kernel dispatch; returns a work
+        dict whose un-read device handles `decode` consumes later
+        (pipelined ingest), or None when no event passes the filters.
+        On group lanes every event of ``data`` is accepted (the caller
+        kept those alone) and its lane is its group's."""
+        from ..native_ext import assign_rows
+        ok, vals_f, vals_i = encoded or self.encode(data)
         if not ok.any():
             return None
-        # group ids only for ACCEPTED rows — filter-rejected keys must not
-        # allocate slab entries (high-cardinality streams would grow the
-        # [P, G, V] state for groups that never hold data)
-        key_cols = [np.asarray(data.columns[a])[ok]
-                    for a in self.group_attrs]
-        gids_ok = self._gids_for(np.asarray(lanes)[ok], key_cols)
-        gids = np.zeros(n, np.int64)
-        gids[ok] = gids_ok
-
+        n = len(data)
+        gids = np.zeros(n, np.int64)        # group lanes: G = 1
+        if not self.group_lanes:
+            # group ids only for ACCEPTED rows — filter-rejected keys
+            # must not allocate slab entries (high-cardinality streams
+            # would grow the [P, G, V] state for groups that never hold
+            # data)
+            key_cols = [np.asarray(data.columns[a])[ok]
+                        for a in self.group_attrs]
+            gids[ok] = self._gids_for(np.asarray(lanes)[ok], key_cols)
         lanes32 = np.ascontiguousarray(lanes, np.int32)
-        row, _counts, T = assign_rows(lanes32, self.n_lanes)
         P = self.n_lanes
-        T = 1 << (T - 1).bit_length()
-        f_plane = np.zeros((P, T, self._n_float), np.float32)
-        i_plane = np.zeros((P, T, self._n_int), np.int32)
-        g_plane = np.zeros((P, T), np.int32)
-        ok_plane = np.zeros((P, T), bool)
-        f_plane[lanes32, row] = vals_f
-        i_plane[lanes32, row] = vals_i
-        g_plane[lanes32, row] = gids
-        ok_plane[lanes32, row] = ok
-        work: Dict[str, Any] = {"data": data, "ok": ok,
-                                "lanes32": lanes32, "row": row}
+        work: Dict[str, Any] = {"data": data, "ok": ok}
+        with _ledger().span("device", "pack"):
+            row, _counts, T = assign_rows(lanes32, P)
+            T = 1 << (T - 1).bit_length()
+            work["cells"] = P * T
+            if self.lane_rows:
+                # compact rows: the step scatters them into its block
+                n_pad = rows_pad(n, P, T)
+                at = np.full(n_pad, T * P, np.int32)
+                at[:n] = row * P + lanes32
+                xf = np.zeros((n_pad, self._n_float), np.float32)
+                xi = np.zeros((n_pad, self._n_int), np.int32)
+                xf[:n] = vals_f
+                xi[:n] = vals_i
+                work.update(n_ok=n, planes=(xf, xi, at, T))
+            else:
+                f_plane = np.zeros((P, T, self._n_float), np.float32)
+                i_plane = np.zeros((P, T, self._n_int), np.int32)
+                g_plane = np.zeros((P, T), np.int32)
+                ok_plane = np.zeros((P, T), bool)
+                f_plane[lanes32, row] = vals_f
+                i_plane[lanes32, row] = vals_i
+                g_plane[lanes32, row] = gids
+                ok_plane[lanes32, row] = ok
+        if self.lane_rows:
+            self.redispatch(work)
+            return work
+        n_ok = work["n_ok"] = int(ok.sum())
         if self.selection is not None:
             # padded per-emission gather vectors for the select step —
             # pow2-bucketed like T so chunk-size jitter reuses traces;
@@ -576,6 +688,14 @@ class CompiledGroupedAgg:
             rp[:n] = row
             op[:n] = ok
             work["sel_pad"] = (lp, rp, op)
+        else:
+            # the accepted events' cells, read in the step's own call
+            n_pad = rows_pad(n_ok, P, T)
+            lp = np.zeros(n_pad, np.int32)
+            rp = np.zeros(n_pad, np.int32)
+            lp[:n_ok] = lanes32[ok]
+            rp[:n_ok] = row[ok]
+            work["at"] = (lp, rp)
         if self.window_kind == "time":
             ts_plane = self._ts_offsets(data, lanes32, row, ok, (P, T))
             work["planes"] = (f_plane, i_plane, g_plane, ts_plane,
@@ -594,7 +714,8 @@ class CompiledGroupedAgg:
         donated = (self.window_kind != "time" and
                    not self._int_sum_needed)
         work["pre_carry"] = None if donated else self.carry
-        self.carry, outs = self._step(self.carry, *work["planes"])
+        self.carry, outs = self._step(self.carry, *work["planes"],
+                                      *work.get("at", ()))
         if self.selection is not None:
             # chain the egress selection kernel: having mask, ordering
             # permutation and limit bound computed on device over the 13
@@ -638,7 +759,6 @@ class CompiledGroupedAgg:
         integer-sum bound — the caller rewinds likewise (the reference's
         @OnError continuation must not see the chunk half-applied)."""
         data, ok = work["data"], work["ok"]
-        lanes32, row = work["lanes32"], work["row"]
         token = work.get("fuse")
         if token is not None:
             fetched = token.fetch()
@@ -653,42 +773,44 @@ class CompiledGroupedAgg:
                 raise GaggOverflow()
             outs_host = [np.asarray(o) for o in work["outs"]]
         if self._numguard and self.window_kind != "time":
-            # device sentinel plane (14th output — see _build_step)
+            # device sentinel plane (appended last — see _build_step)
             sent, outs_host = outs_host[-1], outs_host[:-1]
             if self.sentinels is not None:
                 self.sentinels.observe_sentinel_plane("gagg.step", sent)
-        sel_idx = None
         if self.selection is not None:
             # device selection (ops/select.py): sel_rows is the ordering
             # permutation over chunk rows, meta = [out_count, max_cnt];
             # the 13 planes arrive already gathered + compacted, so the
             # selected rows are simply the first out_count entries
-            sel_rows = np.asarray(outs_host[0])
             meta = np.asarray(outs_host[1])
-            sel_k = int(meta[0])
-            sel_cmax = int(meta[1])
-            sel_idx = sel_rows[:sel_k]
-            outs_host = outs_host[2:]
-        (fhi, flo, ihi, ilo, cnt, w_mnf, w_mxf, w_mni, w_mxi,
-         a_mnf, a_mxf, a_mni, a_mxi) = outs_host
+            k = int(meta[0])
+            sel_idx = np.asarray(outs_host[0])[:k]
+            planes = outs_host[2:]
+
+            def col(p, j=None):
+                a = planes[p][:k]
+                return a if j is None else a[:, j]
+        else:
+            # the accepted events' rows, in chunk order (_gather_spec)
+            k = work["n_ok"]
+            sel_idx = None
+
+            def col(p, j=None):
+                at, pos = self._row_at[p]
+                a = outs_host[at][:k]
+                return a if j is None else a[:, pos[j]]
+        counts = col(CNT).astype(np.int64)
         if self.sentinels is not None:
-            # host-rim witness over planes this decode already fetched:
+            # host-rim witness over rows this decode already fetched:
             # bit-identical by construction (reads only, no compute path
             # change) — covers the time kernel, which has no device plane
-            self.sentinels.observe_floats("gagg.decode", fhi)
-            self.sentinels.observe_counts("gagg.decode", cnt)
-        if sel_idx is not None:
-            def pick(a):
-                return a[:sel_k]
-            cnt_max = sel_cmax
-        else:
-            sel_l, sel_r = lanes32[ok], row[ok]
-
-            def pick(a):
-                return a[sel_l, sel_r]
-        counts = pick(cnt).astype(np.int64)
-        if sel_idx is None:
-            cnt_max = int(counts.max(initial=0))
+            if 0 in self._row_at or self.selection is not None:
+                self.sentinels.observe_floats("gagg.decode", col(0))
+            self.sentinels.observe_counts("gagg.decode", counts)
+        # the bound sees every accepted event's count, the ones a having
+        # clause filtered out too
+        cnt_max = int(meta[1]) if sel_idx is not None else \
+            int(counts.max(initial=0))
         if self._int_sum_needed and self.window == 0 and \
                 cnt_max >= INT_GROUP_MAX:
             raise SiddhiAppRuntimeException(
@@ -707,12 +829,12 @@ class CompiledGroupedAgg:
                 continue
             if kind == "stddev":
                 vx, vh, vl = ref
-                sx = pick(fhi)[:, vx.vidx].astype(np.float64) + \
-                    pick(flo)[:, vx.vidx].astype(np.float64)
-                sxx = (pick(fhi)[:, vh.vidx].astype(np.float64) +
-                       pick(flo)[:, vh.vidx].astype(np.float64)) + \
-                      (pick(fhi)[:, vl.vidx].astype(np.float64) +
-                       pick(flo)[:, vl.vidx].astype(np.float64))
+
+                def pair(v):
+                    return col(0, v.vidx).astype(np.float64) + \
+                        col(1, v.vidx).astype(np.float64)
+                sx = pair(vx)
+                sxx = pair(vh) + pair(vl)
                 with np.errstate(invalid="ignore", divide="ignore"):
                     c = np.maximum(counts, 1)
                     var = sxx / c - (sx / c) ** 2
@@ -722,34 +844,26 @@ class CompiledGroupedAgg:
                 continue
             v: _Value = ref
             j = v.vidx
-            if v.int_mode:
-                sums = reassemble_int_sums(pick(ihi)[:, j],
-                                           pick(ilo)[:, j])
-                mn, mx = pick(w_mni)[:, j], pick(w_mxi)[:, j]
-                fm, fx = pick(a_mni)[:, j], pick(a_mxi)[:, j]
-            else:
-                # two-float pair → f64 (tracks the host's float64
-                # accumulation to ~2^-48 relative)
-                sums = pick(fhi)[:, j].astype(np.float64) + \
-                    pick(flo)[:, j].astype(np.float64)
-                mn, mx = pick(w_mnf)[:, j], pick(w_mxf)[:, j]
-                fm, fx = pick(a_mnf)[:, j], pick(a_mxf)[:, j]
-            if kind == "sum":
-                out[name] = sums
-            elif kind == "avg":
-                with np.errstate(invalid="ignore", divide="ignore"):
-                    out[name] = np.where(
-                        counts > 0,
-                        sums.astype(np.float64) / np.maximum(counts, 1),
-                        np.nan)
-            elif kind == "min":
-                out[name] = mn
-            elif kind == "max":
-                out[name] = mx
-            elif kind == "minforever":
-                out[name] = fm
-            elif kind == "maxforever":
-                out[name] = fx
+            if kind in ("sum", "avg"):
+                if v.int_mode:
+                    sums = reassemble_int_sums(col(2, j), col(3, j))
+                else:
+                    # two-float pair → f64 (tracks the host's float64
+                    # accumulation to ~2^-48 relative)
+                    sums = col(0, j).astype(np.float64) + \
+                        col(1, j).astype(np.float64)
+                if kind == "sum":
+                    out[name] = sums
+                else:
+                    with np.errstate(invalid="ignore", divide="ignore"):
+                        out[name] = np.where(
+                            counts > 0,
+                            sums.astype(np.float64) / np.maximum(counts, 1),
+                            np.nan)
+                continue
+            plane = {"min": 5, "max": 6, "minforever": 9,
+                     "maxforever": 10}[kind] + 2 * v.int_mode
+            out[name] = col(plane, j)
         return out
 
     # ------------------------------------------------------------ types

@@ -202,6 +202,116 @@ def map_keys_to_lanes(key_lanes: Dict[Any, int], keys,
     return lanes
 
 
+class GroupLanes:
+    """A grouped query's group tuples -> lanes, one lane per group
+    (plan/gagg_compiler.py ``group_lanes``): the durable map that
+    snapshots hold, ``{other columns' tuple: {key: lane}}``, with the
+    caches that serve a block beside it.
+
+    One group column is the key (the first string one, else the first):
+    its values are interned as a keyed join interns its key
+    (core/keyfactor.py ``KeyInterner``: one dict probe per event, ids
+    that outlive the block).  The other columns are factored by their
+    distinct values in the block, and the events of each distinct tuple
+    of them gather their lanes by key id from that tuple's ``IdTable``,
+    which admits the keys it has not met.  Lanes are dense from 0 and
+    only ever handed out."""
+
+    def __init__(self, group_attrs: List[str], key_attr: str):
+        self.key_attr = key_attr
+        self.rest_attrs = [a for a in group_attrs if a != key_attr]
+        self.interner = KeyInterner()
+        self.lanes: Dict[tuple, Dict[Optional[str], int]] = {}
+        self._tables: Dict[tuple, IdTable] = {}
+        self.n = 0
+
+    def _admit(self, durable: Dict[Optional[str], int], key) -> int:
+        lane = durable[key] = self.n
+        self.n += 1
+        return lane
+
+    def factor(self, columns: Dict[str, Any]):
+        """A block's group tuples, factored: (per event the key's id in
+        ``interner``, ``NULL`` for a null key; per event the place of its
+        other columns' tuple; those tuples)."""
+        from ..core.keyfactor import NULL
+        col = np.asarray(columns[self.key_attr])
+        keys = intern_values(
+            self.interner, col, None,
+            lambda: [None if v is None else str(v) for v in col.tolist()])
+        ids = keys.ids
+        if keys.keep is not None:
+            ids = np.full(len(col), NULL, np.intp)
+            ids[keys.keep] = keys.ids
+        place = np.zeros(len(col), np.int64)
+        tuples = [()]
+        for a in self.rest_attrs:
+            arr = np.asarray(columns[a])
+            if arr.dtype.kind in "iub" and len(arr) and \
+                    arr.min() == arr.max():
+                # one value in the block (a day, a region): no sort
+                tuples = [t + (arr[0].item(),) for t in tuples]
+                continue
+            if arr.dtype.kind in "iubU":
+                uniq, inv = np.unique(arr, return_inverse=True)
+                vals = uniq.tolist()
+            else:                       # objects: the host's own equality
+                seen: Dict[Any, int] = {}
+                inv = np.fromiter((seen.setdefault(v, len(seen))
+                                   for v in arr.tolist()), np.int64,
+                                  len(arr))
+                vals = list(seen)
+            combo, place = np.unique(place * len(vals) + inv.reshape(-1),
+                                     return_inverse=True)
+            m = len(vals)
+            tuples = [tuples[c // m] + (vals[c % m],)
+                      for c in combo.tolist()]
+            place = place.reshape(-1)
+        return keys, ids, place, tuples
+
+    def lanes_of(self, factored) -> np.ndarray:
+        """Every event's lane, new groups admitted."""
+        keys, ids, place, tuples = factored
+        out = np.empty(len(ids), np.int64)
+        if len(tuples) == 1:
+            parts = [(tuples[0], None)]
+        else:
+            order = np.argsort(place, kind="stable")
+            cut = np.flatnonzero(np.diff(place[order])) + 1
+            parts = list(zip(tuples, np.split(order, cut)))
+        for rest, at in parts:
+            durable = self.lanes.setdefault(rest, {})
+            table = self._tables.get(rest)
+            if table is None:
+                table = self._tables[rest] = IdTable(np.int64)
+            sub = ids if at is None else ids[at]
+            null = sub < 0
+            lanes = np.empty(len(sub), np.int64)
+            if null.any():
+                lane = durable.get(None)
+                lanes[null] = self._admit(durable, None) if lane is None \
+                    else lane
+            some = KeyIds(self.interner, sub[~null] if null.any() else sub,
+                          keys.raw_str, None, 0)
+            lanes[~null] = table.gather(some, durable,
+                                        partial(self._admit, durable))
+            if at is None:
+                out = lanes
+            else:
+                out[at] = lanes
+        return out
+
+    def as_state(self) -> dict:
+        """The durable map as plain data, for the runtime's snapshot."""
+        return {"n": self.n, "lanes": [[list(rest), dict(d)]
+                                       for rest, d in self.lanes.items()]}
+
+    def load_state(self, state: dict) -> None:
+        self.n = int(state["n"])
+        self.lanes = {tuple(rest): dict(d) for rest, d in state["lanes"]}
+        self._tables = {}
+
+
 def _lanes_of_array(key_lanes: Dict[Any, int], keys) -> np.ndarray:
     arr = np.asarray(keys)
     if arr.dtype.kind in "USiu" and len(keys) > 64:
@@ -1644,7 +1754,20 @@ class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
             app.app, q,
             n_lanes=initial_lanes(app.app, self._shard_want)
             if self.keyed else 1,
-            keyed=self.keyed)
+            keyed=self.keyed,
+            definition=None if self.keyed else app.definition_of(
+                sis.stream_id, sis.is_inner, sis.is_fault))
+        # one lane per group tuple (gagg_compiler ``group_lanes``): the
+        # tuples' lanes are this runtime's, and @app:lanes sizes them
+        self.group_lanes: Optional[GroupLanes] = None
+        if self.cga.group_lanes:
+            types = {a.name: a.type
+                     for a in self.cga.input_definition.attributes}
+            attrs = self.cga.group_attrs
+            self.group_lanes = GroupLanes(attrs, next(
+                (a for a in attrs if types[a] == AttrType.STRING),
+                attrs[0]))
+            self.cga.grow_lanes(initial_lanes(app.app))
         # surfaced by service/rest.py stats and tools/t1_report.py: did
         # the selection tail (having/order/limit) compile to device?
         self.selection_route = None
@@ -1709,6 +1832,10 @@ class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
         if data.is_empty:
             return
         marks = shape_registry().marks()
+        if self.group_lanes is not None:
+            self._ingest_group_lanes(data)
+            _record_block(self, marks, stream_id, len(data))
+            return
         if self.keyed:
             data, keys = _factored_keys(self.key_executor, data,
                                         self.app_name)
@@ -1728,8 +1855,39 @@ class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
             work = self.cga.dispatch(lanes, data)
         if work is None:
             return
+        _ledger().note_gagg(self.app_name, len(data), 0, work["cells"])
         self._submit(work)
         _record_block(self, marks, stream_id, len(data))
+
+    def _ingest_group_lanes(self, data) -> None:
+        """One lane per group tuple: the filters and value lanes first,
+        then the accepted events' group tuples factored once for the
+        block and gathered to lanes, then one step."""
+        led = _ledger()
+        cga, gl = self.cga, self.group_lanes
+        with led.span("device", "encode"):
+            ok, vals_f, vals_i = cga.encode(data)
+            if not ok.all():
+                data, vals_f, vals_i = data.mask(ok), vals_f[ok], vals_i[ok]
+                ok = ok[ok]
+        n = len(data)
+        if n == 0:
+            return
+        with led.span("dispatch", "keys"):
+            factored = gl.factor(data.columns)
+            led.note_key_factor(self.app_name, False)
+            led.note_key_intern(self.app_name, n, factored[0].hits)
+        with led.span("dispatch", "lanes"):
+            lanes = gl.lanes_of(factored)
+            if gl.n > cga.n_lanes:
+                cap = cga.n_lanes
+                while cap < gl.n:
+                    cap *= 2
+                self._grow_lanes(cap)
+        with led.span("device"):
+            work = cga.dispatch(lanes, data, (ok, vals_f, vals_i))
+        led.note_gagg(self.app_name, n, n, work["cells"])
+        self._submit(work)
 
     def _ingest_sharded(self, data, keys_arr: np.ndarray) -> None:
         """Hash-route the chunk; each shard's sub-block dispatches on its
@@ -1754,6 +1912,7 @@ class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
             sh.events += len(rows)
             if work is None:
                 continue
+            _ledger().note_gagg(self.app_name, len(rows), 0, work["cells"])
             sh.dispatches += 1
             work["shard"] = sh
             self._submit(work)
@@ -1873,8 +2032,11 @@ class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
                 return {"shards": [{"cga": sh.engine.current_state(),
                                     "key_lanes": dict(sh.key_lanes)}
                                    for sh in self.shards]}
-            return {"cga": self.cga.current_state(),
-                    "key_lanes": dict(self.key_lanes)}
+            state = {"cga": self.cga.current_state(),
+                     "key_lanes": dict(self.key_lanes)}
+            if self.group_lanes is not None:
+                state["group_lanes"] = self.group_lanes.as_state()
+            return state
 
     def restore_state(self, state: dict) -> None:
         with self.qr.lock:
@@ -1889,6 +2051,8 @@ class DeviceGroupedAggRuntime(PipelinedDeviceIngest):
                 return
             self.cga.restore_state(state["cga"])
             self.key_lanes = KeyLanes(state["key_lanes"])
+            if self.group_lanes is not None:
+                self.group_lanes.load_state(state["group_lanes"])
 
 
 @persistent_schema("device-filter", schema=None,

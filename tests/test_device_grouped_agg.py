@@ -314,9 +314,13 @@ def test_filtered_out_keys_allocate_no_groups():
         h.send([f"s", f"u{i}", 1.0, 1], timestamp=1_000_000 + i * 100)
     qr = rt.query_runtimes["q"]
     assert qr.backend == "device"
-    cga = qr.device_runtime.cga
-    assert len(cga.gid_map) == 0 and cga.n_groups == 8, \
-        (len(cga.gid_map), cga.n_groups)
+    # an unpartitioned group-by steps one lane per group: no lane was
+    # handed out, and the lanes never grew past their first capacity
+    dev = qr.device_runtime
+    cga = dev.cga
+    assert cga.group_lanes and len(cga.gid_map) == 0
+    assert dev.group_lanes.n == 0 and cga.n_lanes == 8, \
+        (dev.group_lanes.n, cga.n_lanes)
     rt.shutdown()
 
 
